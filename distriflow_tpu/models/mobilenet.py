@@ -354,6 +354,15 @@ def mobilenet_v2(
         raise ValueError(
             "depthwise_impl='fused' fuses GroupNorm into the kernel and "
             f"requires norm='group', got norm={norm!r}")
+    if depthwise_impl == "fused":
+        from distriflow_tpu.ops import default_interpret
+        from distriflow_tpu.ops.depthwise_gn import MOSAIC_REFUSAL
+
+        if not default_interpret():
+            # say so here, with the compiler's message, instead of minutes
+            # later inside a step compile — and never by quietly taking
+            # the shift path
+            raise NotImplementedError(MOSAIC_REFUSAL)
     if gn_impl not in ("flax", "onepass"):
         raise ValueError(f"gn_impl must be 'flax' or 'onepass', got {gn_impl!r}")
     return spec_from_flax(

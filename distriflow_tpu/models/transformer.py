@@ -27,7 +27,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from distriflow_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distriflow_tpu.models.base import ModelSpec
@@ -45,6 +45,8 @@ from distriflow_tpu.parallel.ring_attention import (
 # short context — and faster only by ~16k (3.03 vs 3.09, builder-measured,
 # docs/PERFORMANCE.md §7e). Caches shorter than this keep bf16 under
 # kv_cache_dtype="int8"; "int8_force" overrides (capacity > latency).
+# Those numbers come from a machine that is gone (BENCH_r05 was deleted in
+# PR 21): on the current TPU v5e the crossover is not measured (ROADMAP S4).
 INT8_KV_DECODE_CROSSOVER_SEQ = 8192
 
 
@@ -174,8 +176,8 @@ class TransformerConfig:
         time (not config-construction time, so a config built on the host
         composes with whatever backend runs it): the fused Pallas sparse
         CE on TPU when the logits' vocab dim stays unsharded — i.e. on a
-        single device or a pure data-parallel mesh (the kernel carries a
-        rows-sharded ``custom_partitioning`` rule, ``ops/fused_ce.py``).
+        single device or a pure data-parallel mesh (the kernel runs per
+        data shard of the trainer's context mesh, ``ops/fused_ce.py``).
         Meshes with model/pipe axes column-shard the lm_head (vocab-sharded
         logits) and seq axes shard a middle dim the flat [tokens, V] view
         cannot represent — those fall back to the sharded XLA loss, which
@@ -273,7 +275,11 @@ def _sharded_flash_attention(q, k, v, causal, mesh):
     from distriflow_tpu.ops import flash_attention  # lazy: pallas import
 
     fn = _ft.partial(flash_attention, causal=causal)
-    if mesh is None:
+    ambient = jax.sharding.get_abstract_mesh()
+    if mesh is None or (not ambient.empty and ambient.are_all_axes_manual):
+        # no mesh, or already inside a shard_map body (FedAvg's local
+        # loop): q/k/v are this device's shard, and a second shard_map over
+        # the concrete mesh is an error there
         return fn(q, k, v)
     parallel_axes = tuple(
         ax for ax in ("data", "model")

@@ -1,12 +1,18 @@
-"""Native host-kernel loader: C++ fast paths with numpy fallbacks.
+"""Native host-kernel loader: C++ fast paths built from the committed source.
 
 The reference has no native layer (SURVEY.md §2.1); this framework's
 host-side hot paths — batch assembly (gather) and federated gradient
 aggregation (mean over client buffers) — get multi-threaded C++ kernels
 (``src/distriflow_native.cpp``) compiled on first use with g++ and loaded
-via ctypes. Everything degrades gracefully: if no compiler or load failure,
-the numpy implementations (themselves C-backed, just single-threaded and
-copy-heavier) are used and ``AVAILABLE`` is False.
+via ctypes.
+
+The shared library is never committed (``*.so`` is git-ignored), so the
+loader ties it to the source it was built from: the file name carries the
+content hash of ``src/distriflow_native.cpp`` and only that name is ever
+opened. A library left behind by another revision of the source has another
+name, is never loaded, and is removed at the next build. A build that fails
+raises with the compiler's output; only a machine with no ``g++`` at all
+runs the numpy implementations (``AVAILABLE`` stays False).
 
 Public surface:
 - :func:`gather_rows(src, idx)` — ``src[idx]`` into a fresh contiguous array;
@@ -17,9 +23,11 @@ Public surface:
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import shutil
 import subprocess
-import sys
 import threading
 from typing import List, Optional, Sequence
 
@@ -27,85 +35,88 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src", "distriflow_native.cpp")
-_LIB_PATH = os.path.join(_DIR, "libdistriflow_native.so")
-_ABI_VERSION = 1
+_LIB_STEM = "libdistriflow_native"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_tried = False
+_no_compiler = False  # decided once: no g++ on PATH, numpy paths serve
 
 AVAILABLE = False
 
 _N_THREADS = min(8, os.cpu_count() or 1)
 
 
-def _build() -> bool:
-    """Compile the shared library; returns success. Quiet on failure.
+def _lib_path() -> str:
+    """The one library file this source may load: named by the content
+    hash of the C++ source as it is on disk now."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"{_LIB_STEM}-{digest}.so")
 
-    Compiles to a per-process temp path then ``os.rename``s into place
+
+def _build(lib_path: str) -> None:
+    """Compile the shared library to ``lib_path``; raises on failure.
+
+    Compiles to a per-process temp path then ``os.replace``s into place
     (atomic on POSIX) so concurrent first-use builds across processes never
-    expose a partially written .so or truncate one another process has
-    already mapped."""
-    tmp_path = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    expose a partially written .so. Libraries of other source revisions
+    (any other ``libdistriflow_native*.so`` here) are removed."""
+    tmp_path = f"{lib_path}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-fPIC", "-shared", "-pthread", "-std=c++17",
         _SRC, "-o", tmp_path,
     ]
     try:
         proc = subprocess.run(cmd, capture_output=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    if proc.returncode != 0:
-        print(f"[native] build failed:\n{proc.stderr.decode()}", file=sys.stderr)
-        return False
-    try:
-        os.rename(tmp_path, _LIB_PATH)
-    except OSError:
-        os.unlink(tmp_path)
-        return os.path.exists(_LIB_PATH)  # another process won the race
-    return True
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"native build failed ({' '.join(cmd)}):\n"
+                f"{proc.stderr.decode(errors='replace')}")
+        os.replace(tmp_path, lib_path)
+    finally:
+        if os.path.exists(tmp_path):
+            os.unlink(tmp_path)
+    for stale in glob.glob(os.path.join(_DIR, f"{_LIB_STEM}*.so")):
+        if stale != lib_path:
+            os.unlink(stale)
 
 
-def _load() -> Optional[ctypes.CDLL]:
-    global AVAILABLE
-    try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
-        return None
-    lib.df_abi_version.restype = ctypes.c_int
-    if lib.df_abi_version() != _ABI_VERSION:
-        # stale build from an older source revision: rebuild
-        return None
+def _load(lib_path: str) -> ctypes.CDLL:
+    lib = ctypes.CDLL(lib_path)
     lib.df_gather_rows.argtypes = [
         ctypes.c_void_p, ctypes.c_uint64, ctypes.c_void_p, ctypes.c_uint64,
         ctypes.c_void_p, ctypes.c_int,
     ]
+    lib.df_gather_rows.restype = None
     lib.df_mean_f32.argtypes = [
         ctypes.POINTER(ctypes.c_void_p), ctypes.c_uint64, ctypes.c_uint64,
         ctypes.c_void_p, ctypes.c_int,
     ]
-    AVAILABLE = True
+    lib.df_mean_f32.restype = None
     return lib
 
 
 def ensure_built(force: bool = False) -> bool:
-    """Build (if needed) and load the native library; returns availability."""
-    global _lib, _tried, AVAILABLE
+    """Build (if needed) and load the library for the source on disk.
+
+    True: the C++ kernels are loaded. False: this machine has no ``g++``
+    and the numpy implementations serve. A compiler that is present and
+    fails, or a library that will not load, raises."""
+    global _lib, _no_compiler, AVAILABLE
     with _lock:
         if _lib is not None and not force:
             return True
-        if _tried and not force:
+        if _no_compiler and not force:
             return False
-        _tried = True
-        if force or not os.path.exists(_LIB_PATH):
-            if not _build():
+        lib_path = _lib_path()
+        if force or not os.path.exists(lib_path):
+            if shutil.which("g++") is None:
+                _no_compiler = True
                 return False
-        _lib = _load()
-        if _lib is None and os.path.exists(_LIB_PATH):
-            # stale or corrupt .so: one rebuild attempt
-            if _build():
-                _lib = _load()
-        return _lib is not None
+            _build(lib_path)
+        _lib = _load(lib_path)
+        AVAILABLE = True
+        return True
 
 
 def gather_rows(src: np.ndarray, idx: np.ndarray) -> np.ndarray:

@@ -78,7 +78,4 @@ void df_mean_f32(const float* const* srcs, uint64_t n_srcs, uint64_t n_elems,
   });
 }
 
-// Sanity/version probe for the ctypes loader.
-int df_abi_version() { return 1; }
-
 }  // extern "C"

@@ -11,8 +11,12 @@ for the ops XLA's default fusion leaves on the table:
 - :func:`depthwise3x3_groupnorm` — depthwise-3x3 + GroupNorm + ReLU6 in one
   VMEM-resident sweep (MobileNet's two measured hot spots fused).
 
-Kernels compile on TPU and fall back to interpret mode on CPU (tests), via
-:func:`default_interpret`.
+On a TPU backend every kernel is compiled by Mosaic. Off-TPU (the CPU test
+suite) the same kernel bodies run in the Pallas interpreter — a correctness
+aid that says nothing about the compiled kernel. :func:`default_interpret`
+is the single switch; ``chip_smoke.py`` asserts that the programs it runs on
+the chip contain the Mosaic custom calls, so nothing on that path may depend
+on the switch being ``True``.
 """
 
 from distriflow_tpu.ops.depthwise_gn import (  # noqa: F401

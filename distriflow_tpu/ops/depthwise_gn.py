@@ -46,9 +46,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from distriflow_tpu.ops.flop_count import record_pallas_cost
-from distriflow_tpu.utils.compat import pallas_tpu_compiler_params
 
 GROUP_SIZE = 8  # matches the model plane: channels are multiples of 8 by
 # construction (_make_divisible), so a fixed group size always divides
@@ -58,6 +58,27 @@ MIN_CHANNELS = 8  # sliver floor: below one group there is nothing to
 VMEM_LIMIT_BYTES = 16 * 1024 * 1024  # TPU scoped-vmem compile limit
 
 _warned_gated: set = set()  # (h, w, c, stride) shapes already warned about
+
+# What the TPU compiler said when this kernel first met it (PR 21: TPU v5e,
+# jax 0.9.0, libtpu 0.0.34; MobileNetV2/96 px shapes, f32 and bf16). The
+# kernel has only ever run in the Pallas interpreter. It is the construct
+# Mosaic refuses, not a tile size, so no shape compiles; ROADMAP S3/D3 own
+# the rewrite-or-delete decision.
+MOSAIC_REFUSAL = (
+    "ops.depthwise3x3_groupnorm does not compile on TPU. Mosaic's words — "
+    "forward, stride 1: \"INTERNAL: Mosaic failed to compile TPU kernel: "
+    "infer-vector-layout: unsupported shape cast ... 'tpu.reshape' "
+    "(vector<48x48x32xf32>) -> vector<2304x4x8xf32>\" (the GroupNorm "
+    "lane split cb -> (cb/8, 8)); forward, stride 2: \"'vector."
+    "extract_strided_slice' op expected strides to be confined to [1, 2)\" "
+    "(the stride-2 lax.slice of the padded tile); backward, both strides: "
+    "\"The Pallas TPU lowering currently requires that the last two "
+    "dimensions of your block shape are divisible by 8 and 128 "
+    "respectively, or be equal to the respective dimensions of the overall "
+    "array. Block spec for outputs[2] in pallas_call depthwise_gn_bwd ... "
+    "block shape (1, C), array shape (B, C)\" (the per-batch dscale/dbias "
+    "partials). Use depthwise_impl='conv' (the default) or 'shift'."
+)
 
 
 def _same_pads(d: int, stride: int) -> Tuple[int, int]:
@@ -271,6 +292,7 @@ def _dwgn_fwd(x, w, scale, bias, stride, eps, group_size, relu6, interpret):
     b2 = bias.reshape(1, c).astype(jnp.float32)
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, tile=tile),
+        name="depthwise_gn_fwd",
         grid=(b, c // block_c),
         in_specs=[
             pl.BlockSpec((1, hp, wp, block_c), lambda bi, cb: (bi, 0, 0, cb)),
@@ -283,7 +305,7 @@ def _dwgn_fwd(x, w, scale, bias, stride, eps, group_size, relu6, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct((b, out_h, out_w, c), x.dtype),
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
     )(xp, wsq, s2, b2)
@@ -308,6 +330,7 @@ def _dwgn_bwd(stride, eps, group_size, relu6, interpret, res, g):
     b2 = bias.reshape(1, c).astype(jnp.float32)
     dxp, dwp, dsp, dbp = pl.pallas_call(
         functools.partial(_bwd_kernel, tile=tile),
+        name="depthwise_gn_bwd",
         grid=(b, c // block_c),
         in_specs=[
             pl.BlockSpec((1, hp, wp, block_c), lambda bi, cb: (bi, 0, 0, cb)),
@@ -334,7 +357,7 @@ def _dwgn_bwd(stride, eps, group_size, relu6, interpret, res, g):
             jax.ShapeDtypeStruct((b, c), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
     )(xp, wsq, s2, b2, g)

@@ -48,7 +48,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distriflow_tpu.ops.flop_count import record_pallas_cost
-from distriflow_tpu.utils.compat import pallas_tpu_compiler_params
 
 
 def _aligned_block(s: int, target: int) -> int:
@@ -393,6 +392,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(b * h, n_q, n_kv),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
@@ -419,7 +419,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
         interpret=interpret,
         # batch*head and Q-block axes are independent -> parallel; only the
         # K axis is a sequential reduction (the scratch recurrence)
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -572,6 +572,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
                 _dkvq_kernel, block_q=bq, block_k=bk, n_q=n_q, causal=causal,
                 scale=scale,
             ),
+            name="flash_attention_bwd_fused",
             grid=(b * h, n_kv, n_q),
             in_specs=[
                 pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
@@ -598,7 +599,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
                 pltpu.VMEM((bk, d), jnp.float32),  # dv accumulator
             ],
             interpret=interpret,
-            compiler_params=pallas_tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
         )(kf, vf, qf, dof, lsef, delta)
@@ -610,6 +611,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
             _dq_kernel, block_q=bq, block_k=bk, n_kv=n_kv, causal=causal,
             scale=scale,
         ),
+        name="flash_attention_bwd_dq",
         grid=(b * h, n_q, n_kv),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
@@ -623,7 +625,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(qf, kf, vf, dof, lsef, delta)
@@ -633,6 +635,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
             _dkv_kernel, block_q=bq, block_k=bk, n_q=n_q, causal=causal,
             scale=scale,
         ),
+        name="flash_attention_bwd_dkv",
         grid=(b * h, n_kv, n_q),
         in_specs=[
             pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
@@ -655,7 +658,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
             pltpu.VMEM((bk, d), jnp.float32),  # dv accumulator
         ],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(kf, vf, qf, dof, lsef, delta)
@@ -759,7 +762,15 @@ def _sharded_fa(causal: bool, interpret: Optional[bool]):
     S and D stay replicated. Mirrors ops/flash_decode.py's heads-sharded
     rule — without it, a bare pallas_call under TP-sharded activations
     forces an all-gather and runs the whole prompt's attention replicated
-    on every chip."""
+    on every chip.
+
+    Known not to work on real multi-chip TPUs with the installed stack (jax
+    0.9.0 / libtpu 0.0.34): the partitioning callback never reaches the TPU
+    compiler and the program fails with "Custom emitter for
+    CustomSPMDPartitioning not found" (four v5e chips, PR 21). On one device
+    the wrapper is transparent, and the CPU partitioner honours it, which is
+    all the tests see. Multi-chip decode needs a shard_map form (ROADMAP
+    R3/R7); ``ops/fused_ce.py`` shows one."""
     from jax.experimental.custom_partitioning import custom_partitioning
     from jax.sharding import NamedSharding, PartitionSpec as P
 

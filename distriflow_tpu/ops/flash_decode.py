@@ -79,9 +79,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distriflow_tpu.utils import compat
-from distriflow_tpu.utils.compat import pallas_tpu_compiler_params
-
 BLOCK_K = 2048  # KV positions per tile: [2048, 512] bf16 K+V tiles are
 # 2 MB each, double-buffered 8 MB — inside the 16 MB scoped-VMEM limit
 # with room for the [BK, H] f32 score/prob tensors
@@ -392,6 +389,7 @@ def flash_decode(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, n_kv),
@@ -405,7 +403,7 @@ def flash_decode(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -587,6 +585,7 @@ def flash_decode_paged(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_decode_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, n_kv),
@@ -600,7 +599,7 @@ def flash_decode_paged(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -635,7 +634,12 @@ def _sharded_fd(quant: bool, interpret: bool):
     """custom_partitioning-wrapped local kernel for one (quant, interpret)
     signature. Head-sharded: q's axis-1 sharding drives everything; the
     packed H*D cache axis and the [B, S, H] scale axis co-shard with it
-    (whole heads per shard), S stays replicated."""
+    (whole heads per shard), S stays replicated.
+
+    Like ``ops/flash_attention.py::_sharded_fa``, this does not compile on
+    real multi-chip TPUs with the installed jax/libtpu ("Custom emitter for
+    CustomSPMDPartitioning not found", PR 21); one device and the CPU
+    partitioner are unaffected."""
     from jax.experimental.custom_partitioning import custom_partitioning
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -676,8 +680,8 @@ def _sharded_fd(quant: bool, interpret: bool):
 
     rule = ("b h d, b s k, b s k, b -> b h d" if not quant else
             "b h d, b s k, b s k, b, b s j, b s j -> b h d")
-    compat.def_partition(
-        wrapped, partition=partition, infer_sharding_from_operands=infer,
+    wrapped.def_partition(
+        partition=partition, infer_sharding_from_operands=infer,
         sharding_rule=rule)
     return wrapped
 

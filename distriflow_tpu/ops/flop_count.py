@@ -53,9 +53,8 @@ def record_pallas_cost(
 
     ``category`` additionally files the cost under ``tally["by_category"]``
     so consumers can re-scale one kernel family's share — the fused CE
-    traces with GLOBAL row counts (its custom_partitioning rule splits rows
-    at compile time, invisible to an abstract trace) while the shard_map'd
-    kernels trace per-shard; ``SyncTrainer.cost_analysis`` divides the CE
+    records GLOBAL row counts (before its own per-data-shard split) while
+    the attention kernels record inside their shard_map, per shard; ``SyncTrainer.cost_analysis`` divides the CE
     share by the row-shard degree to keep the per-device convention exact.
     The roofline model (``ops/roofline.py``) consumes the same categories
     as its phase taxonomy, so a kernel family that wants a roofline row

@@ -39,11 +39,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from distriflow_tpu.ops.flop_count import record_pallas_cost
-from distriflow_tpu.utils import compat
-from distriflow_tpu.utils.compat import pallas_tpu_compiler_params
 
 BLOCK_N = 256   # 256 x 4096 f32 = 4 MB tiles: the measured sweet spot on
 BLOCK_V = 4096  # v5e (2 MB tiles ran 5x slower; 8 MB tiles blow scoped VMEM)
@@ -151,6 +149,7 @@ def _ce_call(kernel, n_outs, out_dtypes, out_cols, block_n, block_v,
     kernel = functools.partial(kernel, block_v=block_v, v_true=v)
     outs = pl.pallas_call(
         kernel,
+        name="fused_ce_fwd" if out_cols == 1 else "fused_ce_bwd",
         grid=grid,
         in_specs=specs,
         out_specs=out_specs if n_outs > 1 else out_specs[0],
@@ -163,7 +162,7 @@ def _ce_call(kernel, n_outs, out_dtypes, out_cols, block_n, block_v,
         # forward (scratch recurrence) and independent in backward — keep it
         # 'arbitrary' (sequential) in both: correct everywhere, and backward
         # row tiles still parallelize
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -182,21 +181,52 @@ def _default_interpret(interpret):
     return interpret
 
 
-# -- GSPMD partitioning (round 3) --------------------------------------------
-# pallas_call has no SPMD rule: under pjit with row-sharded logits the kernel
-# would all-gather the full [N, V] array onto every device. Rows are
-# independent, so custom_partitioning declares exactly that: shard rows over
-# whatever mesh axes the operand already uses, replicate the vocab dim, and
-# run the kernel per-shard. This is what lets the fused CE be the DEFAULT
-# loss on pure data-parallel meshes (models/transformer.py::resolved_loss_for)
-# instead of a single-device-only exhibit.
+# -- Partitioning over a mesh ------------------------------------------------
+# A Mosaic kernel cannot be partitioned automatically: inside a multi-device
+# jit the lowering refuses it unless every mesh axis is manual, i.e. unless
+# the call sits in a ``shard_map``. Rows are independent, so that is all the
+# CE needs: each device runs the kernel on its own rows of the full
+# vocabulary. The mesh comes from the trace context
+# (``jax.sharding.get_abstract_mesh()``): trainers trace their step under
+# ``jax.set_mesh(mesh)`` (``SyncTrainer``), and a ``shard_map`` body (FedAvg's
+# local loop) already is per-shard. This is what lets the fused CE be the
+# DEFAULT loss on pure data-parallel meshes
+# (models/transformer.py::resolved_loss_for).
+#
+# Until PR 21 this was a ``custom_partitioning`` rule. On the installed stack
+# (jax 0.9.0, libtpu 0.0.34) that mechanism does not reach the TPU compiler:
+# on four real chips every program containing it failed with "INVALID_ARGUMENT:
+# Custom emitter for CustomSPMDPartitioning not found". It had only ever met
+# the CPU partitioner.
 
 
-def _row_specs(arg_infos):
-    """Row-dim sharding of the logits operand; vocab forced replicated."""
-    spec = getattr(arg_infos[0].sharding, "spec", None) or P()
-    row = spec[0] if len(spec) >= 1 else None
-    return row
+def _per_data_shard(fn, out_specs):
+    """``fn(*arrays)`` — all operands row-aligned ``[N, ...]``, logits first
+    — run per shard of the context mesh: rows split over its ``data`` axis
+    (the axis every batch in this package is sharded over), everything else
+    replicated. With no mesh in context, or inside a ``shard_map`` body, the
+    call is already local and runs as is."""
+
+    def call(*arrays):
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty or mesh.are_all_axes_manual:
+            return fn(*arrays)
+        n_data = dict(mesh.shape).get("data", 1)
+        row = "data" if arrays[0].shape[0] % n_data == 0 else None
+        return jax.shard_map(
+            fn,
+            in_specs=tuple(P(row, *([None] * (a.ndim - 1))) for a in arrays),
+            out_specs=out_specs(row), check_vma=False)(*arrays)
+
+    return call
+
+
+def _loss_lse_specs(row):  # forward outputs: [N] losses, [N] logsumexps
+    return P(row), P(row)
+
+
+def _grad_specs(row):  # backward output: [N, V] gradient, vocab whole
+    return P(row, None)
 
 
 def _rows_vmappable(fn):
@@ -209,9 +239,8 @@ def _rows_vmappable(fn):
     [B*N, ...]``, re-enters the wrapped call (so nested vmaps collapse
     recursively), and splits the leading dim back out. This removes the
     need to detect batch tracers at all — ``vmap(f)``, ``jit(vmap(f))``
-    and ``vmap(jit(f))`` all reach the same rows-sharded
-    ``custom_partitioning`` kernel (which has no batching rule of its
-    own; round-3 sniffed tracers via a private JAX API and missed the
+    and ``vmap(jit(f))`` all reach the same per-shard kernel call
+    (round-3 sniffed tracers via a private JAX API and missed the
     vmap-of-jit composition)."""
     from jax.custom_batching import custom_vmap
 
@@ -233,41 +262,6 @@ def _rows_vmappable(fn):
     return wrapped
 
 
-def _cp_wrap(fn, sharding_rule, out_specs_fn, vocab_args=(0,)):
-    """Wrap ``fn(*arrays)`` (all row-aligned [N, ...] operands, logits
-    first) with a rows-sharded partitioning rule.
-
-    ``sharding_rule`` is the Shardy einsum-style rule (this JAX uses the
-    Shardy partitioner, which requires it); the ``partition`` callback
-    still provides the per-shard lowering and pins vocab replicated.
-    ``vocab_args`` lists the operand indices that are [N, V]-shaped (dense
-    targets ride along with the logits)."""
-    from jax.experimental.custom_partitioning import custom_partitioning
-
-    wrapped = custom_partitioning(fn)
-
-    def infer(mesh, arg_infos, result_infos):
-        row = _row_specs(arg_infos)
-        return out_specs_fn(mesh, row)
-
-    def partition(mesh, arg_infos, result_infos):
-        row = _row_specs(arg_infos)
-        arg_sh = []
-        for i, info in enumerate(arg_infos):
-            ndim = len(info.shape)
-            if i in vocab_args:  # [N, V]: vocab replicated
-                arg_sh.append(NamedSharding(mesh, P(row, None)))
-            else:  # row-aligned [N] or [N, 1] vectors
-                arg_sh.append(
-                    NamedSharding(mesh, P(row, *([None] * (ndim - 1)))))
-        return mesh, fn, out_specs_fn(mesh, row), tuple(arg_sh)
-
-    compat.def_partition(
-        wrapped, partition=partition, infer_sharding_from_operands=infer,
-        sharding_rule=sharding_rule)
-    return _rows_vmappable(wrapped)
-
-
 def _record_ce_cost(logits, backward):
     """Mirror the kernel's analytic cost into the trace-time tally (XLA's
     cost analysis reports 0 FLOPs for custom calls; see ops/flop_count.py).
@@ -281,8 +275,8 @@ def _record_ce_cost(logits, backward):
         flops=(3 if backward else 5) * n * v,
         bytes_accessed=(2 if backward else 1) * n * v * logits.dtype.itemsize,
         transcendentals=n * v,
-        # filed by category: N here is the GLOBAL row count (the
-        # custom_partitioning split happens at compile time, after this
+        # filed by category: N here is the GLOBAL row count (the split
+        # over the data axis happens in the per-shard call, after this
         # trace-time record) — cost_analysis divides this share by the
         # row-shard degree to keep its per-device convention exact
         category="fused_ce",
@@ -301,9 +295,9 @@ def _per_row_sparse_loss(
 
 
 @functools.lru_cache(maxsize=8)
-def _sparse_fwd_cp(block_n, block_v, interpret):
-    """Rows-sharded (custom_partitioning) sparse-CE forward for one static
-    (block_n, block_v, interpret) signature."""
+def _sparse_fwd_call(block_n, block_v, interpret):
+    """Per-data-shard sparse-CE forward for one static (block_n, block_v,
+    interpret) signature."""
 
     def fwd(logits, labels2d):
         n_v = (logits.shape[1] + block_v - 1) // block_v
@@ -314,20 +308,14 @@ def _sparse_fwd_cp(block_n, block_v, interpret):
         )
         return loss, lse
 
-    # rows (i) shard together everywhere; vocab (j) and the labels column
-    # (k) are factors the rule keeps out of row propagation
-    return _cp_wrap(
-        fwd, "i j, i k -> i, i",
-        lambda mesh, row: (NamedSharding(mesh, P(row)),
-                           NamedSharding(mesh, P(row))),
-    )
+    return _rows_vmappable(_per_data_shard(fwd, _loss_lse_specs))
 
 
 def _sparse_fwd_impl(logits, labels, block_n, block_v, interpret):
     interpret = _default_interpret(interpret)
     _record_ce_cost(logits, backward=False)
     labels2d = labels.astype(jnp.int32)[:, None]
-    return _sparse_fwd_cp(block_n, block_v, interpret)(logits, labels2d)
+    return _sparse_fwd_call(block_n, block_v, interpret)(logits, labels2d)
 
 
 def _sparse_fwd(logits, labels, block_n, block_v, interpret):
@@ -336,7 +324,7 @@ def _sparse_fwd(logits, labels, block_n, block_v, interpret):
 
 
 @functools.lru_cache(maxsize=8)
-def _sparse_bwd_cp(block_n, block_v, interpret):
+def _sparse_bwd_call(block_n, block_v, interpret):
     """Rows-sharded sparse-CE backward (grad wrt logits)."""
 
     def bwd(logits, labels2d, lse2d, g2d):
@@ -348,9 +336,7 @@ def _sparse_bwd_cp(block_n, block_v, interpret):
         )
         return grad
 
-    return _cp_wrap(
-        bwd, "i j, i k, i l, i m -> i j",
-        lambda mesh, row: NamedSharding(mesh, P(row, None)))
+    return _rows_vmappable(_per_data_shard(bwd, _grad_specs))
 
 
 def _sparse_bwd(block_n, block_v, interpret, res, g):
@@ -359,7 +345,7 @@ def _sparse_bwd(block_n, block_v, interpret, res, g):
     _record_ce_cost(logits, backward=True)
     args = (logits, labels.astype(jnp.int32)[:, None], lse[:, None],
             g.astype(jnp.float32)[:, None])
-    grad = _sparse_bwd_cp(block_n, block_v, interpret)(*args)
+    grad = _sparse_bwd_call(block_n, block_v, interpret)(*args)
     return grad, None  # integer labels get no gradient
 
 
@@ -378,7 +364,7 @@ def _per_row_loss(
 
 
 @functools.lru_cache(maxsize=8)
-def _dense_fwd_cp(block_n, block_v, interpret):
+def _dense_fwd_call(block_n, block_v, interpret):
     """Rows-sharded dense-CE forward (targets ride with the logits)."""
 
     def fwd(logits, targets):
@@ -390,18 +376,13 @@ def _dense_fwd_cp(block_n, block_v, interpret):
         )
         return loss, lse
 
-    return _cp_wrap(
-        fwd, "i j, i j -> i, i",
-        lambda mesh, row: (NamedSharding(mesh, P(row)),
-                           NamedSharding(mesh, P(row))),
-        vocab_args=(0, 1),
-    )
+    return _rows_vmappable(_per_data_shard(fwd, _loss_lse_specs))
 
 
 def _dense_fwd_impl(logits, targets, block_n, block_v, interpret):
     interpret = _default_interpret(interpret)
     _record_ce_cost(logits, backward=False)
-    return _dense_fwd_cp(block_n, block_v, interpret)(logits, targets)
+    return _dense_fwd_call(block_n, block_v, interpret)(logits, targets)
 
 
 def _dense_fwd(logits, targets, block_n, block_v, interpret):
@@ -410,7 +391,7 @@ def _dense_fwd(logits, targets, block_n, block_v, interpret):
 
 
 @functools.lru_cache(maxsize=8)
-def _dense_bwd_cp(block_n, block_v, interpret):
+def _dense_bwd_call(block_n, block_v, interpret):
     """Rows-sharded dense-CE backward (grad wrt logits)."""
 
     def bwd(logits, targets, lse2d, g2d):
@@ -422,11 +403,7 @@ def _dense_bwd_cp(block_n, block_v, interpret):
         )
         return grad
 
-    return _cp_wrap(
-        bwd, "i j, i j, i l, i m -> i j",
-        lambda mesh, row: NamedSharding(mesh, P(row, None)),
-        vocab_args=(0, 1),
-    )
+    return _rows_vmappable(_per_data_shard(bwd, _grad_specs))
 
 
 def _dense_bwd(block_n, block_v, interpret, res, g):
@@ -434,7 +411,7 @@ def _dense_bwd(block_n, block_v, interpret, res, g):
     interpret = _default_interpret(interpret)
     _record_ce_cost(logits, backward=True)
     args = (logits, targets, lse[:, None], g.astype(jnp.float32)[:, None])
-    grad = _dense_bwd_cp(block_n, block_v, interpret)(*args)
+    grad = _dense_bwd_call(block_n, block_v, interpret)(*args)
     return grad, None  # targets get no gradient (matches prior behavior)
 
 

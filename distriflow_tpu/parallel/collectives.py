@@ -18,9 +18,8 @@ from typing import Any, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from distriflow_tpu.utils.compat import shard_map
 
 AxisName = Union[str, Sequence[str]]
 

@@ -46,9 +46,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from distriflow_tpu.utils.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distriflow_tpu.parallel.collectives import pvary
@@ -77,6 +75,12 @@ def _pipeline_setup(stacked_params, x, mesh, num_microbatches, axis, data_axis):
     xs = x.reshape((m, mb) + x.shape[1:])
     batch_spec = P(None, data_axis) if d > 1 else P()
     manual = {axis} | ({data_axis} if d > 1 else set())
+    # an axis of size 1 has nothing to partition, so manual and automatic
+    # mean the same for it — but a Mosaic kernel in the stage body (flash
+    # attention, the auto choice on TPU) lowers only when EVERY mesh axis is
+    # manual. Real automatic axes (model > 1: TP inside the stages) stay
+    # automatic, and there the kernels are still refused (ROADMAP R3).
+    manual |= {a for a in mesh.axis_names if mesh.shape[a] == 1}
     perm_down = [(i, (i + 1) % p) for i in range(p)]
     return p, m, mb, d, xs, batch_spec, manual, perm_down
 

@@ -27,9 +27,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from distriflow_tpu.utils.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distriflow_tpu.parallel.collectives import pvary

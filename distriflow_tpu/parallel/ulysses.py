@@ -22,9 +22,7 @@ Requires ``n_heads`` divisible by the ``seq`` axis size.
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax import lax
-
-from distriflow_tpu.utils.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distriflow_tpu.parallel.ring_attention import blockwise_attention

@@ -498,6 +498,22 @@ class InferenceServer:
         with self._device_lock:
             self.params = params
 
+    def lower_decode(self, sampling: bool = False) -> "jax.stages.Lowered":
+        """The engine's decode-chunk program lowered at the live cache's
+        shapes (nothing runs). The cache is allocated at the first
+        admission, so serve a request first. ``chip_smoke.py`` reads the
+        compiled text for the paged flash-decode Mosaic call."""
+        if self._slot_cache is None:
+            raise RuntimeError(
+                "no KV cache yet: it is allocated at the first admission")
+        _insert, _pick, decode = _build_slot_fns(
+            self.config, self.serving.decode_chunk, sampling)
+        with self._device_lock:
+            return decode.lower(
+                self.params, self._slot_cache, self._tok, self._done,
+                self._temps, self._top_ks, self._top_ps, self._seeds,
+                self._eos)
+
     def _live_draft_params(self) -> Any:
         return self.params if self._self_draft else self.draft_params
 

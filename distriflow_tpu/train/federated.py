@@ -28,9 +28,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
-
-from distriflow_tpu.utils.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distriflow_tpu.models.base import ModelSpec, _optimizer, init_params
@@ -127,6 +125,10 @@ class FederatedAveragingTrainer:
             mesh=self.mesh,
             in_specs=(P(), P("data"), P("data")),
             out_specs=(P(), P()),
+            # Pallas kernels in the model (flash attention, fused CE: the
+            # auto choices on TPU) declare no varying-axes type on their
+            # outputs, which the check requires of every op in the body
+            check_vma=False,
         )
         return jax.jit(sharded, donate_argnums=(0,))
 

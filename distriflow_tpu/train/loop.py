@@ -1,8 +1,8 @@
 """Chunked host training loop: one device dispatch per K optimizer steps.
 
-On a tunneled or remote accelerator, per-step dispatch latency (tens to
-hundreds of ms) dominates wall clock for small models; the reference has the
-same problem in sharper form (a full serialize -> websocket -> aggregate ->
+For small models the per-step host dispatch, not the device step, sets the
+wall clock (its size on the current machine: not measured); the reference has
+the same problem in sharper form (a full serialize -> websocket -> aggregate ->
 broadcast round per step, SURVEY.md §3.3). The TPU-idiomatic fix is to run K
 steps as a device-side ``lax.scan`` (:meth:`SyncTrainer.step_many`) so one
 dispatch covers K real parameter updates.

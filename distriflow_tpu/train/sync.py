@@ -262,11 +262,9 @@ class SyncTrainer:
                     mx, my, mw = xyw
                     # re-pin each micro-slice to the batch sharding: the
                     # [B] -> [accum, B/accum] reshape above splits the
-                    # data-axis tiling into a "superdim" op sharding that
-                    # the fused CE's custom_partitioning callback cannot
-                    # parse (jax explode_superdims assertion); the
-                    # constraint keeps row shardings expressible as a
-                    # PartitionSpec and the micro-step fully data-parallel
+                    # data-axis tiling across the two new dims; the
+                    # constraint keeps every micro-batch's rows sharded over
+                    # ``data`` and the micro-step fully data-parallel
                     sh = batch_sharding(self.mesh)
                     mx, my, mw = (
                         jax.lax.with_sharding_constraint(v, sh)
@@ -303,6 +301,10 @@ class SyncTrainer:
             return TrainState(new_params, new_opt, state.step + 1, new_ema), loss
 
         self._one_step = one_step  # raw (unjitted) body, reused by step_many
+        # Every trace and dispatch of the step programs happens under
+        # ``jax.set_mesh(self.mesh)``: kernels that must run per shard (the
+        # fused CE, ops/fused_ce.py) find the mesh in the trace context
+        # instead of having it threaded through the loss registry.
         return jax.jit(one_step, donate_argnums=(0,) if donate else ())
 
     def step(self, batch: Batch) -> float:
@@ -314,7 +316,7 @@ class SyncTrainer:
         if self.state is None:
             self.init()
         batch = self._ensure_placed(batch)
-        with device_timer() as timing:
+        with device_timer() as timing, jax.set_mesh(self.mesh):
             self.state, loss = self._step_fn(self.state, batch)
             loss = float(loss)  # blocks: the step really finished
         self.last_step_ms = timing["ms"]
@@ -355,6 +357,26 @@ class SyncTrainer:
         "v3": 123e12,
     }
 
+    def _batch_structs(self, batch: Batch) -> Any:
+        """``batch`` as data-sharded ShapeDtypeStructs (shapes/dtypes only)."""
+        sharding = batch_sharding(self.mesh)
+        return jax.tree.map(
+            lambda v: jax.ShapeDtypeStruct(
+                jnp.shape(v), jnp.asarray(v).dtype if not hasattr(v, "dtype") else v.dtype,
+                sharding=sharding),
+            batch,
+        )
+
+    def lower_step(self, batch: Batch) -> jax.stages.Lowered:
+        """The jitted step program lowered for ``batch``'s shapes: nothing
+        runs and no data moves. :meth:`cost_analysis` compiles it;
+        ``chip_smoke.py`` reads the compiled text for the Mosaic custom
+        calls and the gradient all-reduce."""
+        if self.state is None:
+            self.init()
+        with jax.set_mesh(self.mesh):
+            return self._step_fn.lower(self.state, self._batch_structs(batch))
+
     def cost_analysis(self, batch: Batch) -> Dict[str, float]:
         """Cost analysis of the **per-device** step program (flops, bytes
         accessed, ...). Multiply by the mesh size for whole-mesh totals.
@@ -371,8 +393,8 @@ class SyncTrainer:
         The tally follows the same per-device convention as XLA's
         analysis, with two corrections applied here (round-3 ADVICE —
         both were documented caveats before): (a) the fused CE records
-        GLOBAL row counts (its custom_partitioning split happens at
-        compile time, invisible to the abstract trace) while the
+        GLOBAL row counts (its split over the data axis happens inside its
+        own shard_map, after the record) while the
         shard_map'd kernels trace per-shard — the CE's category share is
         divided by the mesh's ``data``-axis degree; (b) a ``lax.scan``
         body is traced once but executes ``grad_accum`` times — with
@@ -380,27 +402,17 @@ class SyncTrainer:
         (and traces at micro-batch shapes), so the whole tally is
         multiplied by ``grad_accum``. Both corrections are
         equality-tripwire-tested (tests/test_sync_train.py)."""
-        if self.state is None:
-            self.init()
-        sharding = batch_sharding(self.mesh)
-        structs = jax.tree.map(
-            lambda v: jax.ShapeDtypeStruct(
-                jnp.shape(v), jnp.asarray(v).dtype if not hasattr(v, "dtype") else v.dtype,
-                sharding=sharding),
-            batch,
-        )
+        structs = self._batch_structs(batch)
         key = tuple((s.shape, str(s.dtype)) for s in jax.tree.leaves(structs))
         if key not in self._cost_cache:
-            analysis = self._step_fn.lower(self.state, structs).compile().cost_analysis()
-            if isinstance(analysis, (list, tuple)):  # older jax returns [dict]
-                analysis = analysis[0]
-            analysis = dict(analysis)
+            analysis = dict(
+                self.lower_step(batch).compile().cost_analysis())
             from distriflow_tpu.ops.flop_count import tally_pallas_cost
 
             state_structs = jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.state
             )
-            with tally_pallas_cost() as tally:
+            with tally_pallas_cost() as tally, jax.set_mesh(self.mesh):
                 # eval_shape re-traces the raw step body, but inner
                 # custom_vjp/jit sub-traces are memoized — a warm cache
                 # (a prior step() or the compile above) replays the cached
@@ -412,7 +424,7 @@ class SyncTrainer:
                 # (cost: the next step() recompiles; analysis is cached
                 # per batch signature so this happens at most once each)
                 jax.clear_caches()
-                with tally_pallas_cost() as tally:
+                with tally_pallas_cost() as tally, jax.set_mesh(self.mesh):
                     jax.eval_shape(self._one_step, state_structs, structs)
             # correction (a): the fused CE's rows are split over the data
             # axis at compile time but recorded at global N — rescale its
@@ -677,7 +689,8 @@ class SyncTrainer:
         if self.state is None:
             self.init()
         batch = self._ensure_placed(batch)
-        self.state, loss = self._step_fn(self.state, batch)
+        with jax.set_mesh(self.mesh):
+            self.state, loss = self._step_fn(self.state, batch)
         return loss
 
     def step_many(self, batches: Batch) -> jnp.ndarray:
@@ -708,7 +721,8 @@ class SyncTrainer:
         # NB: no wall-clock recording here — the jitted scan returns on
         # dispatch (async), so timing it would measure launch cost, not the
         # K device steps; honest timing belongs to the caller's value fetch
-        self.state, losses = self._multi_fn(self.state, batches)
+        with jax.set_mesh(self.mesh):
+            self.state, losses = self._multi_fn(self.state, batches)
         self.callbacks.fire("step", self)
         need_version = self.callbacks.has("new_version") or (
             self.save_every and self.store is not None
@@ -744,11 +758,10 @@ class SyncTrainer:
             self.init()
         fn = jitted_metrics(self, self.spec, metrics)
         params = self.ema_params if use_ema else self.state.params
-        if weight is None:
-            batch = self._ensure_placed((x, y))
-            return [float(v) for v in fn(params, *batch)]
-        batch = self._ensure_placed((x, y, jnp.asarray(weight, jnp.float32)))
-        return [float(v) for v in fn(params, *batch)]
+        batch = (x, y) if weight is None else (
+            x, y, jnp.asarray(weight, jnp.float32))
+        with jax.set_mesh(self.mesh):
+            return [float(v) for v in fn(params, *self._ensure_placed(batch))]
 
     def get_params(self) -> Params:
         if self.state is None:

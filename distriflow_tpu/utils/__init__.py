@@ -1,5 +1,6 @@
 """Utility layer: configs, serialization, messages, logging, profiling."""
 
+from distriflow_tpu.utils.compile_cache import enable_compile_cache
 from distriflow_tpu.utils.config import (
     ClientHyperparams,
     CompileConfig,
@@ -42,6 +43,8 @@ from distriflow_tpu.utils.serialization import (
 )
 
 __all__ = [
+    # compile cache placement
+    "enable_compile_cache",
     # config
     "ClientHyperparams",
     "CompileConfig",

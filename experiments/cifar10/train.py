@@ -31,6 +31,7 @@ from distriflow_tpu.train.async_sgd import AsyncSGDTrainer
 from distriflow_tpu.train.federated import FederatedAveragingTrainer
 from distriflow_tpu.train.loop import evaluate_dataset, run_chunked
 from distriflow_tpu.train.sync import SyncTrainer
+from distriflow_tpu.utils.compile_cache import enable_compile_cache
 
 from experiments.cifar10.cifar_data import load_splits, to_xy, to_xy_raw
 
@@ -40,8 +41,8 @@ def run_sync(args, spec, train, val) -> float:
     raw_wire = args.wire_format == "u8"
     if raw_wire:
         # uint8 pixels + int32 labels over the wire, normalize on device:
-        # the input stream (not compute) binds throughput on tunneled or
-        # DCN-fed chips
+        # for this small model the input stream, not compute, can bind
+        # throughput
         spec = dataclasses.replace(
             with_uint8_inputs(spec), loss="sparse_softmax_cross_entropy"
         )
@@ -148,6 +149,7 @@ def main(argv=None) -> float:
                    help="sync mode: ZeRO memory sharding over the data axis")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     splits = load_splits(args.data_dir, seed=args.seed)
     spec = cifar_convnet()

@@ -23,6 +23,7 @@ from distriflow_tpu.models.mobilenet import mobilenet_v2
 from distriflow_tpu.parallel import data_parallel_mesh
 from distriflow_tpu.train.loop import evaluate_dataset, run_chunked
 from distriflow_tpu.train.sync import SyncTrainer
+from distriflow_tpu.utils.compile_cache import enable_compile_cache
 
 from experiments.imagenet_subset.data import load_splits, to_xy, to_xy_raw
 
@@ -46,6 +47,7 @@ def main(argv=None) -> float:
                    help="K optimizer steps per device dispatch (lax.scan)")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     splits = load_splits(args.data_dir, image_size=args.image_size, seed=args.seed)
     num_classes = splits["num_classes"]

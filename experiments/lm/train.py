@@ -37,6 +37,7 @@ from distriflow_tpu.parallel.sharding import (
 )
 from distriflow_tpu.train.sync import SyncTrainer
 from distriflow_tpu.train.loop import run_chunked
+from distriflow_tpu.utils.compile_cache import enable_compile_cache
 from distriflow_tpu.utils.config import MeshConfig
 
 from experiments.lm.data import VOCAB, batches, generate_corpus
@@ -110,6 +111,7 @@ def main(argv=None) -> float:
                         "interrupted; HOST:PORT or :0 for an ephemeral port")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     import jax.numpy as jnp
 
@@ -259,12 +261,21 @@ def main(argv=None) -> float:
             f"(ppl {np.exp(unigram):.1f})",
             file=sys.stderr,
         )
+    params = trainer.get_params()
+    if (args.generate or args.serve is not None) and mesh.devices.size > 1:
+        # decode and serve from ONE chip — a one-chip replica. A Mosaic
+        # kernel lowers in a multi-device program only inside a shard_map,
+        # and the decode kernels' multi-device wrappers rely on
+        # custom_partitioning, which the installed jax/libtpu does not
+        # carry to the TPU compiler (PR 21: every such program failed on
+        # four real chips). Multi-chip serving is ROADMAP R7.
+        params = jax.device_put(params, jax.devices()[0])
     if args.generate > 0:
         from distriflow_tpu.models import generate as lm_generate
 
         prompt_src = eval_corpus if eval_corpus is not None else np.asarray(ex[0])
         prompt = jnp.asarray(prompt_src[None, :gen_prompt_len], jnp.int32)
-        out = lm_generate(cfg, trainer.get_params(), prompt, args.generate)
+        out = lm_generate(cfg, params, prompt, args.generate)
         gen = np.asarray(out[0, gen_prompt_len:])
         if corpus is None:
             print(f"generated {args.generate} tokens", file=sys.stderr)
@@ -281,7 +292,7 @@ def main(argv=None) -> float:
 
         host, port = args.serve
         server = InferenceServer(
-            cfg, trainer.get_params(), host=host, port=port, verbose=True,
+            cfg, params, host=host, port=port, verbose=True,
         ).setup()
         print(f"serving inference on {server.address} — Ctrl-C to stop",
               file=sys.stderr, flush=True)

@@ -18,6 +18,7 @@ from distriflow_tpu.client import (
     DistributedClientConfig,
     FederatedClient,
 )
+from distriflow_tpu.utils.compile_cache import enable_compile_cache
 
 from experiments.mnist.mnist_data import synthetic_mnist, to_xy
 from experiments.mnist.mnist_server import create_dense_model
@@ -39,6 +40,7 @@ def main(argv=None) -> None:
                         "~50-80x on conv nets); default: whatever the "
                         "server pushes, else none")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     hp = ({"gradient_compression": args.gradient_compression}
           if args.gradient_compression else None)

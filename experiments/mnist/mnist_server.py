@@ -25,6 +25,7 @@ from distriflow_tpu.server import (
     DistributedServerInMemoryModel,
     FederatedServer,
 )
+from distriflow_tpu.utils.compile_cache import enable_compile_cache
 
 from experiments.mnist.mnist_data import load_dataset
 
@@ -102,6 +103,7 @@ def main(argv=None) -> None:
                    help="accepted for compatibility (progress logs are on by default)")
     args = p.parse_args(argv)
     args.verbose = not args.quiet
+    enable_compile_cache()
 
     server = build_server(args)
     server.setup()

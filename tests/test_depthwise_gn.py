@@ -238,3 +238,16 @@ def test_mobilenet_fused_block_matches_gated_fallback(monkeypatch):
     assert fused.shape == fallback.shape
     np.testing.assert_allclose(
         np.asarray(fused), np.asarray(fallback), atol=5e-6)
+
+
+def test_fused_refuses_to_build_where_kernels_are_compiled(monkeypatch):
+    """On a TPU backend Mosaic refuses this kernel (chip run, PR 21): the
+    spec builder must say so with the compiler's message, not fall back."""
+    import distriflow_tpu.ops as ops
+    from distriflow_tpu.models.mobilenet import mobilenet_v2
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    with pytest.raises(NotImplementedError, match="Mosaic failed to compile"):
+        mobilenet_v2(image_size=32, classes=10, depthwise_impl="fused")
+    # the other implementations are untouched by the guard
+    mobilenet_v2(image_size=32, classes=10, depthwise_impl="shift")

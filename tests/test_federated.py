@@ -134,3 +134,29 @@ def test_fedavg_checkpoint_resume(devices, tmp_path):
                     jax.tree.leaves(before)):
         np.testing.assert_array_equal(a, b)
     assert np.isfinite(t2.round(x, y))
+
+
+def test_fedavg_round_with_the_tpu_auto_kernels(devices):
+    """The LM with flash attention and the fused CE — what the auto choices
+    pick on a TPU — inside FedAvg's shard_map body. On four real chips
+    (PR 21) this failed twice over: the model wrapped the attention kernel
+    in a second shard_map over the concrete mesh, and the kernels' outputs
+    carry no varying-axes type for check_vma. Interpret mode here, same
+    trace."""
+    import jax.numpy as jnp
+
+    from distriflow_tpu.models.transformer import TransformerConfig, transformer_lm
+
+    mesh = data_parallel_mesh(devices[:2])
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=1, d_ff=64, max_seq=16,
+        dtype=jnp.float32, use_flash_attention=True,
+        loss="fused_sparse_softmax_cross_entropy")
+    t = FederatedAveragingTrainer(
+        transformer_lm(cfg, mesh=mesh, example_seq=16), mesh=mesh,
+        local_steps=2, local_batch_size=2, learning_rate=1e-2)
+    t.init(jax.random.PRNGKey(0))
+    tokens = np.random.RandomState(0).randint(0, 64, (2, 2, 2, 17))
+    xs, ys = tokens[..., :-1].astype(np.int32), tokens[..., 1:].astype(np.int32)
+    losses = [t.round(xs, ys) for _ in range(3)]
+    assert np.isfinite(losses).all() and losses[-1] < losses[0], losses

@@ -6,6 +6,7 @@ when the library is unavailable, and the integration points (mean_serialized
 aggregation, sample_batch).
 """
 
+import os
 import shutil
 
 import numpy as np
@@ -72,13 +73,72 @@ def test_mean_buffers_validates():
 
 
 def test_numpy_fallback_when_unavailable(monkeypatch):
+    # a machine with no g++ at all: the one case the numpy paths serve
     monkeypatch.setattr(native, "_lib", None)
-    monkeypatch.setattr(native, "_tried", True)
+    monkeypatch.setattr(native, "_no_compiler", True)
     monkeypatch.setattr(native, "AVAILABLE", False)
     src = np.arange(12, dtype=np.float32).reshape(4, 3)
     np.testing.assert_array_equal(native.gather_rows(src, np.array([2, 0])), src[[2, 0]])
     bufs = [np.full((3,), float(i), np.float32) for i in range(3)]
     np.testing.assert_allclose(native.mean_buffers(bufs), [1.0, 1.0, 1.0])
+
+
+def _isolated_copy(monkeypatch, tmp_path, source_suffix=b""):
+    """Point the loader at a private copy of the source tree so a test can
+    edit the source and litter the directory without touching the real one."""
+    (tmp_path / "src").mkdir(exist_ok=True)
+    with open(native._SRC, "rb") as f:
+        (tmp_path / "src" / "distriflow_native.cpp").write_bytes(
+            f.read() + source_suffix)
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(
+        native, "_SRC", str(tmp_path / "src" / "distriflow_native.cpp"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_no_compiler", False)
+    monkeypatch.setattr(native, "AVAILABLE", False)
+
+
+def test_loader_refuses_library_of_another_source(monkeypatch, tmp_path):
+    """A library whose source hash differs from the source on disk is never
+    opened: it has another name, and when the build for the current source
+    fails the loader raises instead of using it."""
+    if not HAVE_GXX:
+        pytest.skip("no g++ in this image")
+    real_lib = native._lib_path()
+    assert native.ensure_built() and os.path.exists(real_lib)
+    _isolated_copy(monkeypatch, tmp_path, b"\n// edited\n")
+    wanted = native._lib_path()
+    assert os.path.basename(wanted) != os.path.basename(real_lib)
+    # the other revision's (perfectly loadable) library sits right there
+    stale = tmp_path / os.path.basename(real_lib)
+    shutil.copy(real_lib, stale)
+
+    def refuse(lib_path):
+        raise RuntimeError("native build failed (scripted)")
+
+    monkeypatch.setattr(native, "_build", refuse)
+    with pytest.raises(RuntimeError, match="native build failed"):
+        native.ensure_built()
+    assert native._lib is None and not native.AVAILABLE
+    # with a working compiler the current source is built under its own
+    # name, loaded, and the other revision's file is removed
+    monkeypatch.undo()
+    _isolated_copy(monkeypatch, tmp_path, b"\n// edited\n")
+    assert native.ensure_built()
+    assert os.path.exists(wanted) and not stale.exists()
+    np.testing.assert_array_equal(
+        native.gather_rows(np.arange(6.0).reshape(3, 2), np.array([2, 0])),
+        [[4.0, 5.0], [0.0, 1.0]])
+
+
+def test_failed_build_raises_with_compiler_output(monkeypatch, tmp_path):
+    if not HAVE_GXX:
+        pytest.skip("no g++ in this image")
+    _isolated_copy(monkeypatch, tmp_path, b"\nthis is not C++\n")
+    with pytest.raises(RuntimeError, match="native build failed"):
+        native.gather_rows(np.zeros((2, 2), np.float32), np.array([0]))
+    assert not any(p.suffix == ".so" or p.name.endswith(".tmp")
+                   for p in tmp_path.iterdir())
 
 
 # -- integration points ------------------------------------------------------

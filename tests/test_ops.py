@@ -366,8 +366,8 @@ def test_flagship_loss_resolution(devices, monkeypatch):
     assert transformer_lm(cfg, example_seq=8).loss == (
         "fused_sparse_softmax_cross_entropy"
     )
-    # pure data-parallel mesh: fused stays the default (the kernel carries
-    # a rows-sharded custom_partitioning rule)
+    # pure data-parallel mesh: fused stays the default (the kernel runs
+    # per data shard of the context mesh)
     mesh = data_parallel_mesh(devices)
     assert cfg.resolved_loss_for(mesh) == "fused_sparse_softmax_cross_entropy"
     # ... but meshes that shard the vocab (model/pipe) or the seq dim back
@@ -392,10 +392,10 @@ def test_flagship_loss_resolution(devices, monkeypatch):
 
 
 def test_fused_ce_partitioned_no_allgather(devices):
-    """The fused sparse CE's custom_partitioning rule keeps row-sharded
-    logits sharded: values and grads match the unfused oracle, the grad
-    stays row-sharded, and the compiled program contains NO all-gather
-    (the failure mode the partitioning exists to prevent)."""
+    """Under a context mesh the fused sparse CE runs per data shard and
+    keeps row-sharded logits sharded: values and grads match the unfused
+    oracle, the grad stays row-sharded, and the compiled program contains
+    NO all-gather (the failure mode the partitioning exists to prevent)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from distriflow_tpu.ops import fused_sparse_softmax_cross_entropy
@@ -413,19 +413,25 @@ def test_fused_ce_partitioned_no_allgather(devices):
     f = jax.jit(loss)
     ref = float(jnp.mean(
         optax.softmax_cross_entropy_with_integer_labels(logits, labels)))
-    assert abs(float(f(logits_s, labels_s)) - ref) < 1e-5
-    g = jax.jit(jax.grad(loss))(logits_s, labels_s)
+    with jax.set_mesh(mesh):  # what SyncTrainer does around its step
+        assert abs(float(f(logits_s, labels_s)) - ref) < 1e-5
+        g = jax.jit(jax.grad(loss))(logits_s, labels_s)
+        hlo = f.lower(logits_s, labels_s).compile().as_text()
     g_ref = jax.grad(lambda lg: jnp.mean(
         optax.softmax_cross_entropy_with_integer_labels(lg, labels)))(logits)
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=1e-6)
     assert tuple(g.sharding.spec)[:1] == ("data",)  # rows stay sharded
-    hlo = f.lower(logits_s, labels_s).compile().as_text()
     assert "all-gather" not in hlo
+    # rows that do not divide over the data axis still compute: replicated
+    with jax.set_mesh(mesh):
+        odd = float(f(logits[:60], labels[:60]))
+    assert abs(odd - float(jnp.mean(
+        optax.softmax_cross_entropy_with_integer_labels(
+            logits[:60], labels[:60])))) < 1e-5
 
 
 def test_fused_sparse_ce_vmap_still_works():
-    """custom_partitioning has no batching rule of its own; the kernel
-    wrapper's custom_vmap rule collapses the batch axis into rows, so
+    """The kernel wrapper's custom_vmap rule collapses the batch axis into rows, so
     vmap over the public op keeps working — including the jit
     compositions in both orders (round-3 sniffed batch tracers and
     failed under ``vmap(jit(f))``)."""
@@ -448,7 +454,7 @@ def test_fused_sparse_ce_vmap_still_works():
 
 def test_fused_sparse_ce_vmap_jit_compositions():
     """The round-3 hole: ``vmap(jit(loss))`` hid the batch trace from the
-    tracer probe and the custom_partitioning primitive failed under vmap.
+    tracer probe and the partitioned kernel call failed under vmap.
     The batching rule makes every composition order work, values AND
     grads, plus nested vmap."""
     from distriflow_tpu.ops import fused_sparse_softmax_cross_entropy_per_example
@@ -514,11 +520,12 @@ def test_fused_dense_ce_partitioned_and_vmap(devices):
     onehot = jnp.eye(40, dtype=jnp.float32)[rng.randint(0, 40, 64)]
     sh2 = NamedSharding(mesh, P("data", None))
     f = jax.jit(lambda l, t: fused_softmax_cross_entropy(l, t))
-    got = float(f(jax.device_put(logits, sh2), jax.device_put(onehot, sh2)))
+    with jax.set_mesh(mesh):
+        got = float(f(jax.device_put(logits, sh2), jax.device_put(onehot, sh2)))
+        hlo = f.lower(jax.device_put(logits, sh2),
+                      jax.device_put(onehot, sh2)).compile().as_text()
     want = float(jnp.mean(optax.softmax_cross_entropy(logits, onehot)))
     assert abs(got - want) < 1e-5
-    hlo = f.lower(jax.device_put(logits, sh2),
-                  jax.device_put(onehot, sh2)).compile().as_text()
     assert "all-gather" not in hlo
     # vmap fallback
     bl = jnp.asarray(rng.randn(3, 8, 12).astype(np.float32))

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from distriflow_tpu.parallel import (
@@ -67,8 +68,6 @@ def test_allreduce_mean_matches_numpy(devices):
 
 
 def test_pmean_inside_shard_map(devices):
-    from distriflow_tpu.utils.compat import shard_map
-
     mesh = data_parallel_mesh(devices)
 
     def f(x):
@@ -80,8 +79,6 @@ def test_pmean_inside_shard_map(devices):
 
 
 def test_ppermute_ring_rotates(devices):
-    from distriflow_tpu.utils.compat import shard_map
-
     mesh = data_parallel_mesh(devices)
 
     def f(x):
