@@ -400,6 +400,11 @@ class ServerTransport:
         self._c_corrupt_rx = self.telemetry.counter(
             "transport_frames_corrupt_rx_total", role="server",
             help="received frames rejected by checksum/decode")
+        self._g_busy = self.telemetry.gauge(
+            "transport_handlers_busy",
+            help="event handlers running on executor threads right now")
+        self._busy_lock = threading.Lock()  # the gauge's inc/dec are not atomic
+        self._h_wait: Dict[str, Any] = {}  # event -> handler-wait histogram
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
         self._server: Optional[asyncio.AbstractServer] = None
@@ -470,6 +475,50 @@ class ServerTransport:
         """Register ``handler(client_id, payload) -> ack_result | None``."""
         self._handlers[event] = handler
 
+    def _run_handler(self, handler: Callable[[str, Any], Any], client_id: str,
+                     event: str, payload: Any, handed: float) -> Any:
+        """``handler`` on an executor thread. ``handed`` is the monotonic
+        stamp at which the read loop gave the frame to the executor: the
+        time from there to here is the wait for a free pool thread, which
+        no later stamp of the handler's own can see (a generate handler
+        holds its thread for the request's life, so the default pool caps
+        the requests in flight). With telemetry on it is one
+        ``handler_wait`` span, in the request's trace when the payload
+        carries one, and one ``transport_handler_wait_ms{event}``
+        observation; with telemetry off, two clock reads."""
+        started = time.monotonic()
+        tel = self.telemetry
+        if not tel.enabled:
+            return handler(client_id, payload)
+        wait_ms = (started - handed) * 1000.0
+        hist = self._h_wait.get(event)
+        if hist is None:
+            hist = self._h_wait[event] = tel.histogram(
+                "transport_handler_wait_ms", event=event,
+                help="frame handed to the executor -> handler running on "
+                     "a pool thread (ms), per event")
+        hist.observe(wait_ms)
+        trace_id = payload.get("trace_id") if isinstance(payload, dict) else None
+        if trace_id and tel.tracer.enabled:
+            # like the engine's request spans: only a frame that carries a
+            # trace gets a row (untraced traffic opens no one-span traces),
+            # with the request_id / tier the assembler merges attempts on
+            rid = payload.get("request_id")
+            tel.tracer.emit(
+                "handler_wait", trace_id=str(trace_id),
+                parent_id=str(payload.get("span_id") or "") or None,
+                dur_ms=wait_ms, start=time.time() - (started - handed),
+                mono=handed, event=event,
+                request_id=str(rid) if rid is not None else None,
+                tier=min(max(int(payload.get("tier", 0) or 0), 0), 2))
+        with self._busy_lock:
+            self._g_busy.inc()
+        try:
+            return handler(client_id, payload)
+        finally:
+            with self._busy_lock:
+                self._g_busy.dec()
+
     async def _reap_dead_clients(self) -> None:
         """Evict clients with no traffic inside the heartbeat timeout.
 
@@ -511,7 +560,8 @@ class ServerTransport:
                 # run in executor: handlers do jax work and take locks
                 try:
                     result = await self._loop.run_in_executor(
-                        None, handler, client_id, msg.get("payload")
+                        None, self._run_handler, handler, client_id,
+                        msg.get("event"), msg.get("payload"), time.monotonic()
                     )
                 except Exception as e:
                     # a failing handler must not kill the connection

@@ -24,11 +24,23 @@ Prometheus / jsonl export surfaces for free. Per step, by construction:
 ``submit`` — still gets its own digest but is not double-counted in the
 step attribution).
 
+The device's clock: every live phase and step also opens a
+``jax.profiler.TraceAnnotation`` named ``df/<role>/<phase>``
+(``df/<role>/step`` for a step) for its life. While a ``jax.profiler``
+capture runs, the annotations land on the host plane of the same
+``.xplane.pb`` as the device's ops, on one clock, so a reader can say
+which phase was open while the chip sat idle (``benchmark/lib/
+annotations.py``); keyword stats given to ``phase()`` ride the event.
+With no capture running an annotation is a flag check in the profiler's
+C++ runtime. This is the one facility that shares the device's clock;
+the histograms above stay on the host's.
+
 Cheapness contract (pinned by ``tests/test_obs.py``): a disabled
 ``Telemetry`` hands out the shared :data:`NOOP_PROFILER`, whose
 ``phase()`` / ``step()`` return the shared :data:`NOOP_PHASE` context
-manager — nothing is allocated per step, nothing is registered. Enabled
-phases cost two ``perf_counter`` calls plus one histogram observe.
+manager — nothing is allocated per step, nothing is registered, nothing
+is annotated. Enabled phases cost two ``perf_counter`` calls, one
+histogram observe and one annotation.
 """
 
 from __future__ import annotations
@@ -37,6 +49,9 @@ import threading
 from time import perf_counter
 from typing import Any, Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
+ANNOTATION_PREFIX = "df"
 STEP_WALL = "phase_step_wall_ms"
 STEP_OVERLAP = "phase_step_overlap_ms"
 STEP_IDLE = "phase_step_idle_ms"
@@ -64,8 +79,9 @@ class _NoopProfiler:
     __slots__ = ()
 
     role = ""
+    enabled = False
 
-    def phase(self, name: str) -> _NoopPhase:
+    def phase(self, name: str, **stats: Any) -> _NoopPhase:
         return NOOP_PHASE
 
     def step(self) -> _NoopPhase:
@@ -92,22 +108,25 @@ class _Phase:
     and feeds the enclosing step's busy sum when it is the OUTERMOST
     phase on this thread (nesting tracked via the step's depth)."""
 
-    __slots__ = ("_prof", "_hist", "_t0")
+    __slots__ = ("_prof", "_hist", "_note", "_t0")
 
-    def __init__(self, prof: "PhaseProfiler", hist: Any):
+    def __init__(self, prof: "PhaseProfiler", hist: Any, note: Any):
         self._prof = prof
         self._hist = hist
+        self._note = note  # the phase's TraceAnnotation
         self._t0 = 0.0
 
     def __enter__(self) -> "_Phase":
         step = getattr(self._prof._local, "step", None)
         if step is not None:
             step.depth += 1
+        self._note.__enter__()
         self._t0 = perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         dur = (perf_counter() - self._t0) * 1e3
+        self._note.__exit__(*exc)
         self._hist.observe(dur)
         step = getattr(self._prof._local, "step", None)
         if step is not None:
@@ -121,10 +140,11 @@ class _Step:
     outermost phases run on this thread, and observes the wall /
     overlap / idle digests on exit. Steps do not nest."""
 
-    __slots__ = ("_prof", "_t0", "busy", "depth")
+    __slots__ = ("_prof", "_note", "_t0", "busy", "depth")
 
     def __init__(self, prof: "PhaseProfiler"):
         self._prof = prof
+        self._note = TraceAnnotation(prof._step_label)
         self._t0 = 0.0
         self.busy = 0.0
         self.depth = 0
@@ -133,11 +153,13 @@ class _Step:
         self.busy = 0.0
         self.depth = 0
         self._prof._local.step = self
+        self._note.__enter__()
         self._t0 = perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         wall = (perf_counter() - self._t0) * 1e3
+        self._note.__exit__(*exc)
         self._prof._local.step = None
         self._prof._h_wall.observe(wall)
         self._prof._h_overlap.observe(max(0.0, self.busy - wall))
@@ -155,9 +177,12 @@ class PhaseProfiler:
     accountings can never drift).
     """
 
+    enabled = True
+
     def __init__(self, registry: Any, role: str):
         self.role = role
         self._registry = registry
+        self._step_label = f"{ANNOTATION_PREFIX}/{role}/step"
         self._hists: Dict[str, Any] = {}  # guarded-by: _lock
         self._lock = threading.Lock()
         self._local = threading.local()
@@ -185,9 +210,12 @@ class PhaseProfiler:
                     self._hists[name] = h
         return h
 
-    def phase(self, name: str) -> _Phase:
-        """A context manager timing one phase into its rolling digest."""
-        return _Phase(self, self._hist(name))
+    def phase(self, name: str, **stats: Any) -> _Phase:
+        """A context manager timing one phase into its rolling digest
+        and annotating it on the profiler's clock; ``stats`` ride the
+        annotation's event (numbers or short strings)."""
+        return _Phase(self, self._hist(name), TraceAnnotation(
+            f"{ANNOTATION_PREFIX}/{self.role}/{name}", **stats))
 
     def step(self) -> _Step:
         """A context manager bounding one step for wall/overlap/idle

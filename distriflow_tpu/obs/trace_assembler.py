@@ -76,6 +76,8 @@ _PRIORITY = {
     "admission": 5,
     "retire": 4,
     "queue_wait": 3,
+    # the frame's wait for an executor thread, before the enqueue stamp
+    "handler_wait": 3,
     "route": 2,
     "request": 1,
 }

@@ -79,6 +79,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distriflow_tpu.ops.flop_count import record_pallas_cost
+
 BLOCK_K = 2048  # KV positions per tile: [2048, 512] bf16 K+V tiles are
 # 2 MB each, double-buffered 8 MB — inside the 16 MB scoped-VMEM limit
 # with room for the [BK, H] f32 score/prob tensors
@@ -268,6 +270,26 @@ def _decode_kernel_quant(len_ref, qbd_ref, qs_ref, k_ref, ks_ref, v_ref,
                  p_scale=vs_ref[0])
 
 
+def _record_decode_cost(positions: int, hd: int, kv_item: int,
+                        quant: bool, h: int) -> None:
+    """One call's cost in the trace-time tally (``ops/flop_count.py``),
+    like the other kernels'. ``positions`` is the cache extent the grid
+    visits, batch rows x positions per row: the lengths are runtime values,
+    so what is known at trace time is the extent, and a tile past a row's
+    length is still fetched (its scores are masked). q.K^T and p.V are 2
+    FLOPs per position per feature each; K and V are read once (int8
+    caches add their f32 per-head scales); queries and outputs are
+    negligible. Equals ``benchmark/lib/flops.flash_decode(positions, hd,
+    kv_item)`` for an unquantized cache."""
+    scale_bytes = 2 * positions * h * 4 if quant else 0
+    record_pallas_cost(
+        flops=4 * positions * hd,
+        bytes_accessed=2 * positions * hd * kv_item + scale_bytes,
+        transcendentals=positions * h,
+        category="attention_decode",
+    )
+
+
 def _resolve_interpret(interpret):
     if interpret is None:
         from distriflow_tpu.ops import default_interpret
@@ -334,6 +356,7 @@ def flash_decode(
             "pass a smaller block_k (a divisor of the cache length, "
             "multiple of 8), or let block_k=None pick one")
     n_kv = s // block_k
+    _record_decode_cost(b * s, hd, kv_item, quant, h)
     # scalar-prefetch lengths, one per batch row (a scalar broadcasts:
     # the homogeneous static-batch callers keep their old semantics)
     lens = jnp.broadcast_to(
@@ -533,6 +556,7 @@ def flash_decode_paged(
             f"for page_size={ps}, packed dim {hd} exceeds the "
             f"{VMEM_LIMIT_BYTES / 1e6:.0f} MB TPU limit — shrink page_size")
     n_kv = page_table.shape[1]
+    _record_decode_cost(b * n_kv * ps, hd, kv_item, quant, h)
     # pre-clamp sentinels so the index map is a plain table read
     tab = jnp.minimum(page_table.astype(jnp.int32), n_pages - 1)
     lens = jnp.broadcast_to(

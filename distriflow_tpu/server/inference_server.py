@@ -252,7 +252,9 @@ class InferenceServer:
         self.serving = (serving or ServingConfig()).validate()
         self.logger = VerboseLogger("InferenceServer", verbose)
         self._device_lock = threading.Lock()  # one device program at a time
-        self.transport = ServerTransport(host, port)
+        # the server's telemetry, so the transport's frame counters and its
+        # handler_wait spans land where the engine's request spans do
+        self.transport = ServerTransport(host, port, telemetry=telemetry)
         self.transport.on("model_info", self._on_info)
         self.transport.on("generate", self._on_generate)
         self.transport.on("beam", self._on_beam)
@@ -445,7 +447,7 @@ class InferenceServer:
         # continuous phase profiler (docs/OBSERVABILITY.md §5): serving
         # records phases only — the engine loop mostly idles in _gather, so
         # a per-iteration step() would drown the digests in idle wall time
-        self._prof = tel.profiler("serving")
+        self._prof = tel.profiler("engine")
         # fleet rows for the serving side: under the paged layout each
         # client's row carries the KV pages it currently holds, so a soak
         # operator can spot the connection pinning the pool
@@ -828,22 +830,27 @@ class InferenceServer:
             # quiescence: no backlog, no live slot, no uncommitted plan —
             # every pool page must be free, slot-held, or prefix-shared
             self.verify_pool_conservation("engine idle")
-            item = self._queue.get()
+            # phase("gather"): the engine has no work, so a chip idle under
+            # it waits for traffic, not for the scheduler
+            with self._prof.phase("gather"):
+                item = self._queue.get()
             if item is None:
                 return True
             self._backlog.append(item)
             deadline = time_mod.monotonic() + self._window_s()
-            while True:
-                remaining = deadline - time_mod.monotonic()
-                if remaining <= 0:
-                    return False
-                try:
-                    nxt = self._queue.get(timeout=remaining)
-                except queue_mod.Empty:
-                    return False
-                if nxt is None:
-                    return True
-                self._backlog.append(nxt)
+            # the collection window is the scheduler's own choice to wait
+            with self._prof.phase("batch_window"):
+                while True:
+                    remaining = deadline - time_mod.monotonic()
+                    if remaining <= 0:
+                        return False
+                    try:
+                        nxt = self._queue.get(timeout=remaining)
+                    except queue_mod.Empty:
+                        return False
+                    if nxt is None:
+                        return True
+                    self._backlog.append(nxt)
         while True:
             try:
                 nxt = self._queue.get_nowait()
@@ -1183,19 +1190,22 @@ class InferenceServer:
                 for i in range(pc, plen, pc):
                     logits, row_cache = extend(
                         self.params, row_cache, stacked[:, i:i + pc])
-            if self._paged:
-                self._slot_cache = insert_paged(
-                    self._slot_cache, row_cache, slots, np.int32(plen),
-                    np.int32(shared_len), self._tables.copy())
-                # insert carries the FULL host table to the device, so any
-                # pending sentinel edits from retired slots ride along
-                self._tables_dirty = False
-            else:
-                self._slot_cache = insert(
-                    self._slot_cache, row_cache, slots, np.int32(plen))
-            first = np.asarray(pick_rows(
-                logits, temps, top_ks, top_ps, seeds,
-                np.full((bucket,), plen, np.int32)))[:n]
+            with self._prof.phase("page_insert"):
+                if self._paged:
+                    self._slot_cache = insert_paged(
+                        self._slot_cache, row_cache, slots, np.int32(plen),
+                        np.int32(shared_len), self._tables.copy())
+                    # insert carries the FULL host table to the device, so
+                    # any pending sentinel edits from retired slots ride
+                    # along
+                    self._tables_dirty = False
+                else:
+                    self._slot_cache = insert(
+                        self._slot_cache, row_cache, slots, np.int32(plen))
+            with self._prof.phase("first_token_fetch"):
+                first = np.asarray(pick_rows(
+                    logits, temps, top_ks, top_ps, seeds,
+                    np.full((bucket,), plen, np.int32)))[:n]
         pf1 = time_mod.monotonic()  # first tokens are on the host now
         if self._spec_k:
             # the draft prefills the FULL prompt: even when the target rode
@@ -1286,7 +1296,15 @@ class InferenceServer:
         if self._spec_k:
             self._spec_round(active)
             return
-        with self._prof.phase("decode_iter"):
+        stats: Dict[str, int] = {}
+        if self._prof.enabled:
+            # what single-query attention reads this dispatch, on the
+            # annotation: live rows and their cached context (prompt plus
+            # emitted), so a trace reader needs no second clock for it
+            stats = {"n_active": len(active), "ctx_tokens": sum(
+                self._slot_req[s].prompt.shape[1] + int(self._slot_emitted[s])
+                for s in active)}
+        with self._prof.phase("decode_iter", **stats):
             sampling = bool((self._temps[active] > 0).any())
             _insert, _pick, decode = _build_slot_fns(
                 self.config, srv.decode_chunk, sampling)
@@ -1298,60 +1316,68 @@ class InferenceServer:
                     # host; push the table before dispatch so a frozen
                     # row's continued appends drop instead of landing in
                     # pages the pool may already have re-issued
-                    self._slot_cache = set_page_tables(
-                        self._slot_cache, self._tables.copy())
+                    with self._prof.phase("table_push"):
+                        self._slot_cache = set_page_tables(
+                            self._slot_cache, self._tables.copy())
                     self._tables_dirty = False
-                cache, tok, done, toks = decode(
-                    self.params, self._slot_cache, self._tok, self._done,
-                    self._temps, self._top_ks, self._top_ps, self._seeds,
-                    self._eos)
+                td0 = time_mod.monotonic()
+                with self._prof.phase("decode_dispatch"):
+                    cache, tok, done, toks = decode(
+                        self.params, self._slot_cache, self._tok, self._done,
+                        self._temps, self._top_ks, self._top_ps, self._seeds,
+                        self._eos)
                 self._slot_cache = cache
-                # np.array, not np.asarray: device outputs arrive as
-                # read-only views, and the slot state is mutated in place
-                # below
-                tok = np.array(tok)
-                done = np.array(done)
-                toks = np.array(toks)
+                td1 = time_mod.monotonic()
+                with self._prof.phase("token_fetch"):
+                    # np.array, not np.asarray: device outputs arrive as
+                    # read-only views, and the slot state is mutated in
+                    # place below
+                    tok = np.array(tok)
+                    done = np.array(done)
+                    toks = np.array(toks)
             t1 = time_mod.monotonic()
             elapsed_ms = (t1 - t0) * 1000.0
+            dispatch_ms = round((td1 - td0) * 1000.0, 3)
+            fetch_ms = round((t1 - td1) * 1000.0, 3)
             self.decode_batches += 1
             self._m_batches.inc()
             self._tok = tok
             self._done = done
             emitted_now = 0
-            for s in active:
-                req = self._slot_req[s]
-                row = int(self._slot_row[s])
-                have = int(self._slot_emitted[s])
-                take = min(srv.decode_chunk, req.n_tokens - have)
-                chunk_toks = toks[s, :take].astype(np.int32)
-                emitted_now += take
-                self._slot_emitted[s] = have + take
-                # per-slot decode-interval TPOT (satellite 1): time since
-                # THIS slot last emitted, per token it emitted now — the
-                # old batch-level observe divided one dispatch across all
-                # active slots and conflated every co-resident request
-                if take > 0:
-                    self._m_tpot[req.tier].observe(
-                        (t1 - self._slot_emit_t[s]) * 1000.0 / take)
-                self._slot_emit_t[s] = t1
-                self._req_span(req, "decode_iter", t0, elapsed_ms,
-                               slot=s, n_active=len(active), take=take,
-                               share=round(elapsed_ms / len(active), 3))
-                req.rows_out[row] = np.concatenate(
-                    [req.rows_out[row], chunk_toks])
-                if done[s]:
-                    # row froze to eos inside the scan; pad the remaining
-                    # budget with eos — bit-identical to the solo path's
-                    # frozen-row output — and answer the caller NOW
-                    pad = req.n_tokens - have - take
-                    if pad:
-                        req.rows_out[row] = np.concatenate([
-                            req.rows_out[row],
-                            np.full((pad,), req.eos, np.int32)])
-                    self._complete_row(s)
-                elif have + take >= req.n_tokens:
-                    self._complete_row(s)
+            with self._prof.phase("emit"):
+                for s in active:
+                    req = self._slot_req[s]
+                    row = int(self._slot_row[s])
+                    have = int(self._slot_emitted[s])
+                    take = min(srv.decode_chunk, req.n_tokens - have)
+                    chunk_toks = toks[s, :take].astype(np.int32)
+                    emitted_now += take
+                    self._slot_emitted[s] = have + take
+                    # per-slot decode-interval TPOT (satellite 1): time since
+                    # THIS slot last emitted, per token it emitted now — the
+                    # old batch-level observe divided one dispatch across all
+                    # active slots and conflated every co-resident request
+                    if take > 0:
+                        self._m_tpot[req.tier].observe(
+                            (t1 - self._slot_emit_t[s]) * 1000.0 / take)
+                    self._slot_emit_t[s] = t1
+                    self._req_span(req, "decode_iter", t0, elapsed_ms,
+                                   slot=s, n_active=len(active), take=take,
+                                   dispatch_ms=dispatch_ms, fetch_ms=fetch_ms)
+                    req.rows_out[row] = np.concatenate(
+                        [req.rows_out[row], chunk_toks])
+                    if done[s]:
+                        # row froze to eos inside the scan; pad the remaining
+                        # budget with eos — bit-identical to the solo path's
+                        # frozen-row output — and answer the caller NOW
+                        pad = req.n_tokens - have - take
+                        if pad:
+                            req.rows_out[row] = np.concatenate([
+                                req.rows_out[row],
+                                np.full((pad,), req.eos, np.int32)])
+                        self._complete_row(s)
+                    elif have + take >= req.n_tokens:
+                        self._complete_row(s)
             self._m_tokens.inc(emitted_now)
             self._m_slots.set(
                 sum(1 for r in self._slot_req if r is not None))

@@ -153,6 +153,10 @@ class SyncTrainer:
         # observability (reference time()/log wrappers, abstract_server.ts:92-103)
         self.last_step_ms: Optional[float] = None
         self._step_times: List[float] = []  # rolling window
+        # steps this trainer has dispatched, counted on the host: the
+        # ``train_step`` trace marker's step_num (state.step is a device
+        # value, and reading it would sync the pipeline)
+        self._steps_dispatched = 0
         self._h_step = get_telemetry().histogram(
             "train_step_ms", mode="sync",
             help="wall time per training step/round (ms), by mode")
@@ -229,7 +233,11 @@ class SyncTrainer:
         ema_decay = self.ema_decay
 
         def loss_fn(params: Params, x, y, w) -> jnp.ndarray:
-            return spec.loss_fn(params, x, y, w)
+            # scopes are HLO metadata only (op_name): a device trace reads
+            # "forward" for these ops and "transpose(...forward...)" for
+            # their backward; the compiled code does not change
+            with jax.named_scope("forward"):
+                return spec.loss_fn(params, x, y, w)
 
         def constrain_grads(grads):
             # ZeRO-2: pin the gradient sharding so XLA materializes only
@@ -241,7 +249,12 @@ class SyncTrainer:
                     grads, self._zero_grad_shardings)
             return grads
 
-        def one_step(state: TrainState, batch):
+        def train_step(state: TrainState, batch):
+            # named ``train_step`` since the scopes below came in: jax's
+            # persistent compile cache keys a program on its name and code
+            # but not on op metadata, so under the old name a cache filled
+            # before the scopes existed would hand back an executable
+            # without them, and a device trace would read no scope
             x, y, w = batch if len(batch) == 3 else (*batch, None)
             if accum > 1 and x.shape[0] % accum:
                 raise ValueError(
@@ -283,29 +296,30 @@ class SyncTrainer:
             else:
                 loss, grads = jax.value_and_grad(loss_fn)(state.params, x, y, w)
                 grads = constrain_grads(grads)
-            updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
-            if self.zero_level >= 2 and self._param_shardings is not None:
-                # ZeRO-2 contract: the sharded update all-gathers back to
-                # the param layout (otherwise XLA propagates the grad
-                # sharding into the params and every consumer sees sharded
-                # weights — a layout change, not a memory win)
-                new_params = jax.lax.with_sharding_constraint(
-                    new_params, self._param_shardings)
-            new_ema = state.ema
-            if ema_decay is not None:
-                new_ema = jax.tree.map(
-                    lambda e, p: ema_decay * e + (1.0 - ema_decay) * p.astype(e.dtype),
-                    state.ema, new_params,
-                )
+            with jax.named_scope("optimizer"):
+                updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
+                if self.zero_level >= 2 and self._param_shardings is not None:
+                    # ZeRO-2 contract: the sharded update all-gathers back to
+                    # the param layout (otherwise XLA propagates the grad
+                    # sharding into the params and every consumer sees sharded
+                    # weights — a layout change, not a memory win)
+                    new_params = jax.lax.with_sharding_constraint(
+                        new_params, self._param_shardings)
+                new_ema = state.ema
+                if ema_decay is not None:
+                    new_ema = jax.tree.map(
+                        lambda e, p: ema_decay * e + (1.0 - ema_decay) * p.astype(e.dtype),
+                        state.ema, new_params,
+                    )
             return TrainState(new_params, new_opt, state.step + 1, new_ema), loss
 
-        self._one_step = one_step  # raw (unjitted) body, reused by step_many
+        self._one_step = train_step  # raw (unjitted) body, reused by step_many
         # Every trace and dispatch of the step programs happens under
         # ``jax.set_mesh(self.mesh)``: kernels that must run per shard (the
         # fused CE, ops/fused_ce.py) find the mesh in the trace context
         # instead of having it threaded through the loss registry.
-        return jax.jit(one_step, donate_argnums=(0,) if donate else ())
+        return jax.jit(train_step, donate_argnums=(0,) if donate else ())
 
     def step(self, batch: Batch) -> float:
         """Run one global step; returns the (replicated) loss.
@@ -316,9 +330,12 @@ class SyncTrainer:
         if self.state is None:
             self.init()
         batch = self._ensure_placed(batch)
-        with device_timer() as timing, jax.set_mesh(self.mesh):
+        with device_timer() as timing, jax.set_mesh(self.mesh), \
+                jax.profiler.StepTraceAnnotation(
+                    "train_step", step_num=self._steps_dispatched):
             self.state, loss = self._step_fn(self.state, batch)
             loss = float(loss)  # blocks: the step really finished
+        self._steps_dispatched += 1
         self.last_step_ms = timing["ms"]
         self._h_step.observe(self.last_step_ms)
         self._step_times.append(self.last_step_ms)
@@ -713,16 +730,18 @@ class SyncTrainer:
         if getattr(self, "_multi_fn", None) is None:
             one = self._one_step
 
-            def many(state, bt):
+            def train_steps(state, bt):
                 return jax.lax.scan(one, state, bt)
 
             self._multi_fn = jax.jit(
-                many, donate_argnums=(0,) if self._donate else ())
+                train_steps, donate_argnums=(0,) if self._donate else ())
         # NB: no wall-clock recording here — the jitted scan returns on
         # dispatch (async), so timing it would measure launch cost, not the
         # K device steps; honest timing belongs to the caller's value fetch
-        with jax.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh), jax.profiler.StepTraceAnnotation(
+                "train_step", step_num=self._steps_dispatched):
             self.state, losses = self._multi_fn(self.state, batches)
+        self._steps_dispatched += k
         self.callbacks.fire("step", self)
         need_version = self.callbacks.has("new_version") or (
             self.save_every and self.store is not None

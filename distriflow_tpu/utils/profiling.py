@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Any, Iterator, Optional
+from typing import Iterator, Optional
 
 import jax
 
@@ -26,16 +26,12 @@ def trace(log_dir: Optional[str]) -> Iterator[None]:
         yield
 
 
-def block(tree: Any) -> Any:
-    """Block until all arrays in ``tree`` are computed; returns the tree."""
-    return jax.block_until_ready(tree)
-
-
 @contextlib.contextmanager
 def device_timer() -> Iterator[dict]:
     """Times a block including device completion. Yields a dict; read
     ``result['ms']`` after the block. Caller must block on its outputs
-    (use :func:`block`) for the timing to include device work."""
+    (``jax.block_until_ready`` or a value fetch) for the timing to include
+    device work."""
     result = {"ms": 0.0}
     start = time.perf_counter()
     try:
