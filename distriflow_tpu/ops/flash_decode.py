@@ -32,7 +32,12 @@ The online-softmax recurrence (running max ``m``, exp-sum ``l``,
 accumulator ``acc [1, H*D]``) lives in VMEM scratch; per-head scalars
 broadcast to the packed axis through the same mask matmul. ``valid_len``
 rides in as a scalar-prefetch operand: positions past the cache write
-index are masked.
+index are masked, and a tile that lies wholly past it is neither
+computed (``pl.when``) nor fetched (past the row's last live tile the
+K/V index maps repeat that tile's block index, and a grid step whose
+block index did not change issues no DMA). The grid keeps its static
+extent, rows x tiles; a dead step costs its bookkeeping alone (0.12 us
+on a v5e, PERF.md §6 PR 26).
 
 **int8 cache support**: with ``k_scale``/``v_scale`` operands
 (``[B, S, H]`` f32, symmetric absmax per position x head), the scales
@@ -172,9 +177,15 @@ def _bd_mask(h: int, hd: int) -> jnp.ndarray:
             == lax.broadcasted_iota(jnp.int32, (h, hd), 0)).astype(jnp.float32)
 
 
-def _attend_tile(row_len, v_tile, o_ref, m_ref, l_ref, acc_ref,
-                 j, n_kv, block_k, h, s2, p_scale=None):
-    """Shared online-softmax tile update.
+def _attend_tile(row_len, v_tile, m_ref, l_ref, acc_ref,
+                 j, block_k, h, s2, p_scale=None):
+    """Shared online-softmax tile update. The kernels run it only for a
+    tile that holds a position below ``row_len`` (``pl.when``): for a
+    row with any live position a dead tile's update is exactly the
+    identity (``p = exp(NEG_INF - m) = 0``, ``corr = exp(0) = 1``), so
+    skipping it changes no bit of a live row's output, and a non-finite
+    value in a page that was reserved and never written can no longer
+    reach the output through ``0 * NaN``.
 
     ``row_len``: scalar valid length for THIS batch row (continuous
     batching gives every row its own depth — the callers read it from
@@ -205,14 +216,6 @@ def _attend_tile(row_len, v_tile, o_ref, m_ref, l_ref, acc_ref,
     acc_ref[:] = acc_ref[:] * corr_flat + pv
     m_ref[:] = m_new
 
-    @pl.when(j == n_kv - 1)
-    def _finalize():
-        inv = 1.0 / jnp.maximum(l_ref[:], 1e-30)
-        inv_flat = jax.lax.dot_general(
-            inv, mask, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_ref[0] = (acc_ref[:] * inv_flat).astype(o_ref.dtype)
-
 
 def _init_scratch(j, m_ref, l_ref, acc_ref):
     @pl.when(j == 0)
@@ -220,6 +223,19 @@ def _init_scratch(j, m_ref, l_ref, acc_ref):
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _finalize(j, n_kv, o_ref, l_ref, acc_ref, h):
+    """Normalize and write the row's output at its last grid step. A row
+    of length 0 (no live tile ran) writes zeros: ``acc`` 0, ``l``
+    clamped."""
+    @pl.when(j == n_kv - 1)
+    def _write():
+        inv = 1.0 / jnp.maximum(l_ref[:], 1e-30)
+        inv_flat = jax.lax.dot_general(
+            inv, _bd_mask(h, acc_ref.shape[-1]), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        o_ref[0] = (acc_ref[:] * inv_flat).astype(o_ref.dtype)
 
 
 def _qk_scores(qbd_ref, k_tile, d):
@@ -233,12 +249,20 @@ def _qk_scores(qbd_ref, k_tile, d):
 
 def _decode_kernel(len_ref, qbd_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, block_k, n_kv, h):
+    """One (row, tile) grid step, slab or paged alike: the two layouts
+    differ only in their index maps."""
     j = pl.program_id(1)
+    row_len = len_ref[pl.program_id(0)]
     _init_scratch(j, m_ref, l_ref, acc_ref)
-    d = k_ref.shape[-1] // h
-    s2 = _qk_scores(qbd_ref, k_ref[0].astype(jnp.bfloat16), d)
-    _attend_tile(len_ref[pl.program_id(0)], v_ref[0].astype(jnp.bfloat16),
-                 o_ref, m_ref, l_ref, acc_ref, j, n_kv, block_k, h, s2)
+
+    @pl.when(j * block_k < row_len)
+    def _live():
+        d = k_ref.shape[-1] // h
+        s2 = _qk_scores(qbd_ref, k_ref[0].astype(jnp.bfloat16), d)
+        _attend_tile(row_len, v_ref[0].astype(jnp.bfloat16),
+                     m_ref, l_ref, acc_ref, j, block_k, h, s2)
+
+    _finalize(j, n_kv, o_ref, l_ref, acc_ref, h)
 
 
 def _decode_kernel_quant(len_ref, qbd_ref, qs_ref, k_ref, ks_ref, v_ref,
@@ -258,25 +282,31 @@ def _decode_kernel_quant(len_ref, qbd_ref, qs_ref, k_ref, ks_ref, v_ref,
     per-tile absmax under-resolves peaked softmax rows), so exact f32
     probabilities are kept and only V pays a cast."""
     j = pl.program_id(1)
+    row_len = len_ref[pl.program_id(0)]
     _init_scratch(j, m_ref, l_ref, acc_ref)
-    d = k_ref.shape[-1] // h
-    s_i32 = jax.lax.dot_general(
-        k_ref[0], qbd_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)  # [BK, H] on the s8 MXU
-    scale = 1.0 / (d ** 0.5)
-    s2 = s_i32.astype(jnp.float32) * ks_ref[0] * (qs_ref[0] * scale)
-    _attend_tile(len_ref[pl.program_id(0)], v_ref[0].astype(jnp.bfloat16),
-                 o_ref, m_ref, l_ref, acc_ref, j, n_kv, block_k, h, s2,
-                 p_scale=vs_ref[0])
+
+    @pl.when(j * block_k < row_len)
+    def _live():
+        d = k_ref.shape[-1] // h
+        s_i32 = jax.lax.dot_general(
+            k_ref[0], qbd_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)  # [BK, H] on the s8 MXU
+        scale = 1.0 / (d ** 0.5)
+        s2 = s_i32.astype(jnp.float32) * ks_ref[0] * (qs_ref[0] * scale)
+        _attend_tile(row_len, v_ref[0].astype(jnp.bfloat16),
+                     m_ref, l_ref, acc_ref, j, block_k, h, s2,
+                     p_scale=vs_ref[0])
+
+    _finalize(j, n_kv, o_ref, l_ref, acc_ref, h)
 
 
 def _record_decode_cost(positions: int, hd: int, kv_item: int,
                         quant: bool, h: int) -> None:
     """One call's cost in the trace-time tally (``ops/flop_count.py``),
     like the other kernels'. ``positions`` is the cache extent the grid
-    visits, batch rows x positions per row: the lengths are runtime values,
-    so what is known at trace time is the extent, and a tile past a row's
-    length is still fetched (its scores are masked). q.K^T and p.V are 2
+    spans, batch rows x positions per row: the lengths are runtime values,
+    so what is known at trace time is the extent, which is the upper
+    bound; the kernel visits live pages only. q.K^T and p.V are 2
     FLOPs per position per feature each; K and V are read once (int8
     caches add their f32 per-head scales); queries and outputs are
     negligible. Equals ``benchmark/lib/flops.flash_decode(positions, hd,
@@ -361,6 +391,25 @@ def flash_decode(
     # the homogeneous static-batch callers keep their old semantics)
     lens = jnp.broadcast_to(
         jnp.reshape(valid_len.astype(jnp.int32), (-1,)), (b,))
+    # past a row's last live tile the block index repeats, and a grid
+    # step whose block index did not change issues no DMA
+    return _tiled_decode(
+        "flash_decode", q, (k, k_scale, v, v_scale), lens, block_k, n_kv,
+        lambda bi, j, lens, last: (bi, jnp.minimum(j, last[bi]), 0),
+        (), interpret)
+
+
+def _tiled_decode(name, q, kv, lens, block_k, n_kv, kv_index, tables,
+                  interpret):
+    """The (row, tile) grid both layouts run: ``kv_index`` maps a grid
+    step to the K/V (and scale) block, given the scalar-prefetch refs
+    ``lens`` [B], ``last`` [B] (the row's last live tile) and then
+    ``tables``."""
+    k, k_scale, v, v_scale = kv
+    b, h, d = q.shape
+    hd = h * d
+    quant = k_scale is not None
+    last = jnp.maximum(lens - 1, 0) // block_k
 
     # block-diagonal query [B, HD, H]: head h's query in rows h*D:(h+1)*D
     # of column h — the operand that turns all-head scores into ONE
@@ -381,44 +430,40 @@ def flash_decode(
             b, hd, h).astype(jnp.bfloat16)
 
     # index maps under PrefetchScalarGridSpec receive the scalar refs last
-    in_specs = [
-        pl.BlockSpec((1, hd, h), lambda bi, j, lens: (bi, 0, 0)),
-    ]
+    def per_row(bi, j, *scalars):
+        return (bi, 0, 0)
+
+    in_specs = [pl.BlockSpec((1, hd, h), per_row)]
     arrays = [qbd]
     if quant:
-        in_specs.append(
-            pl.BlockSpec((1, 1, h), lambda bi, j, lens: (bi, 0, 0)))
+        in_specs.append(pl.BlockSpec((1, 1, h), per_row))
         arrays.append(qs_row)
-    in_specs.append(
-        pl.BlockSpec((1, block_k, hd), lambda bi, j, lens: (bi, j, 0)))
+    in_specs.append(pl.BlockSpec((1, block_k, hd), kv_index))
     arrays.append(k)
     if quant:
-        in_specs.append(
-            pl.BlockSpec((1, block_k, h), lambda bi, j, lens: (bi, j, 0)))
+        in_specs.append(pl.BlockSpec((1, block_k, h), kv_index))
         arrays.append(k_scale)
-    in_specs.append(
-        pl.BlockSpec((1, block_k, hd), lambda bi, j, lens: (bi, j, 0)))
+    in_specs.append(pl.BlockSpec((1, block_k, hd), kv_index))
     arrays.append(v)
     if quant:
-        in_specs.append(
-            pl.BlockSpec((1, block_k, h), lambda bi, j, lens: (bi, j, 0)))
+        in_specs.append(pl.BlockSpec((1, block_k, h), kv_index))
         arrays.append(v_scale)
 
-    kernel = (
-        functools.partial(_decode_kernel_quant, block_k=block_k, n_kv=n_kv,
-                          h=h)
-        if quant else
-        functools.partial(_decode_kernel, block_k=block_k, n_kv=n_kv, h=h)
-    )
+    body = functools.partial(
+        _decode_kernel_quant if quant else _decode_kernel,
+        block_k=block_k, n_kv=n_kv, h=h)
+
+    def kernel(len_ref, last_ref, *refs):
+        body(len_ref, *refs[len(tables):])  # the tables serve the index maps
+
     out = pl.pallas_call(
         kernel,
-        name="flash_decode",
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2 + len(tables),
             grid=(b, n_kv),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, hd),
-                                   lambda bi, j, lens: (bi, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, hd), per_row),
             scratch_shapes=[
                 pltpu.VMEM((1, h), jnp.float32),
                 pltpu.VMEM((1, h), jnp.float32),
@@ -430,7 +475,7 @@ def flash_decode(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lens, *arrays)
+    )(lens, last, *tables, *arrays)
     return out.reshape(b, h, d)
 
 
@@ -440,14 +485,22 @@ def flash_decode(
 # per-row slabs with ONE pool of fixed-size pages [n_pages, page_size,
 # H*D] plus a per-row page table: row bi's logical KV positions
 # [j*page_size, (j+1)*page_size) live in physical page table[bi, j]. The
-# kernel below is the same online-softmax recurrence as
-# :func:`flash_decode` with block_k == page_size — the ONLY change is
-# that the K/V tile index maps dereference the page table (a second
-# scalar-prefetch operand) instead of striding contiguously. Sentinel
-# table entries (>= n_pages, unallocated tail pages) are pre-clamped to
-# the last real page on the host side; whatever garbage that tile holds
-# is masked by the row's ``valid_len`` exactly like the slab kernel
-# masks its own tail.
+# kernel below is the same (row, tile) grid as :func:`flash_decode` with
+# block_k == page_size (:func:`_tiled_decode`) — the ONLY change is that
+# the K/V tile index maps dereference the page table (one more
+# scalar-prefetch operand) instead of striding contiguously.
+#
+# A row's live pages are read off the kernel's own two inputs: the table
+# says which pages exist (the entries before the first sentinel, an
+# entry >= n_pages), the length says how many positions are written, and
+# ``eff_len = min(valid_len, n_real * page_size)`` is what the kernel
+# runs on. Dead pages — past the length, or at and past the first
+# sentinel — are neither computed nor fetched: a slot that has reserved
+# its whole horizon pays for the pages it has written, and a retired
+# slot (all sentinels under a stale, still growing length) pays 16 bare
+# grid steps and writes zeros. Sentinels are still clamped to the last
+# real page so the index map is a plain table read; that page is
+# fetched at most once per run of dead rows and never computed on.
 #
 # Accumulation order note: the paged kernel tiles at page_size, the slab
 # kernel at pick_block_k(S) — when those differ the online-softmax adds
@@ -492,32 +545,6 @@ def supports_paged(page_size: int, hd: int = 512, kv_item: int = 2) -> bool:
     return False
 
 
-def _paged_kernel(tab_ref, len_ref, qbd_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, page_size, n_kv, h):
-    j = pl.program_id(1)
-    _init_scratch(j, m_ref, l_ref, acc_ref)
-    d = k_ref.shape[-1] // h
-    s2 = _qk_scores(qbd_ref, k_ref[0].astype(jnp.bfloat16), d)
-    _attend_tile(len_ref[pl.program_id(0)], v_ref[0].astype(jnp.bfloat16),
-                 o_ref, m_ref, l_ref, acc_ref, j, n_kv, page_size, h, s2)
-
-
-def _paged_kernel_quant(tab_ref, len_ref, qbd_ref, qs_ref, k_ref, ks_ref,
-                        v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                        page_size, n_kv, h):
-    j = pl.program_id(1)
-    _init_scratch(j, m_ref, l_ref, acc_ref)
-    d = k_ref.shape[-1] // h
-    s_i32 = jax.lax.dot_general(
-        k_ref[0], qbd_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)  # s8 MXU, like _decode_kernel_quant
-    scale = 1.0 / (d ** 0.5)
-    s2 = s_i32.astype(jnp.float32) * ks_ref[0] * (qs_ref[0] * scale)
-    _attend_tile(len_ref[pl.program_id(0)], v_ref[0].astype(jnp.bfloat16),
-                 o_ref, m_ref, l_ref, acc_ref, j, n_kv, page_size, h, s2,
-                 p_scale=vs_ref[0])
-
-
 def flash_decode_paged(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -534,10 +561,14 @@ def flash_decode_paged(
     H*D]`` (bf16/f32, or int8 with ``k_scale``/``v_scale``
     ``[n_pages, page_size, H]`` f32 pools); ``page_table``: [B, PP]
     int32 — row bi reads physical page ``page_table[bi, j]`` for its
-    j-th logical page (entries >= n_pages are sentinels: clamped to a
-    real page whose contents the length mask discards); ``valid_len``:
-    scalar or [B] per-row window, same contract as :func:`flash_decode`.
-    Returns [B, H, D] in ``q``'s dtype."""
+    j-th logical page (entries >= n_pages are sentinels, and the first
+    one ends the row: positions at and past it are not attended to,
+    whatever the length says); ``valid_len``: scalar or [B] per-row
+    window, same contract as :func:`flash_decode`. Returns [B, H, D] in
+    ``q``'s dtype; a row with no live position (length 0, or a table
+    that starts with a sentinel) returns zeros. Pages past a row's
+    length are neither fetched nor computed on, so what they hold
+    (NaN included) cannot reach the output."""
     interpret = _resolve_interpret(interpret)
     b, h, d = q.shape
     n_pages, ps, hd = k.shape
@@ -557,78 +588,24 @@ def flash_decode_paged(
             f"{VMEM_LIMIT_BYTES / 1e6:.0f} MB TPU limit — shrink page_size")
     n_kv = page_table.shape[1]
     _record_decode_cost(b * n_kv * ps, hd, kv_item, quant, h)
-    # pre-clamp sentinels so the index map is a plain table read
-    tab = jnp.minimum(page_table.astype(jnp.int32), n_pages - 1)
+    table = page_table.astype(jnp.int32)
     lens = jnp.broadcast_to(
         jnp.reshape(valid_len.astype(jnp.int32), (-1,)), (b,))
-
-    eye = jnp.eye(h, dtype=jnp.float32)
-    qf32 = q.astype(jnp.float32)
-    if quant:
-        qs = jnp.max(jnp.abs(qf32), axis=-1, keepdims=True) / 127.0
-        qs = jnp.maximum(qs, 1e-20)  # [B, H, 1]
-        q8 = jnp.clip(jnp.round(qf32 / qs), -127, 127)
-        qbd = jnp.einsum("bhd,hg->bhdg", q8, eye).reshape(
-            b, hd, h).astype(jnp.int8)
-        qs_row = qs[:, :, 0][:, None, :]  # [B, 1, H]
-    else:
-        qbd = jnp.einsum("bhd,hg->bhdg", qf32, eye).reshape(
-            b, hd, h).astype(jnp.bfloat16)
-
-    # index maps receive (grid indices..., tab_ref, len_ref): K/V tiles
-    # dereference the page table — THE paged indirection
-    in_specs = [
-        pl.BlockSpec((1, hd, h), lambda bi, j, tab, lens: (bi, 0, 0)),
-    ]
-    arrays = [qbd]
-    if quant:
-        in_specs.append(
-            pl.BlockSpec((1, 1, h), lambda bi, j, tab, lens: (bi, 0, 0)))
-        arrays.append(qs_row)
-    in_specs.append(
-        pl.BlockSpec((1, ps, hd), lambda bi, j, tab, lens: (tab[bi, j], 0, 0)))
-    arrays.append(k)
-    if quant:
-        in_specs.append(
-            pl.BlockSpec((1, ps, h),
-                         lambda bi, j, tab, lens: (tab[bi, j], 0, 0)))
-        arrays.append(k_scale)
-    in_specs.append(
-        pl.BlockSpec((1, ps, hd), lambda bi, j, tab, lens: (tab[bi, j], 0, 0)))
-    arrays.append(v)
-    if quant:
-        in_specs.append(
-            pl.BlockSpec((1, ps, h),
-                         lambda bi, j, tab, lens: (tab[bi, j], 0, 0)))
-        arrays.append(v_scale)
-
-    kernel = (
-        functools.partial(_paged_kernel_quant, page_size=ps, n_kv=n_kv, h=h)
-        if quant else
-        functools.partial(_paged_kernel, page_size=ps, n_kv=n_kv, h=h)
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="flash_decode_paged",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, n_kv),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, hd),
-                                   lambda bi, j, tab, lens: (bi, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, h), jnp.float32),
-                pltpu.VMEM((1, h), jnp.float32),
-                pltpu.VMEM((1, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(tab, lens, *arrays)
-    return out.reshape(b, h, d)
+    # a row's live pages end at its first sentinel, whatever its length
+    # says: a retired slot's row is all sentinels under a stale, still
+    # growing length, and costs its bare grid steps alone
+    col = lax.broadcasted_iota(jnp.int32, table.shape, 1)
+    n_real = jnp.min(jnp.where(table < n_pages, n_kv, col), axis=1)
+    eff_len = jnp.minimum(lens, n_real * ps)
+    # THE paged indirection: K/V tiles dereference the page table, at a
+    # column that stops at the row's last live page; sentinels are
+    # pre-clamped so the index map is a plain table read
+    tab = jnp.minimum(table, n_pages - 1)
+    return _tiled_decode(
+        "flash_decode_paged", q, (k, k_scale, v, v_scale), eff_len, ps, n_kv,
+        lambda bi, j, lens, last, tab: (
+            tab[bi, jnp.minimum(j, last[bi])], 0, 0),
+        (tab,), interpret)
 
 
 # -- GSPMD partitioning ----------------------------------------------------

@@ -1300,10 +1300,16 @@ class InferenceServer:
         if self._prof.enabled:
             # what single-query attention reads this dispatch, on the
             # annotation: live rows and their cached context (prompt plus
-            # emitted), so a trace reader needs no second clock for it
-            stats = {"n_active": len(active), "ctx_tokens": sum(
-                self._slot_req[s].prompt.shape[1] + int(self._slot_emitted[s])
-                for s in active)}
+            # emitted), so a trace reader needs no second clock for it;
+            # under the paged layout also the pages that context fills
+            # against the pages the kernel's grid spans
+            ctx = [self._slot_req[s].prompt.shape[1]
+                   + int(self._slot_emitted[s]) for s in active]
+            stats = {"n_active": len(active), "ctx_tokens": sum(ctx)}
+            if self._paged:
+                ps = srv.page_size
+                stats["live_pages"] = sum(-(-c // ps) for c in ctx)
+                stats["table_pages"] = len(self._slot_req) * self._pp
         with self._prof.phase("decode_iter", **stats):
             sampling = bool((self._temps[active] > 0).any())
             _insert, _pick, decode = _build_slot_fns(

@@ -121,6 +121,9 @@ def test_engine_phases_land_on_the_profilers_host_plane(tmp_path):
     stats = [s for _, _, s in sorted(events["decode_iter"])]
     assert [(s["n_active"], s["ctx_tokens"]) for s in stats] == [
         (1, 7), (1, 9)] * 2
+    # and the pages that context fills, against the 2 slots x 3 pages the
+    # paged decode kernel's grid spans
+    assert [(s["live_pages"], s["table_pages"]) for s in stats] == [(1, 6)] * 4
     # the server's transport shares its telemetry: each generate frame left
     # one handler_wait row in its request's trace
     waits = tel.tracer.finished("handler_wait")
@@ -133,6 +136,48 @@ def test_engine_phases_land_on_the_profilers_host_plane(tmp_path):
         "share" not in s and s["dispatch_ms"] >= 0 and s["fetch_ms"] >= 0
         and s["dispatch_ms"] + s["fetch_ms"] <= s["dur_ms"] + 1e-3
         for s in spans)
+
+
+@pytest.mark.parametrize("profiling", [True, False])
+def test_decode_iter_stats_count_live_pages_only_while_profiling(profiling):
+    """``live_pages`` / ``table_pages`` say how much of the paged decode
+    kernel's grid is live. They are host arithmetic inside the engine's
+    ``if self._prof.enabled`` block: with profiling off the phase opens with
+    no stats at all."""
+    params = transformer_lm(CFG, example_seq=16).init(jax.random.PRNGKey(0))
+    server = InferenceServer(
+        CFG, params, port=0, telemetry=Telemetry(enabled=profiling),
+        serving=ServingConfig(batch_window_s=0.01, decode_chunk=2,
+                              max_slots=2, kv_layout="paged", page_size=16,
+                              page_pool_pages=8)).setup()
+    prof, seen = server._prof, []
+    assert prof.enabled is profiling
+
+    class Spy:
+        enabled = prof.enabled
+
+        def phase(self, name, **stats):
+            if name == "decode_iter":
+                seen.append(stats)
+            return prof.phase(name, **stats)
+
+        def __getattr__(self, name):
+            return getattr(prof, name)
+
+    server._prof = Spy()
+    try:
+        with InferenceClient(server.address) as client:
+            # 15 prompt tokens: the context crosses a page edge (16) between
+            # the first chunk of 2 and the second
+            client.generate(np.arange(1, 16, dtype=np.int32)[None], 5)
+    finally:
+        server.stop()
+    if profiling:
+        assert seen == [
+            {"n_active": 1, "ctx_tokens": 16, "live_pages": 1, "table_pages": 6},
+            {"n_active": 1, "ctx_tokens": 18, "live_pages": 2, "table_pages": 6}]
+    else:
+        assert seen == [{}, {}]
 
 
 def test_disabled_telemetry_opens_no_annotation(monkeypatch):
