@@ -462,6 +462,80 @@ def slot_cache(config: TransformerConfig, params, max_slots: int):
 _POOL_LEAVES = ("cached_k", "cached_v", "k_scale", "v_scale")
 
 
+def _split_pools(cache):
+    """``(pools, rest)``: the cache's ``_POOL_LEAVES`` and everything
+    else, each under the cache's own nesting. The pools are the bytes
+    and are always distinct buffers; the rest (``page_table``,
+    ``cache_index``: a few KB) is what :func:`set_page_tables` and
+    :func:`_set_cache_positions` put ONE array into for every layer."""
+    pools, rest = {}, {}
+    for name, sub in cache.items():
+        if name in _POOL_LEAVES:
+            pools[name] = sub
+        elif hasattr(sub, "items"):
+            pools[name], rest[name] = _split_pools(sub)
+        else:
+            rest[name] = sub
+    return pools, rest
+
+
+def _join_pools(pools, rest):
+    """Inverse of :func:`_split_pools`."""
+    out = dict(rest)
+    for name, sub in pools.items():
+        out[name] = (_join_pools(sub, rest[name])
+                     if hasattr(sub, "items") else sub)
+    return out
+
+
+class _CacheProgram:
+    """``jax.jit`` of an engine program that takes the resident cache as
+    positional argument ``cache_arg`` and returns its successor, with the
+    cache's pools DONATED: XLA aliases each pool to its output and updates
+    it in place, so a dispatch neither copies the pool on the device nor
+    allocates a second one. The caller's cache is consumed: rebind to the
+    result and never touch the pools passed in again.
+
+    Only the pools are donated. A tree that holds one array under several
+    leaves cannot be donated whole (``Attempt to donate the same buffer
+    twice``), and the tables and indices are exactly that; they ride along
+    undonated, and the program itself sees the one cache pytree whose
+    structure switches ``_decode_attend``'s mode. ``lower`` takes the
+    same arguments as the call; ``body`` is the undecorated function
+    (the tests jit it plainly as the undonated oracle)."""
+
+    def __init__(self, fn, cache_arg: int):
+        self.body = fn
+        self._cache_arg = cache_arg
+
+        def program(pools, *args):
+            args = list(args)
+            args[cache_arg] = _join_pools(pools, args[cache_arg])
+            return fn(*args)
+
+        # the program's name in traces and in the compile cache's key
+        program.__name__ = program.__qualname__ = fn.__name__
+        self._jit = jax.jit(program, donate_argnums=0)
+
+    def _split(self, args):
+        args = list(args)
+        pools, args[self._cache_arg] = _split_pools(args[self._cache_arg])
+        return pools, args
+
+    def __call__(self, *args):
+        pools, args = self._split(args)
+        return self._jit(pools, *args)
+
+    def lower(self, *args):
+        pools, args = self._split(args)
+        return self._jit.lower(pools, *args)
+
+
+def _donates_cache(cache_arg: int):
+    """Decorator form of :class:`_CacheProgram`."""
+    return functools.partial(_CacheProgram, cache_arg=cache_arg)
+
+
 def pages_per_slot(max_seq: int, page_size: int) -> int:
     """Logical pages a full-depth row spans: ``ceil(max_seq / page_size)``
     — the page-table width (plus one pinned sentinel column)."""
@@ -552,7 +626,7 @@ def _build_paged_fns(config: TransformerConfig, page_size: int):
     max_seq = config.max_seq
     pp = pages_per_slot(max_seq, page_size)
 
-    @jax.jit
+    @_donates_cache(0)
     def insert(cache, row_cache, slots, length, start, table):
         row_cache = _as_dict(row_cache)
         r = slots.shape[0]
@@ -677,7 +751,7 @@ def _build_slot_fns(config: TransformerConfig, chunk: int,
     cache, so switching mid-flight is free)."""
     module = _decode_module(config)
 
-    @jax.jit
+    @_donates_cache(0)
     def insert(cache, row_cache, slots, length):
         row_cache = _as_dict(row_cache)
 
@@ -707,7 +781,7 @@ def _build_slot_fns(config: TransformerConfig, chunk: int,
     def pick_rows(logits, temps, top_ks, top_ps, seeds, positions):
         return _pick(logits, temps, top_ks, top_ps, seeds, positions)
 
-    @jax.jit
+    @_donates_cache(1)
     def decode(params, cache, tok, done, temps, top_ks, top_ps, seeds, eos):
         def step(carry, _):
             cache, tok, done = carry
@@ -829,7 +903,7 @@ def _build_spec_fns(config: TransformerConfig,
         return jax.random.fold_in(
             jax.random.fold_in(jax.random.PRNGKey(seed), pos), tag)
 
-    @jax.jit
+    @_donates_cache(1)
     def draft_k(d_params, d_cache, tok, temps, top_ks, top_ps, seeds):
         def dstep(carry, _):
             cache, tk = carry
@@ -859,7 +933,7 @@ def _build_spec_fns(config: TransformerConfig,
             dstep, (d_cache, tok), None, length=k)
         return d_cache, drafts.T, jnp.transpose(qs, (1, 0, 2))
 
-    @jax.jit
+    @_donates_cache(1)
     def verify(params, cache, tok, drafts, qprobs, temps, top_ks, top_ps,
                seeds, done, eos):
         b = tok.shape[0]
@@ -956,7 +1030,7 @@ def _build_spec_fns(config: TransformerConfig,
         return (_set_cache_positions(cache, new_idx), emit, n_emit, n_acc,
                 new_tok, new_done, catch_up, new_idx)
 
-    @jax.jit
+    @_donates_cache(1)
     def commit(d_params, d_cache, last_draft, catch_up, new_idx):
         cur = _cache_positions(d_cache)  # p + k after the draft scan
         divert = jnp.where(

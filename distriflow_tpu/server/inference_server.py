@@ -69,9 +69,11 @@ shardings (heads-sharded KV cache, psum'd o_proj; see
 
 from __future__ import annotations
 
+import contextlib
 import queue as queue_mod
 import threading
 import time as time_mod
+import warnings
 from collections import OrderedDict, deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
@@ -87,6 +89,7 @@ from distriflow_tpu.models.generate import (
     _build_slot_fns,
     _build_spec_fns,
     _check_fits,
+    _find_cache_leaf,
     beam_search,
     generate,
     paged_cache,
@@ -444,6 +447,19 @@ class InferenceServer:
         self._m_spec_rate = tel.gauge(
             "serving_spec_accepted_per_step",
             help="accepted draft tokens per speculative step")
+        # every program that returns the cache's successor donates the
+        # pools (models/generate.py::_CacheProgram); these say, per call,
+        # whether the runtime took them over or the call copied the pool
+        self._m_cache_donated = {p: tel.counter(
+            "serving_cache_donations_total", program=p,
+            help="engine dispatches that updated the KV pools in place")
+            for p in ("decode", "insert", "spec")}
+        self._m_cache_copied = {p: tel.counter(
+            "serving_cache_copies_total", program=p,
+            help="engine dispatches whose donated KV pools were not "
+                 "usable, so the whole pool was copied")
+            for p in ("decode", "insert", "spec")}
+        self._copy_warned: set = set()  # single-writer: scheduler thread
         # continuous phase profiler (docs/OBSERVABILITY.md §5): serving
         # records phases only — the engine loop mostly idles in _gather, so
         # a per-iteration step() would drown the digests in idle wall time
@@ -821,6 +837,7 @@ class InferenceServer:
                     self._decode_iteration()
             except Exception as e:  # device failure: fail loud, stay up
                 self.logger.log(f"engine error: {e!r}")
+                self._drop_dead_cache(e)
                 self._abort_all(e)
 
     def _gather(self) -> bool:
@@ -1009,6 +1026,53 @@ class InferenceServer:
             dur_ms=dur_ms, start=start, mono=mono0,
             request_id=req.request_id, tier=req.tier, **attrs)
 
+    @contextlib.contextmanager
+    def _donating(self, program: str, cache: Any):
+        """Around ONE call that donates ``cache``'s pools and rebinds the
+        engine's reference to the result: afterwards the pools passed in
+        must be deleted (the runtime took them over and the program wrote
+        in place). One still alive means XLA could not alias it and the
+        call copied the whole pool. ``is_deleted`` reads a flag: no device
+        sync. A call that raises is not counted."""
+        pool = _find_cache_leaf(cache, "cached_k")
+        yield
+        if pool.is_deleted():
+            self._m_cache_donated[program].inc()
+            return
+        self._m_cache_copied[program].inc()
+        if program not in self._copy_warned:
+            self._copy_warned.add(program)
+            warnings.warn(
+                f"InferenceServer: the {program} program copied the KV "
+                "pool instead of updating it in place (its donated buffers "
+                "were not usable); serving_cache_copies_total counts every "
+                "such dispatch", RuntimeWarning, stacklevel=3)
+
+    def _drop_dead_cache(self, err: Exception) -> bool:
+        """After a failed device call: a donating program that fails once
+        it has started executing has consumed the pools it was passed, and
+        the engine must not dispatch on deleted buffers. If that happened,
+        fail every resident request with ``err``, forget the prefix map
+        (its pages' contents went with the pool) and drop both caches so
+        the next admission allocates fresh ones. An error raised before
+        execution (trace, compile, argument check) leaves the pools alive
+        and this a no-op. Returns whether the caches were dropped."""
+        caches = (self._slot_cache, self._draft_cache)
+        if not any(c is not None
+                   and _find_cache_leaf(c, "cached_k").is_deleted()
+                   for c in caches):
+            return False
+        self.logger.log(f"engine error: KV pools lost to a failed call, "
+                        f"re-allocating at the next admission: {err!r}")
+        with self._device_lock:
+            self._slot_cache = self._draft_cache = None
+        self._fail_residents(err)
+        if self._paged:
+            self._flush_prefix_map()
+            self._tables[:] = self._n_pages
+            self._draft_tables[:] = self._n_pages
+        return True
+
     def _admit(self) -> None:
         """Move backlog requests into free slots (strict FIFO — a wide
         request blocks later ones rather than being starved), prefill
@@ -1080,8 +1144,13 @@ class InferenceServer:
                     groups.setdefault(
                         (req.prompt.shape[1], shared_len), []).append(
                             (req, row))
+            lost: Optional[Exception] = None
             for (plen, shared_len), members in sorted(groups.items()):
                 try:
+                    if lost is not None:
+                        # the pools died under an earlier group, and the
+                        # shared prefix pages this group planned on with them
+                        raise lost
                     self._admit_group(plen, shared_len, members)
                 except Exception as e:
                     # contain a failed prefill to its own group: any slots
@@ -1101,6 +1170,8 @@ class InferenceServer:
                         self._tables_dirty = True
                         if self._spec_k:
                             self._draft_tables_dirty = True
+                    if lost is None and self._drop_dead_cache(e):
+                        lost = e
                     for req in {id(r): r for r, _ in members}.values():
                         self._finish_error(req, e)
             self.batched_requests += len(admit)
@@ -1192,16 +1263,20 @@ class InferenceServer:
                         self.params, row_cache, stacked[:, i:i + pc])
             with self._prof.phase("page_insert"):
                 if self._paged:
-                    self._slot_cache = insert_paged(
-                        self._slot_cache, row_cache, slots, np.int32(plen),
-                        np.int32(shared_len), self._tables.copy())
+                    with self._donating("insert", self._slot_cache):
+                        self._slot_cache = insert_paged(
+                            self._slot_cache, row_cache, slots,
+                            np.int32(plen), np.int32(shared_len),
+                            self._tables.copy())
                     # insert carries the FULL host table to the device, so
                     # any pending sentinel edits from retired slots ride
                     # along
                     self._tables_dirty = False
                 else:
-                    self._slot_cache = insert(
-                        self._slot_cache, row_cache, slots, np.int32(plen))
+                    with self._donating("insert", self._slot_cache):
+                        self._slot_cache = insert(
+                            self._slot_cache, row_cache, slots,
+                            np.int32(plen))
             with self._prof.phase("first_token_fetch"):
                 first = np.asarray(pick_rows(
                     logits, temps, top_ks, top_ps, seeds,
@@ -1223,9 +1298,10 @@ class InferenceServer:
                     for i in range(pc, plen, pc):
                         _, d_row = d_extend(
                             dparams, d_row, stacked[:, i:i + pc])
-                self._draft_cache = d_insert(
-                    self._draft_cache, d_row, slots, np.int32(plen),
-                    np.int32(0), self._draft_tables.copy())
+                with self._donating("insert", self._draft_cache):
+                    self._draft_cache = d_insert(
+                        self._draft_cache, d_row, slots, np.int32(plen),
+                        np.int32(0), self._draft_tables.copy())
                 self._draft_tables_dirty = False
         for j, (req, row) in enumerate(members):
             s = int(slots[j])
@@ -1327,12 +1403,12 @@ class InferenceServer:
                             self._slot_cache, self._tables.copy())
                     self._tables_dirty = False
                 td0 = time_mod.monotonic()
-                with self._prof.phase("decode_dispatch"):
-                    cache, tok, done, toks = decode(
+                with self._prof.phase("decode_dispatch"), \
+                        self._donating("decode", self._slot_cache):
+                    self._slot_cache, tok, done, toks = decode(
                         self.params, self._slot_cache, self._tok, self._done,
                         self._temps, self._top_ks, self._top_ps, self._seeds,
                         self._eos)
-                self._slot_cache = cache
                 td1 = time_mod.monotonic()
                 with self._prof.phase("token_fetch"):
                     # np.array, not np.asarray: device outputs arrive as
@@ -1415,17 +1491,19 @@ class InferenceServer:
                 self._draft_tables_dirty = False
             dparams = self._live_draft_params()
             with self._prof.phase("spec_draft"):
-                self._draft_cache, drafts, qprobs = draft_k(
-                    dparams, self._draft_cache, self._tok, self._temps,
-                    self._top_ks, self._top_ps, self._seeds)
+                with self._donating("spec", self._draft_cache):
+                    self._draft_cache, drafts, qprobs = draft_k(
+                        dparams, self._draft_cache, self._tok, self._temps,
+                        self._top_ks, self._top_ps, self._seeds)
                 drafts.block_until_ready()
             td = time_mod.monotonic()
             with self._prof.phase("spec_verify"):
-                (self._slot_cache, emit, n_emit, n_acc, new_tok, new_done,
-                 catch, new_idx) = verify(
-                    self.params, self._slot_cache, self._tok, drafts,
-                    qprobs, self._temps, self._top_ks, self._top_ps,
-                    self._seeds, self._done, self._eos)
+                with self._donating("spec", self._slot_cache):
+                    (self._slot_cache, emit, n_emit, n_acc, new_tok,
+                     new_done, catch, new_idx) = verify(
+                        self.params, self._slot_cache, self._tok, drafts,
+                        qprobs, self._temps, self._top_ks, self._top_ps,
+                        self._seeds, self._done, self._eos)
                 emit = np.array(emit)
                 n_emit = np.array(n_emit)
                 n_acc = np.array(n_acc)
@@ -1433,9 +1511,10 @@ class InferenceServer:
                 new_done = np.array(new_done)
             tv = time_mod.monotonic()
             with self._prof.phase("spec_commit"):
-                self._draft_cache = commit(
-                    dparams, self._draft_cache, drafts[:, -1], catch,
-                    new_idx)
+                with self._donating("spec", self._draft_cache):
+                    self._draft_cache = commit(
+                        dparams, self._draft_cache, drafts[:, -1], catch,
+                        new_idx)
                 jax.block_until_ready(self._draft_cache)
         tc = time_mod.monotonic()
         self.decode_batches += 1
@@ -1574,13 +1653,19 @@ class InferenceServer:
         the paged bench reconcile on exactly that identity)."""
         freed = 0
         if self._paged:
-            while self._prefix_map:
-                _h, pg = self._prefix_map.popitem(last=False)
-                self._evicted_prefixes.append(_h)
-                self._prefix_hit_counts.pop(_h, None)
-                freed += self._pool.unref([pg])
-            self._note_occupancy()
+            freed = self._flush_prefix_map()
             self.verify_pool_conservation("release_prefix_cache")
+        return freed
+
+    def _flush_prefix_map(self) -> int:
+        """Drop every prefix-map entry; returns the pages that freed."""
+        freed = 0
+        while self._prefix_map:
+            _h, pg = self._prefix_map.popitem(last=False)
+            self._evicted_prefixes.append(_h)
+            self._prefix_hit_counts.pop(_h, None)
+            freed += self._pool.unref([pg])
+        self._note_occupancy()
         return freed
 
     def verify_pool_conservation(self, context: str = "") -> None:
@@ -1608,12 +1693,15 @@ class InferenceServer:
     def _abort_all(self, err: Exception) -> None:
         """Device failure mid-engine: error every waiter (active slots and
         backlog) and reset slot state so the engine can keep serving."""
+        self._fail_residents(err)
+        while self._backlog:
+            self._finish_error(self._backlog.popleft(), err)
+
+    def _fail_residents(self, err: Exception) -> None:
         for s, req in enumerate(self._slot_req):
             if req is not None:
                 self._retire_slot(s)
                 self._finish_error(req, err)
-        while self._backlog:
-            self._finish_error(self._backlog.popleft(), err)
         self._m_slots.set(0)
 
     def _shutdown_engine(self) -> None:

@@ -1,4 +1,5 @@
-"""The decode kernels pass the TPU's own compiler at the serving cell's widths.
+"""The decode kernels pass the TPU's own compiler at the serving cell's widths,
+and the engine's programs alias the KV pools there.
 
 Interpret mode says a kernel computes the right thing; it does not say that
 Mosaic accepts it (an unaligned slice, too much VMEM, a scalar op the core
@@ -14,6 +15,15 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import distriflow_tpu.ops as ops
+from distriflow_tpu.models.generate import (
+    _build_paged_fns,
+    _build_prefill,
+    _build_slot_fns,
+    _split_pools,
+    paged_cache,
+)
+from distriflow_tpu.models.transformer import TransformerConfig, transformer_lm
 from distriflow_tpu.ops.flash_decode import flash_decode, flash_decode_paged
 
 pytestmark = pytest.mark.kernels
@@ -66,3 +76,43 @@ def test_decode_kernel_compiles_for_v5e(one_chip, layout, kv):
         args = (q, pool, pool, lens, *scales)
     compiled = jax.jit(call).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode", "insert"])
+def test_engine_program_aliases_the_pools_on_v5e(one_chip, monkeypatch,
+                                                 program):
+    """The serving cell's decode chunk and page scatter, two layers deep:
+    the TPU compiler aliases every byte of the donated pools to an output,
+    so neither program holds the pool twice (PERF.md §4)."""
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=H * D, n_heads=H, n_layers=2, d_ff=H * D,
+        max_seq=WIDTH * PAGE, dtype=jnp.bfloat16, use_flash_attention=True,
+        use_flash_decode=True)
+
+    def placed(tree):
+        return jax.tree.map(lambda v: jax.ShapeDtypeStruct(
+            v.shape, v.dtype, sharding=one_chip), tree)
+
+    def shape(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = placed(jax.eval_shape(
+        transformer_lm(cfg, example_seq=PAGE).init, jax.random.PRNGKey(0)))
+    cache = placed(jax.eval_shape(
+        lambda p: paged_cache(cfg, p, B, PAGE, N_PAGES), params))
+    pool_bytes = sum(v.size * v.dtype.itemsize
+                     for v in jax.tree.leaves(_split_pools(cache)[0]))
+    if program == "decode":
+        compiled = _build_slot_fns(cfg, 8, False)[2].lower(
+            params, cache, shape((B,)), shape((B,), jnp.bool_),
+            shape((B,), jnp.float32), shape((B,)), shape((B,), jnp.float32),
+            shape((B,)), shape((B,))).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    else:
+        rows = jax.eval_shape(_build_prefill(cfg)[0], params,
+                              jax.ShapeDtypeStruct((2, 3 * PAGE), jnp.int32))[1]
+        compiled = _build_paged_fns(cfg, PAGE)[0].lower(
+            cache, placed(rows), shape((2,)), shape(()), shape(()),
+            shape((B, WIDTH + 1))).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
