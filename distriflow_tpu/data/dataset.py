@@ -303,7 +303,7 @@ def sample_batch(x, y, idx):
     """Gather a training batch by row indices.
 
     The host-side batch-assembly hot path for the sampling-style training
-    loops (experiments, bench): multi-threaded C++ gather when
+    loops (experiments): multi-threaded C++ gather when
     ``distriflow_tpu.native`` is built, numpy fancy indexing otherwise.
     """
     from distriflow_tpu import native

@@ -137,8 +137,7 @@ class SoakModel(DistributedModel):
 @dataclass
 class SoakConfig:
     """Knobs for one soak run. Defaults are the tier-1 miniature; the
-    ``slow``-marked test and the bench leg scale ``n_clients`` into the
-    hundreds."""
+    ``slow``-marked test scales ``n_clients`` into the hundreds."""
 
     n_clients: int = 24
     seed: int = 0
@@ -242,20 +241,6 @@ class SoakResult:
     mismatches: Dict[str, Tuple[Any, Any]] = field(default_factory=dict)
     clients_evicted: int = 0
     errors: List[str] = field(default_factory=list)
-
-    def bench_numbers(self) -> Dict[str, float]:
-        """The ledger-facing scalars (bench.py ``fleet_soak`` row)."""
-        return {
-            "clients": float(self.n_clients),
-            "applies": float(self.applied),
-            "goodput_applies_per_s": self.goodput_applies_per_s,
-            "ack_p99_ms": self.ack_p99_ms,
-            "round_p99_ms": self.round_p99_ms,
-            "kills": float(self.kills),
-            "rejoins": float(self.rejoins),
-            "adaptations": float(self.adaptations),
-            "final_loss": self.final_loss,
-        }
 
 
 class _ClientRec:

@@ -1,8 +1,8 @@
-"""MobileNetV2 (BASELINE config #5, ImageNet-subset stretch workload).
+"""MobileNetV2 (the ImageNet-subset stretch workload).
 
 No reference counterpart exists (the reference ships only the MNIST MLP and a
 Keras ConvNet export, ``experiment/mnist/mnist_server.ts:16-22`` /
-``model.json``); BASELINE.md adds MobileNetV2 as the v4-32 stretch target.
+``model.json``); MobileNetV2 is this repo's own stretch target.
 
 TPU-first design decisions:
 
@@ -338,7 +338,7 @@ def mobilenet_v2(
     depthwise_impl: str = "conv",
     gn_impl: str = "flax",
 ) -> ModelSpec:
-    """BASELINE config #5 model (ImageNet-subset, sync-SGD, v4-32 stretch).
+    """The ImageNet-subset stretch model (sync-SGD).
 
     ``norm="group"`` (default) trains from scratch; ``norm="batch"`` is the
     canonical-checkpoint-compatible frozen-BatchNorm variant (see

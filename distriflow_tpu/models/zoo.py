@@ -7,7 +7,7 @@
   hidden width configurable.
 - :func:`mnist_convnet` — the Keras ConvNet the reference ships as
   ``experiment/mnist/model.json`` (Conv2D x2 + MaxPool + dense head).
-- :func:`cifar_convnet` — CIFAR-10 ConvNet for BASELINE config #2.
+- :func:`cifar_convnet` — CIFAR-10 ConvNet (``experiments/cifar10``).
 - MobileNetV2 lives in ``distriflow_tpu/models/mobilenet.py``; the
   transformer (long-context flagship) in ``distriflow_tpu/models/transformer.py``.
 - :func:`flagship_lm_config` / :func:`draft_lm_config` — the small/flagship
@@ -72,7 +72,7 @@ class ConvNet(nn.Module):
 
 
 def mnist_mlp(hidden: int = 10, dtype: Any = jnp.float32) -> ModelSpec:
-    """BASELINE config #1 model (reference ``mnist_server.ts:16-22``)."""
+    """The MNIST parity model (reference ``mnist_server.ts:16-22``)."""
     return spec_from_flax(
         MLP(hidden=hidden, classes=10, dtype=dtype),
         input_shape=(28, 28, 1),
@@ -92,7 +92,7 @@ def mnist_convnet(dtype: Any = jnp.float32) -> ModelSpec:
 
 
 def cifar_convnet(dtype: Any = jnp.float32) -> ModelSpec:
-    """BASELINE config #2/#3 model."""
+    """The CIFAR-10 model of ``experiments/cifar10`` (sync and async)."""
     return spec_from_flax(
         ConvNet(features=(64, 128, 256), classes=10, dense=256, dtype=dtype),
         input_shape=(32, 32, 3),
@@ -106,8 +106,8 @@ def cifar_convnet(dtype: Any = jnp.float32) -> ModelSpec:
 
 def flagship_lm_config(max_seq: int = 2048,
                        dtype: Any = jnp.bfloat16) -> TransformerConfig:
-    """The bench-flagship LM dims (bench.py's ``transformer_lm_flagship``
-    row) as a serving target config."""
+    """The zoo's mid-size LM (d512, 8 layers, vocab 32000) as a serving
+    target config; :func:`draft_lm_config` is its speculative draft."""
     return TransformerConfig(
         vocab_size=32000, d_model=512, n_heads=8, n_layers=8, d_ff=2048,
         max_seq=max_seq, dtype=dtype)

@@ -33,7 +33,6 @@ from distriflow_tpu.obs.health import (
     default_bands,
 )
 from distriflow_tpu.obs.jax_hooks import install_jax_hooks
-from distriflow_tpu.obs.ledger import BenchLedger, band_for, lower_is_better
 from distriflow_tpu.obs.profiler import (
     NOOP_PHASE,
     NOOP_PROFILER,
@@ -79,7 +78,6 @@ from distriflow_tpu.obs.tracing import (
 __all__ = [
     "Assembly",
     "BUCKET_BOUNDS",
-    "BenchLedger",
     "Counter",
     "FleetTable",
     "FlightRecorder",
@@ -106,12 +104,10 @@ __all__ = [
     "Tracer",
     "assemble",
     "assemble_dir",
-    "band_for",
     "default_bands",
     "fit_slope",
     "get_telemetry",
     "install_jax_hooks",
-    "lower_is_better",
     "metric_ident",
     "new_span_id",
     "new_trace_id",

@@ -37,7 +37,7 @@ work carves its slice out of the client's enclosing submit window; the
 quarantine gate carves out of apply), uncovered time is an idle gap
 between named phases, and ``overlap_ms = max(0, busy - wall)`` — the
 same definition the profiler's step digest uses, so the two accountings
-are mutually checkable (bench pins them within 10%).
+are mutually checkable.
 """
 
 from __future__ import annotations

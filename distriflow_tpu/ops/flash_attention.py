@@ -531,8 +531,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
     # dQ = dS K, dK = dS^T Q — four matmuls, 8*B*H*S*S*D (2x forward). The
     # kernels ALSO recompute the scores, but that is remat overhead,
     # excluded from MFU by convention (see ops/flop_count.py docstring);
-    # it IS counted in hw_flops, which is what the roofline divides by
-    # peak: the fused kernel runs 5 matmuls per tile pair, the two-kernel
+    # it IS counted in hw_flops, the work the kernel executes: the fused kernel runs 5 matmuls per tile pair, the two-kernel
     # fallback 7 (s and dp each computed twice).
     causal_div = 2 if causal else 1
     matmul_unit = 2 * b * h * s * s * d // causal_div

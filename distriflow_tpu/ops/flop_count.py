@@ -16,7 +16,8 @@ kernel that recomputes does not get credit for the recompute.
 
 Round 18 adds the **hardware** side of the ledger: ``hw_flops`` is the
 FLOPs the kernel actually executes — model FLOPs PLUS recompute — and it is
-what a roofline time model must divide by peak (``ops/roofline.py``). The
+what a roofline share divides by peak (model FLOPs would flatter a kernel
+that recomputes). The
 two columns make the cost of rematerialization a first-class, queryable
 number: the fused attention backward's whole win is that its ``hw_flops``
 drops from 14 to 10 matmul-units while its model FLOPs (the MFU numerator)
@@ -56,13 +57,11 @@ def record_pallas_cost(
     records GLOBAL row counts (before its own per-data-shard split) while
     the attention kernels record inside their shard_map, per shard; ``SyncTrainer.cost_analysis`` divides the CE
     share by the row-shard degree to keep the per-device convention exact.
-    The roofline model (``ops/roofline.py``) consumes the same categories
-    as its phase taxonomy, so a kernel family that wants a roofline row
-    must tag itself.
+    The benchmark's own counts (``benchmark/lib/flops.py``) are held equal
+    to these categories by ``tests/test_device_clock.py``.
 
     ``hw_flops``: FLOPs the kernel body actually executes (model FLOPs +
-    recompute); defaults to ``flops``. Never folded into MFU — consumed
-    only by the roofline time model.
+    recompute); defaults to ``flops``. Never folded into MFU.
     """
     tally = _TALLY.get()
     if tally is not None:
@@ -97,9 +96,8 @@ def pallas_cost_of(fn, *args, **kwargs) -> Dict[str, float]:
     """Tally of one abstract trace of ``fn(*args, **kwargs)``.
 
     ``jax.eval_shape`` under a fresh tally — no compile, no execution, no
-    data movement. The convenience entry for tests and the roofline model:
-    both need "what would this function's kernels record?" without standing
-    up a trainer. Caveat (the PR 1 warm-cache lesson, pinned by
+    data movement. The convenience entry for tests, which need "what would
+    this function's kernels record?" without standing up a trainer. Caveat (the PR 1 warm-cache lesson, pinned by
     tests/test_depthwise_gn.py): a warm trace cache replays memoized
     jaxprs and skips the Python kernel wrappers, so a zero tally from a
     function KNOWN to contain Pallas calls means the cache ate the trace —

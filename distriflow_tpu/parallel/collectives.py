@@ -8,7 +8,7 @@ AllReduce over ICI — weights and gradients never leave the devices.
 
 Most user code never calls these directly: jit + shardings let XLA insert the
 collectives. They exist for shard_map code (federated local-epoch training,
-ring attention) and for the collective microbenchmarks in ``bench.py``.
+ring attention) and for the doctor's collective-latency check.
 """
 
 from __future__ import annotations
@@ -89,7 +89,7 @@ def allreduce_mean(mesh: Mesh, tree: Any, axis: str = "data") -> Any:
 
 def collective_latency_us(mesh: Mesh, nbytes: int = 4 * 1024 * 1024, axis: str = "data",
                           iters: int = 10) -> float:
-    """Measured allreduce latency for an ``nbytes`` float32 buffer (bench helper)."""
+    """Measured allreduce latency for an ``nbytes`` float32 buffer (doctor)."""
     import time
 
     n = nbytes // 4

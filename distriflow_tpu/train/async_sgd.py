@@ -215,7 +215,7 @@ class AsyncSGDTrainer:
         # continuous phase profiler (docs/OBSERVABILITY.md §5): _phase()
         # feeds the same dt into rolling digests, and worker_loop bounds
         # each pull->fit->submit span with a step() so wall-vs-busy yields
-        # the overlap/idle attribution bench.py reports
+        # the overlap/idle attribution (phase_step_overlap_ms / _idle_ms)
         self._prof = _t.profiler("trainer")
         self._tracer = _t.tracer
         # per-worker-thread round context: when a worker_loop round is open
@@ -949,29 +949,8 @@ class AsyncSGDTrainer:
         flops / (per-step wall x per-chip peak). ``step_seconds`` is the
         per-BATCH wall time (elapsed / batches processed) — the async mode
         is host-coordination-bound by design, so this is chiefly a live
-        audit surface, mirrored into ``train_mfu{mode="async"}`` so the
-        bench cross-check covers the async row like every other MFU row
-        (round-18 satellite)."""
-        if peak_flops_per_chip is None:
-            from distriflow_tpu.train.sync import SyncTrainer
+        audit surface, mirrored into ``train_mfu{mode="async"}``."""
+        from distriflow_tpu.train.sync import _publish_mfu
 
-            kind = jax.devices()[0].device_kind
-            for key, peak in SyncTrainer.PEAK_BF16_FLOPS.items():
-                if key in kind.lower():
-                    peak_flops_per_chip = peak
-                    break
-            else:
-                raise ValueError(
-                    f"unknown device kind {kind!r}; pass peak_flops_per_chip="
-                )
-        analysis = self.cost_analysis(batch_size)
-        if not analysis.get("flops"):
-            raise ValueError(
-                "grad-step cost analysis reports no 'flops' on this "
-                f"backend (keys: {sorted(analysis)}); MFU unavailable")
-        value = float(analysis["flops"]) / (step_seconds * peak_flops_per_chip)
-        get_telemetry().gauge(
-            "train_mfu", mode=gauge_mode,
-            help="model FLOPs utilization vs peak chip FLOPs",
-        ).set(value)
-        return value
+        return _publish_mfu(self.cost_analysis(batch_size), step_seconds,
+                           peak_flops_per_chip, gauge_mode)

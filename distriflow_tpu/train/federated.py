@@ -2,8 +2,8 @@
 
 The reference's "FederatedServer" is really a gradient-mean server — clients
 push per-chunk *gradients*, not locally-trained weights (SURVEY.md §3.2;
-``src/client/federated_client.ts:95-121``). True FedAvg (BASELINE config #4:
-"per-worker local epochs + periodic weight allreduce") is implemented here
+``src/client/federated_client.ts:95-121``). True FedAvg
+("per-worker local epochs + periodic weight allreduce") is implemented here
 the TPU way:
 
 - every mesh device on the ``data`` axis is one federated worker;

@@ -87,6 +87,46 @@ class _SaveItem:
         self.error: Optional[Exception] = None
 
 
+def _publish_mfu(
+    analysis: Dict[str, float],
+    step_seconds: float,
+    peak_flops_per_chip: Optional[float],
+    gauge_mode: str,
+) -> float:
+    """``analysis["flops"]`` / (step time x per-chip peak), mirrored into the
+    ``train_mfu{mode=gauge_mode}`` gauge; the tail both trainers' ``mfu()``
+    share. The peak is looked up from the device kind
+    (``SyncTrainer.PEAK_BF16_FLOPS``) when not given."""
+    if peak_flops_per_chip is None:
+        kind = jax.devices()[0].device_kind
+        for key, peak in SyncTrainer.PEAK_BF16_FLOPS.items():
+            if key in kind.lower():
+                peak_flops_per_chip = peak
+                break
+        else:
+            raise ValueError(
+                f"unknown device kind {kind!r}; pass peak_flops_per_chip="
+            )
+    if not analysis.get("flops"):
+        # a 0.0 here would read as "fully dispatch-bound", not "backend
+        # reports no flop counts" — fail loudly like the unknown-kind path
+        raise ValueError(
+            "compiled-step cost analysis reports no 'flops' on this "
+            f"backend (keys: {sorted(analysis)}); MFU unavailable"
+        )
+    value = float(analysis["flops"]) / (step_seconds * peak_flops_per_chip)
+    # live MFU surface: the health sentinel's mfu_floor band reads this
+    # gauge (docs/OBSERVABILITY.md §6); set only on success so a backend
+    # without flop counts leaves the gauge unregistered rather than pinned
+    # at a stale value. ``gauge_mode`` keys the per-workload series (sync /
+    # async...) so concurrent trainers don't clobber one label
+    get_telemetry().gauge(
+        "train_mfu", mode=gauge_mode,
+        help="model FLOPs utilization vs peak chip FLOPs",
+    ).set(value)
+    return value
+
+
 class SyncTrainer:
     """One-jit-step synchronous trainer over a device mesh.
 
@@ -469,9 +509,8 @@ class SyncTrainer:
                         cat[field] *= self.grad_accum
             analysis["xla_flops"] = float(analysis.get("flops", 0.0))
             analysis["pallas_flops"] = tally["flops"]
-            # hardware-FLOPs + per-kernel-family breakdown for the roofline
-            # time model (ops/roofline.py): hw_flops counts recompute that
-            # the MFU numerator deliberately excludes
+            # hardware-FLOPs + per-kernel-family breakdown: hw_flops counts
+            # recompute that the MFU numerator deliberately excludes
             analysis["pallas_hw_flops"] = tally["hw_flops"]
             analysis["pallas_by_category"] = {
                 k: dict(v) for k, v in tally["by_category"].items()
@@ -526,38 +565,8 @@ class SyncTrainer:
             if self.mean_step_ms is None:
                 raise ValueError("no steps timed yet; pass step_seconds=")
             step_seconds = self.mean_step_ms / 1e3
-        if peak_flops_per_chip is None:
-            kind = jax.devices()[0].device_kind
-            for key, peak in self.PEAK_BF16_FLOPS.items():
-                if key in kind.lower():
-                    peak_flops_per_chip = peak
-                    break
-            else:
-                raise ValueError(
-                    f"unknown device kind {kind!r}; pass peak_flops_per_chip="
-                )
-        analysis = self.cost_analysis(batch)
-        if not analysis.get("flops"):
-            # a 0.0 here would read as "fully dispatch-bound", not "backend
-            # reports no flop counts" — fail loudly like the unknown-kind path
-            raise ValueError(
-                "compiled-step cost analysis reports no 'flops' on this "
-                f"backend (keys: {sorted(analysis)}); MFU unavailable"
-            )
-        value = float(analysis["flops"]) / (step_seconds * peak_flops_per_chip)
-        # live MFU surface: the health sentinel's mfu_floor band and the
-        # bench cross-check read this gauge (docs/OBSERVABILITY.md §6);
-        # set only on success so a backend without flop counts leaves the
-        # gauge unregistered rather than pinned at a stale value.
-        # ``gauge_mode`` keys the per-workload series (sync / mobilenet /
-        # async...) so concurrent bench rows don't clobber one label and
-        # every MFU row can audit ITS OWN gauge (round-18 satellite: the
-        # cross-check previously only ever found mode="sync")
-        get_telemetry().gauge(
-            "train_mfu", mode=gauge_mode,
-            help="model FLOPs utilization vs peak chip FLOPs",
-        ).set(value)
-        return value
+        return _publish_mfu(self.cost_analysis(batch), step_seconds,
+                           peak_flops_per_chip, gauge_mode)
 
     # -- checkpointing -----------------------------------------------------
 

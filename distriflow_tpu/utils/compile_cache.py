@@ -1,6 +1,6 @@
 """Where the persistent XLA compilation cache lives — one rule for every
-entry point (``chip_smoke.py``, ``bench.py``, the ``experiments/`` mains,
-``tests/conftest.py``).
+entry point (``chip_smoke.py``, ``benchmark/run.py``, the ``experiments/``
+mains, ``tests/conftest.py``).
 
 A cold compile of the LM train step or a 12-layer prefill takes tens of
 seconds; the cache turns a second run on the same machine into a load.

@@ -1,6 +1,6 @@
-"""CIFAR-10 data pipeline (BASELINE configs #2/#3).
+"""CIFAR-10 data pipeline.
 
-The reference has no CIFAR experiment — BASELINE.json adds it as a target
+The reference has no CIFAR experiment — it is this repo's own target
 workload. Loader reads the standard "CIFAR-10 python version" pickle batches
 (``data_batch_1..5`` + ``test_batch``: dict with ``b"data"`` uint8
 [n, 3072] row-major CHW and ``b"labels"``); :func:`synthetic_cifar10` is

@@ -1,14 +1,14 @@
-"""CIFAR-10 training entrypoint (BASELINE configs #2/#3/#4).
+"""CIFAR-10 training entrypoint (sync, async and federated modes).
 
 No reference counterpart exists (the reference ships only the MNIST
-experiment); this is the v4-8-targeting workload from BASELINE.md:
+experiment); this is the repo's own one-host workload:
 
 - ``--mode sync``      sync-SGD: batch sharded over the mesh's data axis,
-  gradient mean as an in-graph psum (config #2);
+  gradient mean as an in-graph psum;
 - ``--mode async``     host-coordinated async SGD with bounded staleness
-  (``--max-staleness``, config #3);
+  (``--max-staleness``);
 - ``--mode federated`` federated averaging: K local steps per worker +
-  periodic weight pmean (config #4).
+  periodic weight pmean.
 
 Run:  python -m experiments.cifar10.train --mode sync --steps 100
 """

@@ -1,7 +1,7 @@
-"""ImageNet-subset data pipeline (BASELINE config #5 stretch workload).
+"""ImageNet-subset data pipeline (the stretch workload).
 
-The reference has no ImageNet experiment — BASELINE.json adds it as the
-MobileNetV2/v4-32 stretch. Loader reads a directory-per-class tree of
+The reference has no ImageNet experiment — it is this repo's
+MobileNetV2 stretch. Loader reads a directory-per-class tree of
 pre-decoded ``.npy`` images (the zero-dependency on-disk format this image
 supports; no PIL/TFDS here):
 

@@ -1,6 +1,6 @@
-"""ImageNet-subset MobileNetV2 training entrypoint (BASELINE config #5).
+"""ImageNet-subset MobileNetV2 training entrypoint.
 
-The v4-32 stretch workload: MobileNetV2, sync-SGD, batch sharded over the
+The stretch workload: MobileNetV2, sync-SGD, batch sharded over the
 mesh's data axis with the gradient mean as an in-graph psum. No reference
 counterpart (the reference ships only MNIST).
 

@@ -215,3 +215,16 @@ def test_stage_dataset_rejects_preprocess(devices):
     t.dataset.add_preprocess(lambda x, y: (x * 2, y))
     with pytest.raises(RuntimeError, match="preprocess"):
         t.worker_loop(0, max_steps=1)
+
+
+def test_mfu_is_the_sync_trainers_rule(devices):
+    """``AsyncSGDTrainer.mfu`` is flops / (t * peak) through the helper it
+    shares with ``SyncTrainer.mfu``; a device kind in no peak table (the
+    CPU's here) is an error, never a default."""
+    t, _ = _trainer(n=64, bs=32)
+    flops = t.cost_analysis(32)["flops"]
+    assert flops > 0
+    got = t.mfu(32, step_seconds=2.0, peak_flops_per_chip=flops)
+    np.testing.assert_allclose(got, 0.5, rtol=1e-6)
+    with pytest.raises(ValueError, match="unknown device kind"):
+        t.mfu(32, step_seconds=2.0)

@@ -1,6 +1,6 @@
-"""MobileNetV2 + ImageNet-subset experiment tests (BASELINE config #5).
+"""MobileNetV2 + ImageNet-subset experiment tests.
 
-No reference counterpart — BASELINE.json adds MobileNetV2 as the stretch
+No reference counterpart — MobileNetV2 is this repo's stretch
 workload; these cover the model's shapes/purity, sharded training, and the
 experiment entrypoint's synthetic path.
 """

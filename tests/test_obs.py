@@ -35,6 +35,7 @@ from distriflow_tpu.obs import (
     Telemetry,
     render_prometheus,
 )
+from distriflow_tpu.obs.jax_hooks import install_jax_hooks
 from distriflow_tpu.obs.tracing import SPANS_FILENAME
 from distriflow_tpu.server.abstract_server import DistributedServerConfig
 from distriflow_tpu.server.async_server import AsynchronousSGDServer
@@ -482,3 +483,95 @@ def test_dump_watch_smoke(tmp_path, capsys):
     empty = tmp_path / "empty"
     empty.mkdir()
     assert dump.main([str(empty), "--watch", "--iterations", "1"]) == 2
+
+
+# -- dump --critical-path, malformed lines ----------------------------------
+
+
+def _span_row(name, t0, dur_ms, **attrs):
+    return {"name": name, "trace_id": "f" * 32, "span_id": f"s-{name}",
+            "start": t0 + 500.0, "mono": t0, "pid": 1, "dur_ms": dur_ms,
+            "status": "ok", **attrs}
+
+
+def test_dump_critical_path_cli(tmp_path, capsys):
+    from distriflow_tpu.obs import dump
+
+    rows = [
+        _span_row("upload", 0.0, 80.0, update_id="u1", serialize_ms=5.0),
+        _span_row("apply", 0.05, 10.0, update_id="u1", accepted=True),
+    ]
+    spans = tmp_path / SPANS_FILENAME
+    spans.write_text("".join(json.dumps(r) + "\n" for r in rows)
+                     + "{torn\n")
+    rc = dump.main([str(tmp_path), "--critical-path"])
+    out = capsys.readouterr().out
+    assert rc == 0
+    assert "1 applied" in out and "bound_by=submit" in out
+    assert "1 malformed jsonl line(s) skipped" in out
+    # no spans file: distinct exit code, no traceback
+    rc = dump.main([str(tmp_path / "empty"), "--critical-path"])
+    assert rc == 2
+
+
+def test_dump_counts_malformed_metric_lines(tmp_path, capsys):
+    from distriflow_tpu.obs import dump
+
+    (tmp_path / "metrics.jsonl").write_text(
+        json.dumps({"time": 1.0, "loss": 2.0}) + "\n{half a row\n")
+    (tmp_path / SPANS_FILENAME).write_text(
+        json.dumps(_span_row("upload", 0.0, 5.0)) + "\nnot json at all\n")
+    assert dump.main([str(tmp_path)]) == 0
+    out = capsys.readouterr().out
+    assert out.count("1 malformed line(s) skipped") == 2
+
+
+# -- jax runtime hooks ------------------------------------------------------
+
+
+def test_register_sampler_runs_at_snapshot():
+    tel = Telemetry()
+    calls = []
+    tel.register_sampler(lambda: calls.append(1))
+
+    def bad():
+        raise RuntimeError("sampler must never break a snapshot")
+
+    tel.register_sampler(bad)
+    snap = tel.snapshot()
+    assert calls == [1] and isinstance(snap, dict)
+    tel.snapshot()
+    assert calls == [1, 1]
+
+
+def test_jax_hooks_count_recompiles_not_cache_hits():
+    import jax
+    import jax.numpy as jnp
+
+    tel = Telemetry()
+    assert install_jax_hooks(tel) is True
+    assert install_jax_hooks(tel) is True  # idempotent per telemetry
+
+    @jax.jit
+    def f(a):
+        return a * 2.0 + 1.0
+
+    f(jnp.ones((3, 5))).block_until_ready()
+    after_compile = tel.counter_value("jit_recompiles_total")
+    assert after_compile >= 1, "backend compile did not bump the counter"
+    # steady state: the executable cache serves the same shape — flat
+    f(jnp.ones((3, 5))).block_until_ready()
+    assert tel.counter_value("jit_recompiles_total") == after_compile
+    # shape churn recompiles
+    f(jnp.ones((4, 5))).block_until_ready()
+    assert tel.counter_value("jit_recompiles_total") > after_compile
+    # the memory sampler is wired into snapshot() and must tolerate CPU
+    # backends reporting no stats (gauge simply absent there)
+    snap = tel.snapshot()
+    assert isinstance(snap, dict)
+
+
+def test_install_without_telemetry_uses_global(monkeypatch):
+    # disabled telemetry: nothing to install into, still no crash
+    tel = Telemetry(enabled=False)
+    assert install_jax_hooks(tel) in (True, False)

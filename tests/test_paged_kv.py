@@ -41,7 +41,6 @@ from distriflow_tpu.obs import get_telemetry
 from distriflow_tpu.server import InferenceServer
 from distriflow_tpu.server.inference_server import _PagePool
 from distriflow_tpu.utils.config import ServingConfig
-from distriflow_tpu.obs.ledger import lower_is_better
 
 pytestmark = pytest.mark.paging
 
@@ -103,11 +102,6 @@ def test_serving_config_paged_knobs():
     # default pool == the slab budget: max_slots worst-case slots
     assert srv.pool_pages(48) == 4 * 3
     assert ServingConfig(page_pool_pages=7).pool_pages(48) == 7
-
-
-def test_ledger_occupancy_is_lower_better():
-    assert lower_is_better("page_occupancy")
-    assert not lower_is_better("prefix_hit_rate")
 
 
 # -- device half: bit-identity across layouts ------------------------------
