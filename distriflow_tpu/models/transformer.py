@@ -21,6 +21,7 @@ stay float32.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -806,6 +807,56 @@ def _cast_logits(logits, loss_name, decode=False):
     if not decode and loss_name.startswith("fused_"):
         return logits
     return logits.astype(jnp.float32)
+
+
+#: the modules above that are built with ``dtype=cfg.dtype``: flax casts
+#: their ``kernel`` to the compute dtype at every use. Everything else
+#: (the LayerNorms, the MoE ``router``) computes in float32 from float32
+#: leaves, and casting those would change the result.
+_COMPUTE_DTYPE_MODULES = frozenset(
+    {"q_proj", "k_proj", "v_proj", "o_proj", "wi", "wo", "lm_head"})
+#: leaves cast to ``cfg.dtype`` whatever module holds them (``nn.Embed``'s
+#: table; ``MoEFFN``'s expert stacks, cast by hand at their use)
+_COMPUTE_DTYPE_LEAVES = frozenset({"embedding", "experts_wi", "experts_wo"})
+
+
+@functools.partial(jax.jit, static_argnames="dtype")
+def _cast_leaves(leaves, dtype):
+    return [leaf.astype(dtype) for leaf in leaves]
+
+
+def compute_view(config: TransformerConfig, params: Any) -> Any:
+    """``params`` as the block consumes them: every leaf a module casts to
+    ``config.dtype`` at its use comes back in ``config.dtype``, every
+    other leaf as the array it is. ``TransformerLM.apply`` over the view
+    is bit-equal to ``apply`` over ``params`` (flax's ``promote_dtype`` is
+    the identity on a leaf already in the module's dtype), and a program
+    that takes the view reads the narrow weights instead of re-making
+    them: XLA hoists the casts out of a decode loop and materialises a
+    compute-dtype copy of all weights at the head of every dispatch.
+
+    A leaf already in ``config.dtype`` is returned as the same array, so a
+    tree served in the compute dtype costs no copy and runs no program.
+    The rest are cast by one jitted call; an elementwise cast keeps its
+    operand's sharding, so TP-sharded params stay sharded. A caller whose
+    weights change (``InferenceServer.set_params``) builds the view again.
+    """
+    dtype = jnp.dtype(config.dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    leaves = [leaf for _, leaf in flat]
+    todo = []
+    for i, (path, leaf) in enumerate(flat):
+        names = [getattr(k, "key", None) for k in path]
+        consumed = names[-1] in _COMPUTE_DTYPE_LEAVES or (
+            names[-1] == "kernel" and len(names) > 1
+            and names[-2] in _COMPUTE_DTYPE_MODULES)
+        if consumed and jnp.dtype(leaf.dtype) != dtype:
+            todo.append(i)
+    if not todo:
+        return params
+    for i, cast in zip(todo, _cast_leaves([leaves[i] for i in todo], dtype)):
+        leaves[i] = cast
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 class StageBlocks(nn.Module):

@@ -98,7 +98,11 @@ from distriflow_tpu.models.generate import (
     set_page_tables,
     slot_cache,
 )
-from distriflow_tpu.models.transformer import TransformerConfig, TransformerLM
+from distriflow_tpu.models.transformer import (
+    TransformerConfig,
+    TransformerLM,
+    compute_view,
+)
 from distriflow_tpu.models.zoo import draft_config_for
 from distriflow_tpu.obs import FleetTable, get_telemetry
 from distriflow_tpu.utils.config import ServingConfig
@@ -362,6 +366,7 @@ class InferenceServer:
         self._self_draft = False
         self.draft_config: Optional[TransformerConfig] = None
         self.draft_params: Any = None
+        self._draft_view: Any = None
         self._draft_cache: Any = None
         self._draft_tables = np.zeros((0, 0), np.int32)
         self._draft_tables_dirty = False
@@ -378,6 +383,11 @@ class InferenceServer:
                 variables = TransformerLM(self.draft_config, mesh=None).init(
                     jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))
                 self.draft_params = {"params": variables["params"]}
+            if not self._self_draft:
+                # a separate draft tree is never swapped: its view is
+                # made once, here
+                self._draft_view = compute_view(
+                    self.draft_config, self.draft_params)
             self._draft_tables = np.full(
                 (s, self._pp + 1), self._n_pages, np.int32)
         # serving metrics (contract table in docs/OBSERVABILITY.md §1)
@@ -460,6 +470,19 @@ class InferenceServer:
                  "usable, so the whole pool was copied")
             for p in ("decode", "insert", "spec")}
         self._copy_warned: set = set()  # single-writer: scheduler thread
+        # what the decode loop reads: ``params`` with every weight the
+        # block consumes in ``config.dtype`` already cast (compute_view),
+        # made here and at each set_params, never at a dispatch
+        self._m_view_builds = tel.counter(
+            "serving_param_view_builds_total",
+            help="compute-dtype views made of the served weights: 1 at "
+                 "construction, +1 per set_params, never per dispatch")
+        self._m_view_bytes = tel.gauge(
+            "serving_param_view_bytes",
+            help="bytes the compute-dtype view holds beyond the served "
+                 "weights (0 for weights already in the compute dtype)")
+        self._param_view: Any = None
+        self._build_param_view()
         # continuous phase profiler (docs/OBSERVABILITY.md §5): serving
         # records phases only — the engine loop mostly idles in _gather, so
         # a per-iteration step() would drown the digests in idle wall time
@@ -509,12 +532,33 @@ class InferenceServer:
     def set_params(self, params: Any) -> None:
         """Swap serving weights (e.g. after a training round). Requests
         mid-decode continue on the NEW params from their next chunk — the
-        engine re-reads ``self.params`` every dispatch; the KV cache is
-        config-shaped only, so it survives the swap. Under
+        engine re-reads ``self.params`` and the view of them every
+        dispatch, and the one cast that makes the view runs here; the KV
+        cache is config-shaped only, so it survives the swap. Under
         ``draft_model="self"`` the draft follows automatically —
-        :meth:`_live_draft_params` reads ``self.params`` at dispatch."""
+        :meth:`_live_draft_params` and :meth:`_live_draft_view` read the
+        served tree and its view at dispatch."""
         with self._device_lock:
             self.params = params
+            self._build_param_view()
+
+    def _build_param_view(self) -> None:
+        """Rebind ``_param_view`` to the compute-dtype view of
+        ``self.params``. The programs that run the block in a loop take it
+        (the decode chunk, the speculative round, the one-shot ``generate``
+        and ``beam_search``): XLA hoists a loop's weight casts to the head
+        of the call and re-makes a copy of every weight each dispatch.
+        The programs that run the block once (prefill, extend, ``score``)
+        take ``self.params``: there the cast fuses into the matmul's
+        operand, and on the TPU their float32 form is the one that
+        compiles small (PERF.md §6 PR 31)."""
+        self._param_view = None  # the old copy goes before the new is made
+        self._param_view = compute_view(self.config, self.params)
+        self._m_view_builds.inc()
+        self._m_view_bytes.set(sum(
+            v.nbytes for p, v in zip(jax.tree.leaves(self.params),
+                                     jax.tree.leaves(self._param_view))
+            if v is not p))
 
     def lower_decode(self, sampling: bool = False) -> "jax.stages.Lowered":
         """The engine's decode-chunk program lowered at the live cache's
@@ -528,12 +572,16 @@ class InferenceServer:
             self.config, self.serving.decode_chunk, sampling)
         with self._device_lock:
             return decode.lower(
-                self.params, self._slot_cache, self._tok, self._done,
-                self._temps, self._top_ks, self._top_ps, self._seeds,
-                self._eos)
+                self._param_view, self._slot_cache, self._tok,
+                self._done, self._temps, self._top_ks, self._top_ps,
+                self._seeds, self._eos)
 
     def _live_draft_params(self) -> Any:
         return self.params if self._self_draft else self.draft_params
+
+    def _live_draft_view(self) -> Any:
+        """What the draft's loop programs read (``_build_param_view``)."""
+        return self._param_view if self._self_draft else self._draft_view
 
     # -- config accessors (None -> module constant, read at use time so
     #    tests that monkeypatch the constants keep working) ----------------
@@ -803,7 +851,7 @@ class InferenceServer:
                 f"generate[{prompt.shape[0]}x{prompt.shape[1]}+{n_tokens}]"
             ):
                 out = generate(
-                    self.config, self.params, prompt, n_tokens,
+                    self.config, self._param_view, prompt, n_tokens,
                     temperature=temperature,
                     top_k=int(top_k) if top_k is not None else None,
                     top_p=float(top_p) if top_p is not None else None,
@@ -1406,9 +1454,9 @@ class InferenceServer:
                 with self._prof.phase("decode_dispatch"), \
                         self._donating("decode", self._slot_cache):
                     self._slot_cache, tok, done, toks = decode(
-                        self.params, self._slot_cache, self._tok, self._done,
-                        self._temps, self._top_ks, self._top_ps, self._seeds,
-                        self._eos)
+                        self._param_view, self._slot_cache, self._tok,
+                        self._done, self._temps, self._top_ks, self._top_ps,
+                        self._seeds, self._eos)
                 td1 = time_mod.monotonic()
                 with self._prof.phase("token_fetch"):
                     # np.array, not np.asarray: device outputs arrive as
@@ -1489,7 +1537,7 @@ class InferenceServer:
                 self._draft_cache = set_page_tables(
                     self._draft_cache, self._draft_tables.copy())
                 self._draft_tables_dirty = False
-            dparams = self._live_draft_params()
+            dparams = self._live_draft_view()
             with self._prof.phase("spec_draft"):
                 with self._donating("spec", self._draft_cache):
                     self._draft_cache, drafts, qprobs = draft_k(
@@ -1501,8 +1549,8 @@ class InferenceServer:
                 with self._donating("spec", self._slot_cache):
                     (self._slot_cache, emit, n_emit, n_acc, new_tok,
                      new_done, catch, new_idx) = verify(
-                        self.params, self._slot_cache, self._tok, drafts,
-                        qprobs, self._temps, self._top_ks, self._top_ps,
+                        self._param_view, self._slot_cache, self._tok,
+                        drafts, qprobs, self._temps, self._top_ks, self._top_ps,
                         self._seeds, self._done, self._eos)
                 emit = np.array(emit)
                 n_emit = np.array(n_emit)
@@ -1736,7 +1784,7 @@ class InferenceServer:
             f"beam[{prompt.shape[0]}x{prompt.shape[1]}+{n_tokens} k={beam_size}]"
         ):
             out, scores = beam_search(
-                self.config, self.params, prompt, n_tokens,
+                self.config, self._param_view, prompt, n_tokens,
                 beam_size=beam_size, length_penalty=length_penalty,
                 eos_id=int(eos_id) if eos_id is not None else None,
             )
