@@ -14,7 +14,8 @@ to the top-k logits and/or a top-p (nucleus) cumulative-probability mass.
 The cache holds ``max_seq`` positions per layer; ``prompt_len + n_tokens``
 must fit.
 
-MoE configs decode with **dense dispatch** (see :func:`_decode_module`):
+MoE configs decode with **dense dispatch** (see
+:func:`_transformer_decode_module`):
 every token goes to its true top-1 expert, no capacity drops — decode is
 group-independent and matches the dense-dispatch training forward exactly.
 Divergence from a *capacity-routed* training forward is bounded by the
@@ -39,7 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +76,25 @@ def _truncate_logits(
     return logits
 
 
-def _decode_module(config: TransformerConfig) -> TransformerLM:
+class DecodeFamily(NamedTuple):
+    """What a model family declares to the decoding paths and the serving
+    engine, which otherwise walk the cache by structure and know no model:
+    the cache leaves that hold the bytes, per token (``[B, max_seq, F]``
+    slabs that become ``[n_pages, page_size, F]`` pools under the paged
+    layout, and that the engine's programs donate), the decode-mode module
+    (``apply`` with a mutable ``cache`` collection: prefill from an empty
+    cache, continuation against one, ``cache_index`` scalar or per row,
+    paged when a ``page_table`` leaf is present), and the teacher-forced
+    forward ``score`` uses. A configuration class names its family in a
+    ``decode_family`` attribute; one without it is ``TransformerConfig``'s.
+    """
+
+    pool_leaves: Tuple[str, ...]
+    decode_module: Callable[[Any], Any]
+    score_logits: Callable[[Any], Callable[[Any, jnp.ndarray], jnp.ndarray]]
+
+
+def _transformer_decode_module(config: TransformerConfig) -> TransformerLM:
     """The decode-mode module all decoding paths share: sharded-attention
     variants never apply to incremental decoding.
 
@@ -100,6 +119,30 @@ def _decode_module(config: TransformerConfig) -> TransformerLM:
     return TransformerLM(cfg, mesh=None, decode=True)
 
 
+def _transformer_score_logits(config: TransformerConfig):
+    cfg = dataclasses.replace(
+        config, use_ring_attention=False, use_ulysses_attention=False
+    )
+    return TransformerLM(cfg, mesh=None).apply  # training-mode forward
+
+
+#: the per-layer cache leaves that move from [max_slots, max_seq, F]
+#: slabs to [n_pages, page_size, F] pools under the paged layout
+_POOL_LEAVES = ("cached_k", "cached_v", "k_scale", "v_scale")
+
+_TRANSFORMER_FAMILY = DecodeFamily(
+    _POOL_LEAVES, _transformer_decode_module, _transformer_score_logits)
+
+
+def decode_family(config: Any) -> DecodeFamily:
+    return getattr(config, "decode_family", _TRANSFORMER_FAMILY)
+
+
+def _decode_module(config: Any) -> Any:
+    """The decode-mode module of ``config``'s family."""
+    return decode_family(config).decode_module(config)
+
+
 def _check_fits(p: int, n_tokens: int, config: TransformerConfig) -> None:
     if p + n_tokens > config.max_seq:
         raise ValueError(
@@ -118,7 +161,7 @@ def _gate_kv_dtype(config: TransformerConfig,
     with (``kv_cache_dtype_for``). ``int8_force`` is never demoted, and
     the replace is a no-op (same hashable config, same ``_build_fns``
     cache entry) whenever the two gates agree."""
-    if (config.kv_cache_dtype == "int8"
+    if (getattr(config, "kv_cache_dtype", None) == "int8"
             and config.kv_cache_dtype_for(context_len) is None
             and config.resolved_kv_cache_dtype == "int8"):
         return dataclasses.replace(config, kv_cache_dtype=None)
@@ -316,14 +359,11 @@ def beam_search(
 
 @functools.lru_cache(maxsize=16)
 def _build_score_fn(config: TransformerConfig):
-    cfg = dataclasses.replace(
-        config, use_ring_attention=False, use_ulysses_attention=False
-    )
-    module = TransformerLM(cfg, mesh=None)  # training-mode forward
+    forward = decode_family(config).score_logits(config)
 
     @jax.jit
     def score(params, tokens, from_pos):
-        logits = module.apply(params, tokens[:, :-1])
+        logits = forward(params, tokens[:, :-1])
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         target = jnp.take_along_axis(
             logp, tokens[:, 1:, None].astype(jnp.int32), axis=-1
@@ -457,23 +497,19 @@ def slot_cache(config: TransformerConfig, params, max_slots: int):
     return build(_as_dict(shapes))
 
 
-#: the per-layer cache leaves that move from [max_slots, max_seq, F]
-#: slabs to [n_pages, page_size, F] pools under the paged layout
-_POOL_LEAVES = ("cached_k", "cached_v", "k_scale", "v_scale")
-
-
-def _split_pools(cache):
-    """``(pools, rest)``: the cache's ``_POOL_LEAVES`` and everything
+def _split_pools(cache, leaves=_POOL_LEAVES):
+    """``(pools, rest)``: the cache's pool ``leaves`` (its family's
+    ``pool_leaves``; ``TransformerConfig``'s by default) and everything
     else, each under the cache's own nesting. The pools are the bytes
     and are always distinct buffers; the rest (``page_table``,
     ``cache_index``: a few KB) is what :func:`set_page_tables` and
     :func:`_set_cache_positions` put ONE array into for every layer."""
     pools, rest = {}, {}
     for name, sub in cache.items():
-        if name in _POOL_LEAVES:
+        if name in leaves:
             pools[name] = sub
         elif hasattr(sub, "items"):
-            pools[name], rest[name] = _split_pools(sub)
+            pools[name], rest[name] = _split_pools(sub, leaves)
         else:
             rest[name] = sub
     return pools, rest
@@ -504,9 +540,10 @@ class _CacheProgram:
     same arguments as the call; ``body`` is the undecorated function
     (the tests jit it plainly as the undonated oracle)."""
 
-    def __init__(self, fn, cache_arg: int):
+    def __init__(self, fn, cache_arg: int, pool_leaves=_POOL_LEAVES):
         self.body = fn
         self._cache_arg = cache_arg
+        self._pool_leaves = pool_leaves
 
         def program(pools, *args):
             args = list(args)
@@ -519,7 +556,8 @@ class _CacheProgram:
 
     def _split(self, args):
         args = list(args)
-        pools, args[self._cache_arg] = _split_pools(args[self._cache_arg])
+        pools, args[self._cache_arg] = _split_pools(
+            args[self._cache_arg], self._pool_leaves)
         return pools, args
 
     def __call__(self, *args):
@@ -531,9 +569,10 @@ class _CacheProgram:
         return self._jit.lower(pools, *args)
 
 
-def _donates_cache(cache_arg: int):
-    """Decorator form of :class:`_CacheProgram`."""
-    return functools.partial(_CacheProgram, cache_arg=cache_arg)
+def _donates_cache(cache_arg: int, config: Any):
+    """Decorator form of :class:`_CacheProgram`, for ``config``'s pools."""
+    return functools.partial(_CacheProgram, cache_arg=cache_arg,
+                             pool_leaves=decode_family(config).pool_leaves)
 
 
 def pages_per_slot(max_seq: int, page_size: int) -> int:
@@ -560,6 +599,7 @@ def paged_cache(config: TransformerConfig, params, max_slots: int,
         raise ValueError(f"n_pages must be positive, got {n_pages}")
     pp = pages_per_slot(config.max_seq, page_size)
     module = _decode_module(config)
+    pool_leaves = decode_family(config).pool_leaves
     dummy = jnp.zeros((max_slots, 1), jnp.int32)
     shapes = jax.eval_shape(
         lambda p: module.apply(p, dummy, mutable=["cache"])[1]["cache"],
@@ -573,7 +613,7 @@ def paged_cache(config: TransformerConfig, params, max_slots: int,
                     out[name] = jnp.zeros((max_slots,), jnp.int32)
                     out["page_table"] = jnp.full(
                         (max_slots, pp + 1), n_pages, jnp.int32)
-                elif name in _POOL_LEAVES:
+                elif name in pool_leaves:
                     out[name] = jnp.zeros(
                         (n_pages, page_size) + sub.shape[2:], sub.dtype)
                 else:
@@ -625,8 +665,9 @@ def _build_paged_fns(config: TransformerConfig, page_size: int):
     :func:`_build_slot_fns` programs serve both layouts."""
     max_seq = config.max_seq
     pp = pages_per_slot(max_seq, page_size)
+    pool_leaves = decode_family(config).pool_leaves
 
-    @_donates_cache(0)
+    @_donates_cache(0, config)
     def insert(cache, row_cache, slots, length, start, table):
         row_cache = _as_dict(row_cache)
         r = slots.shape[0]
@@ -651,7 +692,7 @@ def _build_paged_fns(config: TransformerConfig, page_size: int):
                 elif name == "cache_index":
                     out[name] = d.at[slots].set(
                         jnp.broadcast_to(length, slots.shape).astype(d.dtype))
-                elif name in _POOL_LEAVES:
+                elif name in pool_leaves:
                     out[name] = scatter_pool(d, src[name].astype(d.dtype))
                 elif hasattr(d, "items"):
                     out[name] = walk(d, src[name])
@@ -670,7 +711,7 @@ def _build_paged_fns(config: TransformerConfig, page_size: int):
                     continue
                 if name == "cache_index":
                     out[name] = jnp.asarray(start, jnp.int32)
-                elif name in _POOL_LEAVES:
+                elif name in pool_leaves:
                     n_pg, ps = sub.shape[0], sub.shape[1]
                     tab = jnp.minimum(tables[:, :pp], n_pg - 1)
                     g = sub[tab].reshape(
@@ -751,17 +792,27 @@ def _build_slot_fns(config: TransformerConfig, chunk: int,
     cache, so switching mid-flight is free)."""
     module = _decode_module(config)
 
-    @_donates_cache(0)
+    pool_leaves = decode_family(config).pool_leaves
+
+    @_donates_cache(0, config)
     def insert(cache, row_cache, slots, length):
         row_cache = _as_dict(row_cache)
 
-        def put(dst, src):
-            if src.ndim == 0:  # scalar cache_index -> one entry per slot
-                return dst.at[slots].set(
-                    jnp.broadcast_to(length, slots.shape).astype(dst.dtype))
-            return dst.at[slots].set(src.astype(dst.dtype))
+        def walk(dst, src):
+            out = {}
+            for name, d in dst.items():
+                if name == "cache_index":  # scalar -> one entry per slot
+                    out[name] = d.at[slots].set(
+                        jnp.broadcast_to(length, slots.shape).astype(d.dtype))
+                elif name in pool_leaves:
+                    out[name] = d.at[slots].set(src[name].astype(d.dtype))
+                elif hasattr(d, "items"):
+                    out[name] = walk(d, src[name])
+                else:  # the engine's own leaf, not a row's
+                    out[name] = d
+            return out
 
-        return jax.tree.map(put, cache, row_cache)
+        return walk(cache, row_cache)
 
     def _pick(logits, temps, top_ks, top_ps, seeds, positions):
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -781,7 +832,7 @@ def _build_slot_fns(config: TransformerConfig, chunk: int,
     def pick_rows(logits, temps, top_ks, top_ps, seeds, positions):
         return _pick(logits, temps, top_ks, top_ps, seeds, positions)
 
-    @_donates_cache(1)
+    @_donates_cache(1, config)
     def decode(params, cache, tok, done, temps, top_ks, top_ps, seeds, eos):
         def step(carry, _):
             cache, tok, done = carry
@@ -903,7 +954,7 @@ def _build_spec_fns(config: TransformerConfig,
         return jax.random.fold_in(
             jax.random.fold_in(jax.random.PRNGKey(seed), pos), tag)
 
-    @_donates_cache(1)
+    @_donates_cache(1, draft_config)
     def draft_k(d_params, d_cache, tok, temps, top_ks, top_ps, seeds):
         def dstep(carry, _):
             cache, tk = carry
@@ -933,7 +984,7 @@ def _build_spec_fns(config: TransformerConfig,
             dstep, (d_cache, tok), None, length=k)
         return d_cache, drafts.T, jnp.transpose(qs, (1, 0, 2))
 
-    @_donates_cache(1)
+    @_donates_cache(1, config)
     def verify(params, cache, tok, drafts, qprobs, temps, top_ks, top_ps,
                seeds, done, eos):
         b = tok.shape[0]
@@ -1030,7 +1081,7 @@ def _build_spec_fns(config: TransformerConfig,
         return (_set_cache_positions(cache, new_idx), emit, n_emit, n_acc,
                 new_tok, new_done, catch_up, new_idx)
 
-    @_donates_cache(1)
+    @_donates_cache(1, draft_config)
     def commit(d_params, d_cache, last_draft, catch_up, new_idx):
         cur = _cache_positions(d_cache)  # p + k after the draft scan
         divert = jnp.where(

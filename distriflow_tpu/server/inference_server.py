@@ -91,6 +91,7 @@ from distriflow_tpu.models.generate import (
     _check_fits,
     _find_cache_leaf,
     beam_search,
+    decode_family,
     generate,
     paged_cache,
     pages_per_slot,
@@ -338,6 +339,9 @@ class InferenceServer:
         # next insert/decode dispatch re-uploads it, so a retired slot's
         # frozen writes can never land in a page the pool has re-issued.
         self._paged = self.serving.kv_layout == "paged"
+        # a cache leaf every layer of either family holds: the one whose
+        # buffer says whether a donating call took the pools over
+        self._pool_leaf = decode_family(config).pool_leaves[0]
         self._pp = pages_per_slot(config.max_seq, self.serving.page_size)
         self._n_pages = self.serving.pool_pages(config.max_seq)
         self._pool = _PagePool(self._n_pages) if self._paged else None
@@ -1082,7 +1086,7 @@ class InferenceServer:
         in place). One still alive means XLA could not alias it and the
         call copied the whole pool. ``is_deleted`` reads a flag: no device
         sync. A call that raises is not counted."""
-        pool = _find_cache_leaf(cache, "cached_k")
+        pool = _find_cache_leaf(cache, self._pool_leaf)
         yield
         if pool.is_deleted():
             self._m_cache_donated[program].inc()
@@ -1107,7 +1111,7 @@ class InferenceServer:
         and this a no-op. Returns whether the caches were dropped."""
         caches = (self._slot_cache, self._draft_cache)
         if not any(c is not None
-                   and _find_cache_leaf(c, "cached_k").is_deleted()
+                   and _find_cache_leaf(c, self._pool_leaf).is_deleted()
                    for c in caches):
             return False
         self.logger.log(f"engine error: KV pools lost to a failed call, "
