@@ -474,6 +474,28 @@ class InferenceServer:
                  "usable, so the whole pool was copied")
             for p in ("decode", "insert", "spec")}
         self._copy_warned: set = set()  # single-writer: scheduler thread
+        # a family whose decode step reads a chosen part of the context and
+        # of its experts says how much (``config.decode_work``); the engine
+        # counts it per dispatch. TransformerConfig declares none.
+        self._decode_work = getattr(config, "decode_work", None)
+        self._expert_stats_seen = np.zeros((2,), np.int64)
+        if self._decode_work is not None:
+            self._m_ctx_tokens = tel.counter(
+                "serving_context_tokens_total",
+                help="cached tokens of the live rows, summed over decode "
+                     "dispatches (at each dispatch's start)")
+            self._m_sel_tokens = tel.counter(
+                "serving_sparse_selected_tokens_total",
+                help="of those, the tokens sparse attention selects")
+            self._m_experts_run = tel.counter(
+                "serving_experts_run_total",
+                help="held experts that ran, summed over sparse layers and "
+                     "decode steps")
+            self._m_assignments = {held: tel.counter(
+                "serving_expert_assignments_total", held=held,
+                help="(token, expert) choices of live rows in decode steps, "
+                     "by whether this server holds the expert")
+                for held in ("yes", "no")}
         # what the decode loop reads: ``params`` with every weight the
         # block consumes in ``config.dtype`` already cast (compute_view),
         # made here and at each set_params, never at a dispatch
@@ -1100,6 +1122,21 @@ class InferenceServer:
                 "were not usable); serving_cache_copies_total counts every "
                 "such dispatch", RuntimeWarning, stacklevel=3)
 
+    def _expert_stats(self) -> np.ndarray:
+        """``(experts run, assignments held here)`` so far, summed over the
+        sparse layers: the cache's ``expert_stats`` leaves."""
+        found: List[Any] = []
+
+        def walk(node):
+            for name, sub in node.items():
+                if name == "expert_stats":
+                    found.append(sub)
+                elif hasattr(sub, "items"):
+                    walk(sub)
+
+        walk(self._slot_cache)
+        return np.sum(jax.device_get(found), axis=0, dtype=np.int64)
+
     def _drop_dead_cache(self, err: Exception) -> bool:
         """After a failed device call: a donating program that fails once
         it has started executing has consumed the pools it was passed, and
@@ -1159,6 +1196,7 @@ class InferenceServer:
             return
         with self._prof.phase("admission"):
             if self._slot_cache is None:
+                self._expert_stats_seen[:] = 0  # a fresh cache counts from 0
                 with self._device_lock:
                     if self._paged:
                         self._slot_cache = paged_cache(
@@ -1438,6 +1476,9 @@ class InferenceServer:
                 ps = srv.page_size
                 stats["live_pages"] = sum(-(-c // ps) for c in ctx)
                 stats["table_pages"] = len(self._slot_req) * self._pp
+            if self._decode_work is not None:
+                work = self._decode_work(ctx, srv.decode_chunk)
+                stats["sel_tokens"] = work["sel_tokens"]
         with self._prof.phase("decode_iter", **stats):
             sampling = bool((self._temps[active] > 0).any())
             _insert, _pick, decode = _build_slot_fns(
@@ -1469,10 +1510,29 @@ class InferenceServer:
                     tok = np.array(tok)
                     done = np.array(done)
                     toks = np.array(toks)
+                    if stats and self._decode_work is not None:
+                        # a few int32 of a program that has finished: the
+                        # fetch waits for nothing
+                        seen = self._expert_stats()
+                        run_now, local = seen - self._expert_stats_seen
+                        self._expert_stats_seen = seen
+                        stats.update(experts_hit=int(run_now),
+                                     local_assignments=int(local))
             t1 = time_mod.monotonic()
             elapsed_ms = (t1 - t0) * 1000.0
             dispatch_ms = round((td1 - td0) * 1000.0, 3)
             fetch_ms = round((t1 - td1) * 1000.0, 3)
+            sparse = {}
+            if "experts_hit" in stats:
+                sparse = {k: stats[k] for k in (
+                    "ctx_tokens", "sel_tokens", "experts_hit",
+                    "local_assignments")}
+                self._m_ctx_tokens.inc(stats["ctx_tokens"])
+                self._m_sel_tokens.inc(stats["sel_tokens"])
+                self._m_experts_run.inc(stats["experts_hit"])
+                self._m_assignments["yes"].inc(stats["local_assignments"])
+                self._m_assignments["no"].inc(
+                    work["assignments"] - stats["local_assignments"])
             self.decode_batches += 1
             self._m_batches.inc()
             self._tok = tok
@@ -1497,7 +1557,8 @@ class InferenceServer:
                     self._slot_emit_t[s] = t1
                     self._req_span(req, "decode_iter", t0, elapsed_ms,
                                    slot=s, n_active=len(active), take=take,
-                                   dispatch_ms=dispatch_ms, fetch_ms=fetch_ms)
+                                   dispatch_ms=dispatch_ms, fetch_ms=fetch_ms,
+                                   **sparse)
                     req.rows_out[row] = np.concatenate(
                         [req.rows_out[row], chunk_toks])
                     if done[s]:

