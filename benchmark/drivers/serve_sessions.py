@@ -20,10 +20,22 @@ generate), ``score_tokens`` (``score()`` against the reference, past
 ``index_topk``, where the program's selected sets are also compared with
 the reference's), ``check_replies_per_length`` (replies re-scored after
 the window, per context length).
+
+``correct`` has three kinds of comparison with the plain reference, each
+with its limits below: the whole model on the same tokens (``score()``, the
+chosen sets, the replies' logits), which bfloat16 matmuls already move by
+hundredths of a nat; each float32 piece (a norm, the router) on the
+program's own inputs to it, which nothing upstream moves and which is what
+tells float32 from bfloat16 there (:func:`precision_control` runs the
+program lowered and has to come out not ``correct``); and the sets one
+reply's turn and generated tokens chose on the path the window timed
+(``extend`` against the resident context, then decode from pages).
 """
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import gc
 import time
 from typing import Any, Dict, List, Optional
@@ -44,9 +56,10 @@ _latencies = base._latencies  # what rehearsal/knee_sweep.py asks a driver for
 # "highest". Selection and routing are discrete: a near-tie that falls the
 # other way changes one position's set or one token's expert, so single
 # positions differ by tenths of a nat while the mean does not move.
-# score(): the mean difference per token. Read 5.8e-5 to 1.2e-3 over five
+# score(): the mean difference per token. Read 5.8e-5 to 1.3e-3 over eighteen
 # seeds on the chip; a wrong mask, scale or rotary pairing shifts every
-# position one way, by 0.1 nats and more.
+# position one way, by 0.1 nats and more (planted at toy widths in
+# tests/test_latent_sparse_benchmark.py).
 SCORE_NATS_PER_TOKEN = 4e-3
 # Replies are judged on the reference's logits, not on token identity (the
 # near-tie rule of serve_loop.py): a generated token's reference
@@ -70,6 +83,33 @@ GREEDY_WORST_NATS = 2.0
 # scrambles the ranking.
 SELECTION_OVERLAP_MIN = 0.95
 ROUTING_OVERLAP_MIN = 0.95
+# The limits above have sound readings only: the same weights with the norms
+# and the router computed in bfloat16 read inside every one of them (PERF.md
+# §6 PR 33), because the bfloat16 matmuls upstream move each logit by more.
+# What tells the two apart is each float32 piece on the program's own input
+# to it, over score()'s tokens (readings: PERF.md §6 PR 33, stated /
+# lowered). A norm's output is the float32 result rounded once to the
+# compute dtype: the share of its values that are (to 1e-5, room for a
+# float32 sum in another order), lowest of the 21 norms, reads 1.00000 /
+# 0.00013.
+NORM_MATCH_MIN = 0.99
+# The router's affinities against sigmoid(W_r m) at "highest" on the same m:
+# the largest difference reads 0.0 / 2.0e-3 (bfloat16 resolves 2e-3 to 4e-3
+# under 1).
+AFFINITY_ERR_MAX = 1e-4
+# Its choice of held experts against the k largest of those affinities plus
+# the bias, as intersection over union: 1.00000 / 0.99607 (a bfloat16
+# affinity moves the eighth and ninth of 256 past one another in one token
+# of ten, and one choice in sixteen is of a held expert).
+OWN_ROUTING_MIN = 0.998
+# One reply's sets on the served path (its turn through extend, its
+# generated tokens through decode steps from pages) against the
+# reference's: the limits above, but the routing of some 250 tokens is
+# some 500 (token, held expert) pairs, and a share of them scatters by 0.7
+# points; read 0.9773-0.9813 (selection) and 0.9589-0.9906 (routing) on
+# 160-330 positions behind 8,192, five seeds. Rows read from a wrong page
+# scramble both.
+SERVED_ROUTING_MIN = 0.92
 
 
 def program_config(c: Dict[str, Any]) -> Any:
@@ -96,7 +136,8 @@ def program_config(c: Dict[str, Any]) -> Any:
         index_rope_dim=c["index_rope_dim"],
         rope_base=float(c["rope_parameters"]["rope_theta"]),
         rms_eps=c["rms_norm_eps"], dtype=getattr(jnp, c["compute_dtype"]),
-        param_dtype=getattr(jnp, c["param_dtype"]))
+        param_dtype=getattr(jnp, c["param_dtype"]),
+        norm_router_dtype=getattr(jnp, c["norm_router_dtype"]))
 
 
 def reference_model(c: Dict[str, Any]) -> Dict[str, Any]:
@@ -127,37 +168,183 @@ class _Callers(base._Callers):
                             req.offset, self.turn_tokens)
 
 
+def _picked(cfg: Any, sown: Any) -> Dict[str, Any]:
+    """What a forward chose, from the values it sows (traced): per ``full``
+    layer the selected positions ``(idx, valid)``, per sparse layer the
+    held experts routed to."""
+    out: Dict[str, Any] = {"selected": {}, "routed": {}}
+    for i, layer in enumerate(cfg.indexer_types):
+        if layer == "full":
+            out["selected"][i] = sown[f"layers_{i}"]["attn"]["selected"][0]
+        if cfg.mlp_layer_types[i] == "sparse":
+            out["routed"][i] = sown[f"layers_{i}"]["mlp"]["routed"][0]
+    return out
+
+
+def _as_sets(cfg: Any, picked: Dict[str, Any], n: int):
+    """``_picked`` of ``n`` tokens on the host: ``[n, max_seq]`` bool per
+    ``full`` layer and ``[n, held]`` bool per sparse layer."""
+    selected, routed = {}, {}
+    for i, (idx, valid) in picked["selected"].items():
+        mask = np.zeros((n, cfg.max_seq), bool)
+        np.put_along_axis(mask, np.asarray(idx).reshape(n, -1),
+                          np.asarray(valid).reshape(n, -1), axis=1)
+        selected[i] = mask
+    for i, r in picked["routed"].items():
+        routed[i] = np.asarray(r).reshape(n, -1)
+    return selected, routed
+
+
+def _own_inputs(cfg: Any, p: Any, sown: Any) -> Dict[str, Any]:
+    """Each float32 piece of the program against the reference's on the
+    program's own input to it (traced): ``norm_match``, ``affinity_err``,
+    ``own_routing`` as the limits above define them."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    match, err, both, either = [], [], [], []
+
+    def walk(node, pnode):
+        for name, sub in node.items():
+            if name == "io":  # an RMSNorm's input and output
+                x, y = sub[0]
+                want = reference.rms_norm(x.astype(f32), pnode["scale"])
+                want = want.astype(y.dtype).astype(f32)
+                match.append(jnp.mean(jnp.abs(y.astype(f32) - want)
+                                      <= 1e-5 * jnp.abs(want) + 1e-7))
+            elif name == "router_io":  # the router's input and affinities
+                m, got = sub[0]
+                want = reference.affinity(pnode, m)
+                err.append(jnp.max(jnp.abs(got - want)))
+                _, chosen = jax.lax.top_k(
+                    want + pnode["e_score_correction_bias"],
+                    cfg.n_experts_per_tok)
+                first, count = cfg.experts_held
+                held = jnp.any(chosen[..., None] == first + jnp.arange(count),
+                               axis=-2)
+                routed = node["routed"][0]
+                both.append(jnp.sum(held & routed))
+                either.append(jnp.sum(held | routed))
+            elif hasattr(sub, "items"):
+                walk(sub, pnode[name])
+
+    walk(sown, p)
+    return {"norm_match": jnp.min(jnp.stack(match)),
+            "affinity_err": jnp.max(jnp.stack(err)),
+            "own_routing": sum(both) / sum(either)}
+
+
+def _own_within(own: Dict[str, float]) -> bool:
+    return (own["norm_match"] >= NORM_MATCH_MIN
+            and own["affinity_err"] <= AFFINITY_ERR_MAX
+            and own["own_routing"] >= OWN_ROUTING_MIN)
+
+
 def _system_sets(cfg: Any, params: Any, tokens: np.ndarray):
-    """What the program's forward chooses on ``tokens``, from the values it
-    sows: ``[S, S]`` bool per ``full`` layer (the selected positions) and
-    ``[S, held]`` bool per sparse layer (the held experts routed to)."""
+    """What the program's teacher-forced forward chooses on ``tokens``
+    (``_as_sets``) and how its float32 pieces read (``_own_inputs``)."""
     import jax
 
     from distriflow_tpu.models.latent_sparse import LatentSparseLM
 
     module = LatentSparseLM(cfg)
-    _, state = jax.jit(lambda p, t: module.apply(
-        p, t, mutable=["cache", "intermediates"]))(params, tokens[None])
+
+    def forward(p, t):
+        _, state = module.apply(p, t, mutable=["cache", "intermediates"])
+        sown = state["intermediates"]
+        return _picked(cfg, sown), _own_inputs(cfg, p["params"], sown)
+
+    picked, own = jax.jit(forward)(params, tokens[None])
+    selected, routed = _as_sets(cfg, picked, len(tokens))
     n = len(tokens)
-    selected, routed = {}, {}
-    for i, layer in enumerate(cfg.indexer_types):
-        sown = state["intermediates"][f"layers_{i}"]
-        if layer == "full":
-            idx, valid = (np.asarray(v[0]) for v in sown["attn"]["selected"][0])
-            mask = np.zeros((n, cfg.max_seq), bool)
-            np.put_along_axis(mask, idx, valid, axis=1)
-            selected[i] = mask[:, :n]
-        if cfg.mlp_layer_types[i] == "sparse":
-            routed[i] = np.asarray(sown["mlp"]["routed"][0])
-    return selected, routed
+    return ({i: m[:, :n] for i, m in selected.items()}, routed,
+            {k: float(v) for k, v in own.items()})
 
 
 def _held(got: Dict[int, np.ndarray], want: Dict[int, np.ndarray],
-          rows: np.ndarray) -> float:
+          rows: Any = slice(None)) -> float:
     """Share of the reference's choices on ``rows``, over the layers, that
     the program's choices hold."""
     return float(sum((got[i][rows] & want[i][rows]).sum() for i in want)
                  / sum(want[i][rows].sum() for i in want))
+
+
+@contextlib.contextmanager
+def _not_kept():
+    """Programs compiled inside stay out of the persistent compile cache:
+    they serve the check of one reply after the window, and the cache that
+    comes with a machine is small enough for them to push out programs
+    that set-up loads (PERF.md §6 PR 33), which `setup_s` would then pay."""
+    import jax
+
+    key = "jax_persistent_cache_min_compile_time_secs"
+    was = getattr(jax.config, key)
+    jax.config.update(key, 1e9)
+    try:
+        yield
+    finally:
+        jax.config.update(key, was)
+
+
+def _served_sets(cfg: Any, serving: Any, params: Any, toks: np.ndarray,
+                 ctx_len: int, prompt_len: int):
+    """What the program chose for one reply ``toks`` (context, turn,
+    generated tokens) on the path the window timed, from position
+    ``ctx_len`` on: the context prefilled in the engine's chunks by the
+    engine's own programs, the turn through ``extend`` against that row
+    cache, the row scattered into a page pool by the engine's ``insert``
+    (physical pages in reverse order, so that the table is read), and every
+    generated token but the last fed through a decode step against the
+    pool. Returns ``(selected, routed, greedy)`` for those ``len(toks) - 1
+    - ctx_len`` positions; ``greedy`` are the program's tokens after each
+    position from the turn's last on."""
+    import jax
+    import jax.numpy as jnp
+
+    from distriflow_tpu.models.generate import (
+        _as_dict, _build_paged_fns, _build_prefill, decode_family,
+        paged_cache, pages_per_slot)
+
+    module = decode_family(cfg).decode_module(cfg)
+    prefill, extend = _build_prefill(cfg)
+    pc = serving.prefill_chunk or ctx_len
+    _, row = prefill(params, toks[None, :min(pc, ctx_len)])
+    for i in range(pc, ctx_len, pc):
+        _, row = extend(params, row, toks[None, i:min(i + pc, ctx_len)])
+
+    def apply(p, cache, t):
+        logits, state = module.apply({**p, "cache": cache}, t,
+                                     mutable=["cache", "intermediates"])
+        return (_as_dict(state["cache"]), _picked(cfg, state["intermediates"]),
+                jnp.argmax(logits[0], axis=-1))
+
+    def decode(p, cache, fed):
+        def step(cache, tok):
+            cache, picked, nxt = apply(p, cache, tok[None, None])
+            return cache, (picked, nxt[0])
+        return jax.lax.scan(step, cache, fed)[1]
+
+    ps = serving.page_size
+    n_pages = -(-len(toks) // ps)
+    insert, _ = _build_paged_fns(cfg, ps)
+    with _not_kept():
+        row, turn, nxt = jax.jit(apply)(params, row,
+                                        toks[None, ctx_len:prompt_len])
+        pool = paged_cache(cfg, params, 1, ps, n_pages)
+        table = np.full((1, pages_per_slot(cfg.max_seq, ps) + 1), n_pages,
+                        np.int32)
+        table[0, :n_pages] = np.arange(n_pages)[::-1]
+        pool = insert(pool, row, np.zeros((1,), np.int32),
+                      np.int32(prompt_len), np.int32(0), table)
+        fed = toks[prompt_len:-1]
+        steps, after = jax.jit(decode)(params, pool, fed)
+    by_turn = _as_sets(cfg, turn, prompt_len - ctx_len)
+    by_step = _as_sets(cfg, steps, len(fed))
+    selected, routed = ({i: np.concatenate([a[i], b[i]]) for i in a}
+                        for a, b in zip(by_turn, by_step))
+    return selected, routed, np.concatenate(
+        [np.asarray(nxt)[-1:], np.asarray(after)])
 
 
 def _check_score(run: Run, cfg: Any, client: Any, params: Any,
@@ -173,26 +360,55 @@ def _check_score(run: Run, cfg: Any, client: Any, params: Any,
     want = float(np.take_along_axis(
         np.asarray(logp), tokens[1:, None].astype(np.int64), axis=-1).sum())
     per_token = abs(got - want) / (n - 1)
-    selected, routed = _system_sets(cfg, params, tokens)
+    selected, routed, own = _system_sets(cfg, params, tokens)
     past = np.arange(min(cfg.index_topk, n - 1), n)
     overlap = _held(selected, masks, past)
-    routing = _held(routed, routes, np.arange(n))
+    routing = _held(routed, routes)
     say(f"  reference: score() {got:.3f} vs {want:.3f} nats over {n - 1} "
         f"tokens, {per_token:.2e} per token (tol {SCORE_NATS_PER_TOKEN}); "
         f"selected sets at the {len(past)} positions past index_topk hold "
         f"{overlap:.4f} of the reference's (at least {SELECTION_OVERLAP_MIN}); "
         f"the routing to the held experts holds {routing:.4f} of the "
         f"reference's (at least {ROUTING_OVERLAP_MIN})")
+    say(f"  reference on the program's own inputs: the norms' outputs are the "
+        f"float32 result in {own['norm_match']:.5f} of their values (at least "
+        f"{NORM_MATCH_MIN}), the router's affinities differ by at most "
+        f"{own['affinity_err']:.2e} (tol {AFFINITY_ERR_MAX}), its choice of "
+        f"held experts agrees in {own['own_routing']:.5f} (at least "
+        f"{OWN_ROUTING_MIN})")
     return (per_token <= SCORE_NATS_PER_TOKEN
             and overlap >= SELECTION_OVERLAP_MIN
-            and routing >= ROUTING_OVERLAP_MIN)
+            and routing >= ROUTING_OVERLAP_MIN and _own_within(own))
 
 
-def _check_replies(run: Run, params: Any, records: List[Dict[str, Any]],
-                   callers: _Callers, reqs: List[Any]) -> bool:
+def _check_served_sets(cfg: Any, serving: Any, params: Any, toks: np.ndarray,
+                       ctx_len: int, prompt_len: int,
+                       masks: Dict[int, np.ndarray],
+                       routes: Dict[int, np.ndarray]) -> bool:
+    """One reply's sets on the served path (:func:`_served_sets`) against
+    the reference's on the same rows, ``ctx_len`` to the last token fed."""
+    selected, routed, greedy = _served_sets(cfg, serving, params, toks,
+                                            ctx_len, prompt_len)
+    width = next(iter(masks.values())).shape[1]
+    overlap = _held({i: m[:, :width] for i, m in selected.items()}, masks)
+    routing = _held(routed, routes)
+    same = float(np.mean(greedy == toks[prompt_len:]))
+    say(f"  served path, {prompt_len - ctx_len} turn + {len(greedy) - 1} "
+        f"decoded positions behind {ctx_len}: selected sets hold "
+        f"{overlap:.4f} of the reference's (at least {SELECTION_OVERLAP_MIN}), "
+        f"the routing {routing:.4f} (at least {SERVED_ROUTING_MIN}); "
+        f"{same:.3f} of the reply's tokens are this replay's greedy ones")
+    return overlap >= SELECTION_OVERLAP_MIN and routing >= SERVED_ROUTING_MIN
+
+
+def _check_replies(run: Run, cfg: Any, serving: Any, params: Any,
+                   records: List[Dict[str, Any]], callers: _Callers,
+                   reqs: List[Any]) -> bool:
     """Every reply echoes its prompt at the asked length; per context
     length a seeded sample is re-scored by the reference, token by token,
-    all padded to the longest reply's length: one set of programs."""
+    all padded to the longest reply's length: one set of programs. The
+    first of the shortest is also replayed on the served path, and its sets
+    compared with the reference's."""
     import jax.numpy as jnp
 
     t = run.traffic
@@ -211,6 +427,7 @@ def _check_replies(run: Run, params: Any, records: List[Dict[str, Any]],
     length = max(r.prompt_len for r in reqs) + most_out
     model = reference_model(run.config)
     worst, hits, misses, total, checked = 0.0, 0, 0, 0, 0
+    turn = int(t["turn_tokens"])
     for plen in sorted({r["prompt_len"] for r in done}):
         mine = [r for r in done if r["prompt_len"] == plen]
         n_check = min(int(t["check_replies_per_length"]), len(mine))
@@ -221,9 +438,17 @@ def _check_replies(run: Run, params: Any, records: List[Dict[str, Any]],
             positions = np.arange(plen - 1, len(toks) - 1)
             asked = np.full((most_out,), positions[-1])
             asked[:len(positions)] = positions
-            logp = np.asarray(reference.log_probs(
-                params, jnp.asarray(padded), jnp.asarray(asked),
-                model))[:len(positions)]
+            if checked:
+                logp = reference.log_probs(params, jnp.asarray(padded),
+                                           jnp.asarray(asked), model)
+            else:
+                logp, masks, routes = reference.log_probs(
+                    params, jnp.asarray(padded), jnp.asarray(asked), model,
+                    return_sets=True,
+                    set_rows=np.arange(plen - turn, len(toks) - 1))
+                ok = _check_served_sets(cfg, serving, params, toks, plen - turn,
+                                        plen, masks, routes) and ok
+            logp = np.asarray(logp)[:len(positions)]
             gap = logp.max(-1) - logp[np.arange(len(positions)),
                                       toks[positions + 1]]
             say(f"  reply {mine[int(i)]['index']} ({plen} + "
@@ -452,8 +677,8 @@ def run(run: Run) -> None:
                   "counters": {k: v - counters0.get(k, 0)
                                for k, v in counters1.items()}}
     with run.phase("replies against the reference (after the window)"):
-        replies_ok = _check_replies(run, session.params, measured, callers,
-                                    reqs)
+        replies_ok = _check_replies(run, session.cfg, session.serving,
+                                    session.params, measured, callers, reqs)
     turn = int(t["turn_tokens"])
     by_index = {r.index: r for r in reqs}
     # every page of the session's context was a prefix hit, and no more
@@ -467,3 +692,36 @@ def run(run: Run) -> None:
     run.correct = bool(session.correct and replies_ok and not engine_errors
                        and run.failed == 0 and engine_path and hits_ok
                        and evicted == 0)
+
+
+def precision_control(run: Run) -> Dict[str, bool]:
+    """``_check_score`` twice on one set of weights: the program as the
+    configuration states it, and with its norms and router computed in
+    bfloat16, the nearest precision below (``rehearsal/
+    precision_control.py``). Limits that can tell the two apart give
+    ``{"stated": True, "lowered": False}``."""
+    import jax
+    import jax.numpy as jnp
+
+    from distriflow_tpu import InferenceClient, InferenceServer, ServingConfig
+    from distriflow_tpu.models.latent_sparse import init_params
+
+    stated = program_config(run.config)
+    params = init_params(stated, harness.prng_key(run.seed))
+    jax.block_until_ready(params)
+    corpus = corpus_lib.generate_corpus(run.traffic["corpus_tokens"], seed=0)
+    out = {}
+    for name, cfg in (("stated", stated), ("lowered", dataclasses.replace(
+            stated, norm_router_dtype=jnp.bfloat16))):
+        say(f"{name}: norms and router in "
+            f"{jnp.dtype(cfg.norm_router_dtype).name}")
+        server = InferenceServer(cfg, params, port=0,
+                                 serving=ServingConfig(**run.config["serving"]))
+        server.setup()
+        try:
+            with InferenceClient(server.address, timeout=1100.0) as client:
+                out[name] = _check_score(run, cfg, client, params, corpus)
+        finally:
+            server.stop()
+        say(f"{name}: correct {out[name]}")
+    return out

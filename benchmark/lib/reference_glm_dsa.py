@@ -71,7 +71,7 @@ def _f32(v: jax.Array) -> jax.Array:
     return v.astype(jnp.float32)
 
 
-def _rms_norm(x: jax.Array, scale: jax.Array) -> jax.Array:
+def rms_norm(x: jax.Array, scale: jax.Array) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + RMS_EPS) * _f32(scale)
 
 
@@ -146,8 +146,8 @@ def selection_mask(p: Dict[str, Any], a: jax.Array, c_q: jax.Array,
 
 def _query_latent(p: Dict[str, Any], x: jax.Array):
     """``(a, c_q)``: the normed input and the query's low-rank latent."""
-    a = _rms_norm(x, p["input_norm"]["scale"])
-    return a, _rms_norm(a @ _f32(p["attn"]["q_a_proj"]["kernel"]),
+    a = rms_norm(x, p["input_norm"]["scale"])
+    return a, rms_norm(a @ _f32(p["attn"]["q_a_proj"]["kernel"]),
                         p["attn"]["q_a_norm"]["scale"])
 
 
@@ -166,7 +166,7 @@ def _attention(p: Dict[str, Any], x: jax.Array, mask: jax.Array,
         a, c_q = _query_latent(p, x)
         kv = a @ _f32(pa["kv_a_proj"]["kernel"])
         rank = pa["k_b_proj"].shape[0]
-        c_kv = _rms_norm(kv[:, :rank], pa["kv_a_norm"]["scale"])
+        c_kv = rms_norm(kv[:, :rank], pa["kv_a_norm"]["scale"])
         k_rope = _rotary(kv[:, rank:], theta)  # [S, 64], one head for all
         rope = k_rope.shape[-1]
         s = x.shape[0]
@@ -192,10 +192,16 @@ def _attention(p: Dict[str, Any], x: jax.Array, mask: jax.Array,
         return x + out
 
 
+def affinity(p: Dict[str, Any], m: jax.Array) -> jax.Array:
+    """``s = sigmoid(W_r m)``, ``[S, E]`` float32."""
+    with jax.default_matmul_precision("highest"):
+        return jax.nn.sigmoid(_f32(m) @ _f32(p["router"]))
+
+
 def routing(p: Dict[str, Any], m: jax.Array, k: int, scaling: float):
     """``(chosen [S, k], gates [S, k])`` of a sparse layer's router."""
     with jax.default_matmul_precision("highest"):
-        s = jax.nn.sigmoid(m @ _f32(p["router"]))
+        s = affinity(p, m)
         _, chosen = jax.lax.top_k(s + _f32(p["e_score_correction_bias"]), k)
         picked = jnp.take_along_axis(s, chosen, -1)
         return chosen, scaling * picked / jnp.sum(picked, -1, keepdims=True)
@@ -209,7 +215,7 @@ def _ffn(p: Dict[str, Any], x: jax.Array, k: int, scaling: float,
     (with ``cap`` the sequence length it cannot be); ``routed [S, count]``
     says which tokens chose which held expert."""
     with jax.default_matmul_precision("highest"):
-        m = _rms_norm(x, p["post_attn_norm"]["scale"])
+        m = rms_norm(x, p["post_attn_norm"]["scale"])
         mlp = p["mlp"]
         if "router" not in mlp:
             return (x + _swiglu(mlp, m), jnp.bool_(True),
@@ -239,19 +245,21 @@ def _embed(table, tokens):
 @jax.jit
 def _head_logprobs(norm, head, x, positions):
     with jax.default_matmul_precision("highest"):
-        h = _rms_norm(x[positions], norm)
+        h = rms_norm(x[positions], norm)
         return jax.nn.log_softmax(h @ _f32(head), axis=-1)
 
 
 def log_probs(params: Any, tokens: jax.Array, positions: jax.Array,
-              model: Dict[str, Any], return_sets: bool = False):
+              model: Dict[str, Any], return_sets: bool = False,
+              set_rows: Any = None):
     """Next-token log-probabilities after ``positions`` of one sequence
     ``tokens [S]``: ``[len(positions), V]`` float32. ``model`` holds
     ``indexer_types``, ``index_topk``, ``index_rope_dim``, ``rope_theta``,
     ``num_experts_per_tok``, ``routed_scaling_factor`` and ``experts_held``
     (first, count). With ``return_sets`` also, by layer number, the ``[S,
     S]`` selection of every ``full`` layer and the ``[S, count]`` routing to
-    the held experts of every sparse layer."""
+    the held experts of every sparse layer, or of both only the rows
+    (tokens) ``set_rows``."""
     p = params["params"]
     theta = float(model["rope_theta"])
     first, count = model["experts_held"]
@@ -266,7 +274,8 @@ def log_probs(params: Any, tokens: jax.Array, positions: jax.Array,
                                   int(model["index_topk"]),
                                   int(model["index_rope_dim"]))
             if return_sets:
-                masks[i] = np.asarray(mask)
+                masks[i] = np.asarray(mask if set_rows is None
+                                      else mask[set_rows])
         x = _attention(layer, x, mask, theta)
         # an expert is chosen by n * k / E tokens on average; eight times
         # that is room, and the whole sequence is the fall-back
@@ -278,7 +287,8 @@ def log_probs(params: Any, tokens: jax.Array, positions: jax.Array,
                 break
         x = out
         if return_sets and routed.shape[1]:
-            routes[i] = np.asarray(routed)
+            routes[i] = np.asarray(routed if set_rows is None
+                                   else routed[set_rows])
     out = _head_logprobs(p["norm"]["scale"], p["lm_head"]["kernel"], x,
                          positions)
     return (out, masks, routes) if return_sets else out

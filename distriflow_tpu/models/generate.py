@@ -85,13 +85,19 @@ class DecodeFamily(NamedTuple):
     (``apply`` with a mutable ``cache`` collection: prefill from an empty
     cache, continuation against one, ``cache_index`` scalar or per row,
     paged when a ``page_table`` leaf is present), and the teacher-forced
-    forward ``score`` uses. A configuration class names its family in a
-    ``decode_family`` attribute; one without it is ``TransformerConfig``'s.
+    forward ``score`` uses. ``work_leaf``, if a family has one, names a
+    cache leaf in which a layer adds up, over its calls, small integer
+    counts of the work it chose to do (which counts is the family's to
+    say, in its configuration's ``decode_work``); with telemetry on the
+    engine fetches those leaves with a decode dispatch's tokens. A
+    configuration class names its family in a ``decode_family`` attribute;
+    one without it is ``TransformerConfig``'s.
     """
 
     pool_leaves: Tuple[str, ...]
     decode_module: Callable[[Any], Any]
     score_logits: Callable[[Any], Callable[[Any, jnp.ndarray], jnp.ndarray]]
+    work_leaf: Optional[str] = None
 
 
 def _transformer_decode_module(config: TransformerConfig) -> TransformerLM:

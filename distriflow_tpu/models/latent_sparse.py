@@ -20,7 +20,8 @@ DeepSeek-V3.2 lineage):
   ``index_rope_dim`` values, ``w = W_Iw x`` scaled by ``heads^-1/2
   dim^-1/2``; ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])`` for ``s
   <= t``; a token attends over the ``index_topk`` cached tokens of largest
-  ``I`` (exact ``lax.top_k``), or over all of them while fewer exist. A
+  ``I`` (exactly ``lax.top_k``'s set, found without its sort:
+  :func:`top_positions`), or over all of them while fewer exist. A
   ``"shared"`` layer attends over the set the nearest earlier ``"full"``
   layer chose for the same token, and has no selector weights or cache.
 - **FFN.** ``"dense"`` layers: SwiGLU. ``"sparse"`` layers: ``s =
@@ -85,6 +86,11 @@ class LatentSparseConfig:
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
+    #: what the RMSNorms and the router's affinities are computed in,
+    #: whatever ``dtype`` is. Lowered only by a control that shows what a
+    #: comparison with a reference can tell (benchmark/drivers/
+    #: serve_sessions.py::precision_control).
+    norm_router_dtype: Any = jnp.float32
     query_block: int = 128
 
     def __post_init__(self):
@@ -187,7 +193,9 @@ def top_positions(score: jnp.ndarray, visible: jnp.ndarray, k: int):
     visible positions of a row that has fewer than ``k``. Exact: the k-th
     largest score is found bit by bit, the chosen positions are compacted
     within chunks of ``CHUNK`` by a small sort and the chunks joined by
-    one-hot sums."""
+    one-hot sums (tests/test_latent_sparse.py holds it to ``top_k``'s set
+    at 33,792 positions and k 2,048). One departure: ``-0.0`` ranks below
+    ``0.0``, which ``top_k`` holds equal."""
     width = score.shape[-1]
     lead = score.shape[:-1]
     bits = jax.lax.bitcast_convert_type(score, jnp.uint32)
@@ -240,14 +248,23 @@ def select_tokens(q_idx, w, keys, q_pos, topk: int):
 
 class RMSNorm(nn.Module):
     eps: float
+    dtype: Any = jnp.float32  # computed in; the result is cast to x's
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
-        xf = x.astype(jnp.float32)
+        xf = x.astype(self.dtype)
         var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        return (xf * jax.lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
+        out = (xf * jax.lax.rsqrt(var + jnp.asarray(self.eps, self.dtype))
+               * scale.astype(self.dtype)).astype(x.dtype)
+        # read only by a caller that asks for "intermediates"
+        self.sow("intermediates", "io", (x, out))
+        return out
+
+
+def _norm(cfg: LatentSparseConfig, name: str) -> RMSNorm:
+    return RMSNorm(cfg.rms_eps, cfg.norm_router_dtype, name=name)
 
 
 def _dense(cfg: LatentSparseConfig, features, name: str, **kw):
@@ -295,12 +312,11 @@ class LatentSparseAttention(nn.Module):
         pos0 = idx if idx.ndim == 1 else jnp.broadcast_to(idx, (b,))
         q_pos = pos0[:, None] + jnp.arange(s)[None, :]  # [B, s]
 
-        c_q = RMSNorm(cfg.rms_eps, name="q_a_norm")(
+        c_q = _norm(cfg, "q_a_norm")(
             _dense(cfg, cfg.q_lora_rank, "q_a_proj")(x))
         q = _dense(cfg, (cfg.n_heads, nope + rope), "q_b_proj")(c_q)
         kv = _dense(cfg, lat, "kv_a_proj")(x)
-        c_kv = RMSNorm(cfg.rms_eps, name="kv_a_norm")(
-            kv[..., :cfg.kv_lora_rank])
+        c_kv = _norm(cfg, "kv_a_norm")(kv[..., :cfg.kv_lora_rank])
         k_rope = rope_interleaved(kv[..., cfg.kv_lora_rank:], q_pos,
                                   cfg.rope_base)
         q_rope = rope_interleaved(q[..., nope:], q_pos, cfg.rope_base)
@@ -433,11 +449,13 @@ class SwiGLU(nn.Module):
         return _dense(cfg, cfg.d_model, "down_proj")(jax.nn.silu(gate) * up)
 
 
-def router_affinity(x: jnp.ndarray, router: jnp.ndarray) -> jnp.ndarray:
+def router_affinity(x: jnp.ndarray, router: jnp.ndarray,
+                    dtype: Any = jnp.float32) -> jnp.ndarray:
     """``sigmoid(x W_r)`` in float32 whatever the compute dtype: a choice
     among 256 near-equal scores does not survive bfloat16."""
-    return jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router,
-                                  precision=jax.lax.Precision.HIGHEST))
+    return jax.nn.sigmoid(jnp.dot(
+        x.astype(dtype), router.astype(dtype),
+        precision=jax.lax.Precision.HIGHEST)).astype(jnp.float32)
 
 
 def route(scores: jnp.ndarray, bias: jnp.ndarray, k: int, scaling: float):
@@ -476,14 +494,15 @@ class ExpertShare(nn.Module):
              self.param(f"expert_{e}_up", init, (d, f), cfg.param_dtype),
              self.param(f"expert_{e}_down", init, (f, d), cfg.param_dtype))
             for e in range(count)]
-        gates = route(router_affinity(flat, router), bias,
-                      cfg.n_experts_per_tok,
+        affinity = router_affinity(flat, router, cfg.norm_router_dtype)
+        gates = route(affinity, bias, cfg.n_experts_per_tok,
                       cfg.routed_scaling_factor)[:, first:first + count]
         if live is not None:  # a retired row routes nowhere
             gates = gates * jnp.repeat(live, s)[:, None]
         hit = jnp.any(gates > 0, axis=0)  # [count]
         # read only by a caller that asks for "intermediates"
         self.sow("intermediates", "routed", gates > 0)
+        self.sow("intermediates", "router_io", (flat, affinity))
         # what the share did, for the engine's counters: experts run and
         # (token, expert) assignments that landed here, summed over calls
         stats = self.variable("cache", "expert_stats",
@@ -516,9 +535,9 @@ class LatentSparseBlock(nn.Module):
         cfg = self.config
         attn, selection, live = LatentSparseAttention(
             cfg, self.layer, name="attn")(
-                RMSNorm(cfg.rms_eps, name="input_norm")(x), selection)
+                _norm(cfg, "input_norm")(x), selection)
         x = x + attn
-        h = RMSNorm(cfg.rms_eps, name="post_attn_norm")(x)
+        h = _norm(cfg, "post_attn_norm")(x)
         if cfg.mlp_layer_types[self.layer] == "dense":
             y = SwiGLU(cfg, cfg.d_ff, name="mlp")(h)
         else:
@@ -541,7 +560,7 @@ class LatentSparseLM(nn.Module):
         for i in range(cfg.n_layers):
             x, selection = LatentSparseBlock(cfg, i, name=f"layers_{i}")(
                 x, selection)
-        x = RMSNorm(cfg.rms_eps, name="norm")(x)
+        x = _norm(cfg, "norm")(x)
         return _dense(cfg, cfg.vocab_size, "lm_head")(x).astype(jnp.float32)
 
 
@@ -552,7 +571,7 @@ def _score_logits(config: LatentSparseConfig):
 
 
 _FAMILY = DecodeFamily(("cached_latent", "cached_index_k"), LatentSparseLM,
-                       _score_logits)
+                       _score_logits, work_leaf="expert_stats")
 
 
 def init_params(config: LatentSparseConfig, rng: jax.Array) -> Any:

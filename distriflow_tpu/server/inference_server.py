@@ -478,7 +478,8 @@ class InferenceServer:
         # of its experts says how much (``config.decode_work``); the engine
         # counts it per dispatch. TransformerConfig declares none.
         self._decode_work = getattr(config, "decode_work", None)
-        self._expert_stats_seen = np.zeros((2,), np.int64)
+        self._work_leaf = decode_family(config).work_leaf
+        self._work_seen = np.zeros((2,), np.int64)
         if self._decode_work is not None:
             self._m_ctx_tokens = tel.counter(
                 "serving_context_tokens_total",
@@ -1122,20 +1123,21 @@ class InferenceServer:
                 "were not usable); serving_cache_copies_total counts every "
                 "such dispatch", RuntimeWarning, stacklevel=3)
 
-    def _expert_stats(self) -> np.ndarray:
-        """``(experts run, assignments held here)`` so far, summed over the
-        sparse layers: the cache's ``expert_stats`` leaves."""
+    def _work_leaves(self) -> List[Any]:
+        """The slot cache's ``work_leaf`` leaves (``DecodeFamily``), one per
+        layer that counts its work: ``(experts run, assignments held
+        here)`` so far."""
         found: List[Any] = []
 
         def walk(node):
             for name, sub in node.items():
-                if name == "expert_stats":
+                if name == self._work_leaf:
                     found.append(sub)
                 elif hasattr(sub, "items"):
                     walk(sub)
 
         walk(self._slot_cache)
-        return np.sum(jax.device_get(found), axis=0, dtype=np.int64)
+        return found
 
     def _drop_dead_cache(self, err: Exception) -> bool:
         """After a failed device call: a donating program that fails once
@@ -1196,7 +1198,7 @@ class InferenceServer:
             return
         with self._prof.phase("admission"):
             if self._slot_cache is None:
-                self._expert_stats_seen[:] = 0  # a fresh cache counts from 0
+                self._work_seen[:] = 0  # a fresh cache counts from 0
                 with self._device_lock:
                     if self._paged:
                         self._slot_cache = paged_cache(
@@ -1504,18 +1506,22 @@ class InferenceServer:
                         self._seeds, self._eos)
                 td1 = time_mod.monotonic()
                 with self._prof.phase("token_fetch"):
+                    counts = (self._work_leaves()
+                              if stats and self._decode_work is not None
+                              else [])
+                    if counts:  # the layers' counts ride with the tokens
+                        tok, done, toks, *counts = jax.device_get(
+                            [tok, done, toks] + counts)
                     # np.array, not np.asarray: device outputs arrive as
                     # read-only views, and the slot state is mutated in
                     # place below
                     tok = np.array(tok)
                     done = np.array(done)
                     toks = np.array(toks)
-                    if stats and self._decode_work is not None:
-                        # a few int32 of a program that has finished: the
-                        # fetch waits for nothing
-                        seen = self._expert_stats()
-                        run_now, local = seen - self._expert_stats_seen
-                        self._expert_stats_seen = seen
+                    if counts:
+                        seen = np.sum(counts, axis=0, dtype=np.int64)
+                        run_now, local = seen - self._work_seen
+                        self._work_seen = seen
                         stats.update(experts_hit=int(run_now),
                                      local_assignments=int(local))
             t1 = time_mod.monotonic()

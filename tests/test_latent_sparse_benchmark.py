@@ -3,9 +3,19 @@ traffic generator and the arithmetic of ``benchmark/lib/flops_glm_dsa.py``
 (ISSUE 33's counts), at no device's cost.
 """
 
+import dataclasses
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from benchmark.drivers import serve_sessions
 from benchmark.lib import flops_glm_dsa, harness, loadgen_sessions
+from distriflow_tpu.models.latent_sparse import init_params
 
 REGISTRY = harness.Registry()
 TRAFFIC = REGISTRY.traffic("agent-sessions-open")
@@ -75,3 +85,80 @@ def test_cache_and_step_bytes_are_the_issues():
     expert = 3 * 6144 * 2048 * 2
     assert flops_glm_dsa.experts_bytes(3, 1, CONFIG) == 3 * expert + 4 * (
         expert + 6144 * 256 * 4)
+
+
+def test_the_cells_check_fails_the_precision_below_the_stated_one():
+    """The driver's own comparison (``_check_score``, through a server) on
+    one set of weights: ``correct`` as stated, not ``correct`` with the
+    norms and the router in bfloat16."""
+    proc = subprocess.run(
+        [sys.executable, "benchmark/rehearsal/precision_control.py",
+         "serve-glm52-sessions-long", "3000000019"],
+        cwd=harness.ROOT, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    assert "stated correct True, lowered correct False" in proc.stdout
+
+
+@pytest.mark.parametrize("seed", [1, 2])
+def test_own_input_readings_separate_under_bfloat16_matmuls(seed):
+    """Where everything else computes in bfloat16, as at the published
+    size, what tells float32 norms and router from bfloat16 ones are the
+    readings on the program's own inputs: exact as stated, far outside the
+    limits when lowered."""
+    toy = harness.toy(CONFIG)
+    cfg = serve_sessions.program_config({
+        **toy, "compute_dtype": "bfloat16", "param_dtype": "bfloat16",
+        "hidden_size": 256, "n_routed_experts": 64, "experts_held": [0, 8],
+        "num_experts_per_tok": 8})
+    params = init_params(cfg, jax.random.PRNGKey(seed))
+    tokens = np.random.default_rng(seed).integers(
+        0, toy["vocab_size"], 512).astype(np.int32)
+    own = serve_sessions._system_sets(cfg, params, tokens)[2]
+    assert serve_sessions._own_within(own), own
+    assert own["norm_match"] > 0.9999 and own["affinity_err"] < 1e-6
+    low = serve_sessions._system_sets(dataclasses.replace(
+        cfg, norm_router_dtype=jnp.bfloat16), params, tokens)[2]
+    assert not serve_sessions._own_within(low), low
+    assert low["norm_match"] < 0.9 and low["affinity_err"] > 1e-3
+
+
+@pytest.mark.parametrize("fault,what", [
+    ({"rope_base": 2500.0}, "rotary angles"),
+    ({"index_rope_dim": 4}, "the selector rotates other values"),
+    ({"routed_scaling_factor": 2.0}, "the gates' scale"),
+    ({"rms_eps": 1e-2}, "the norms' epsilon"),
+    ({"index_topk": 12}, "a smaller selection")])
+def test_whole_model_limits_catch_a_planted_fault(fault, what):
+    """The limits on ``score()`` and on the chosen sets have no reading from
+    a lower precision (bfloat16 matmuls hide it); what they guard is a
+    program that computes something else. Each fault planted in the
+    program, against the reference of the true model, breaks one of them;
+    the true program breaks none."""
+    from distriflow_tpu.models.generate import sequence_logprob
+
+    toy = harness.toy(CONFIG)
+    true = serve_sessions.program_config(toy)
+    model = serve_sessions.reference_model(toy)
+    params = init_params(true, jax.random.PRNGKey(3))
+    tokens = np.random.default_rng(3).integers(
+        0, toy["vocab_size"], 192).astype(np.int32)
+    n = len(tokens)
+    logp, masks, routes = serve_sessions.reference.log_probs(
+        params, jnp.asarray(tokens), jnp.arange(n - 1), model,
+        return_sets=True)
+    want = float(np.take_along_axis(
+        np.asarray(logp), tokens[1:, None].astype(np.int64), axis=-1).sum())
+    past = np.arange(true.index_topk, n)
+
+    def within(cfg):
+        got = float(sequence_logprob(cfg, params, tokens[None], from_pos=1)[0])
+        selected, routed, _ = serve_sessions._system_sets(cfg, params, tokens)
+        return (abs(got - want) / (n - 1) <= serve_sessions.SCORE_NATS_PER_TOKEN
+                and serve_sessions._held(selected, masks, past)
+                >= serve_sessions.SELECTION_OVERLAP_MIN
+                and serve_sessions._held(routed, routes)
+                >= serve_sessions.ROUTING_OVERLAP_MIN)
+
+    assert within(true)
+    assert not within(dataclasses.replace(true, **fault)), what
