@@ -34,7 +34,6 @@ reply's turn and generated tokens chose on the path the window timed
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import gc
 import time
@@ -106,9 +105,9 @@ OWN_ROUTING_MIN = 0.998
 # generated tokens through decode steps from pages) against the
 # reference's: the limits above, but the routing of some 250 tokens is
 # some 500 (token, held expert) pairs, and a share of them scatters by 0.7
-# points; read 0.9773-0.9813 (selection) and 0.9589-0.9906 (routing) on
-# 160-330 positions behind 8,192, five seeds. Rows read from a wrong page
-# scramble both.
+# points; read 0.9751-0.9831 (selection) and 0.9446-0.9906 (routing) on
+# 150-330 positions behind 8,192, thirteen seeds. Rows read from a wrong
+# page scramble both.
 SERVED_ROUTING_MIN = 0.92
 
 
@@ -270,25 +269,8 @@ def _held(got: Dict[int, np.ndarray], want: Dict[int, np.ndarray],
                  / sum(want[i][rows].sum() for i in want))
 
 
-@contextlib.contextmanager
-def _not_kept():
-    """Programs compiled inside stay out of the persistent compile cache:
-    they serve the check of one reply after the window, and the cache that
-    comes with a machine is small enough for them to push out programs
-    that set-up loads (PERF.md §6 PR 33), which `setup_s` would then pay."""
-    import jax
-
-    key = "jax_persistent_cache_min_compile_time_secs"
-    was = getattr(jax.config, key)
-    jax.config.update(key, 1e9)
-    try:
-        yield
-    finally:
-        jax.config.update(key, was)
-
-
 def _served_sets(cfg: Any, serving: Any, params: Any, toks: np.ndarray,
-                 ctx_len: int, prompt_len: int):
+                 ctx_len: int, prompt_len: int, most_out: int):
     """What the program chose for one reply ``toks`` (context, turn,
     generated tokens) on the path the window timed, from position
     ``ctx_len`` on: the context prefilled in the engine's chunks by the
@@ -296,9 +278,11 @@ def _served_sets(cfg: Any, serving: Any, params: Any, toks: np.ndarray,
     cache, the row scattered into a page pool by the engine's ``insert``
     (physical pages in reverse order, so that the table is read), and every
     generated token but the last fed through a decode step against the
-    pool. Returns ``(selected, routed, greedy)`` for those ``len(toks) - 1
-    - ctx_len`` positions; ``greedy`` are the program's tokens after each
-    position from the turn's last on."""
+    pool. Pool and steps are sized for the longest reply, ``most_out``
+    tokens, whatever this one's length: one set of programs for every
+    seed, which the compile cache then holds. Returns ``(selected, routed,
+    greedy)`` for those ``len(toks) - 1 - ctx_len`` positions; ``greedy``
+    are the program's tokens after each position from the turn's last on."""
     import jax
     import jax.numpy as jnp
 
@@ -313,38 +297,40 @@ def _served_sets(cfg: Any, serving: Any, params: Any, toks: np.ndarray,
     for i in range(pc, ctx_len, pc):
         _, row = extend(params, row, toks[None, i:min(i + pc, ctx_len)])
 
-    def apply(p, cache, t):
+    def served_turn(p, cache, t):
         logits, state = module.apply({**p, "cache": cache}, t,
                                      mutable=["cache", "intermediates"])
         return (_as_dict(state["cache"]), _picked(cfg, state["intermediates"]),
                 jnp.argmax(logits[0], axis=-1))
 
-    def decode(p, cache, fed):
+    def served_steps(p, cache, fed):
         def step(cache, tok):
-            cache, picked, nxt = apply(p, cache, tok[None, None])
+            cache, picked, nxt = served_turn(p, cache, tok[None, None])
             return cache, (picked, nxt[0])
         return jax.lax.scan(step, cache, fed)[1]
 
     ps = serving.page_size
-    n_pages = -(-len(toks) // ps)
+    n_pages = -(-(prompt_len + most_out) // ps)
     insert, _ = _build_paged_fns(cfg, ps)
-    with _not_kept():
-        row, turn, nxt = jax.jit(apply)(params, row,
-                                        toks[None, ctx_len:prompt_len])
-        pool = paged_cache(cfg, params, 1, ps, n_pages)
-        table = np.full((1, pages_per_slot(cfg.max_seq, ps) + 1), n_pages,
-                        np.int32)
-        table[0, :n_pages] = np.arange(n_pages)[::-1]
-        pool = insert(pool, row, np.zeros((1,), np.int32),
-                      np.int32(prompt_len), np.int32(0), table)
-        fed = toks[prompt_len:-1]
-        steps, after = jax.jit(decode)(params, pool, fed)
+    row, turn, nxt = jax.jit(served_turn)(params, row,
+                                          toks[None, ctx_len:prompt_len])
+    pool = paged_cache(cfg, params, 1, ps, n_pages)
+    table = np.full((1, pages_per_slot(cfg.max_seq, ps) + 1), n_pages,
+                    np.int32)
+    table[0, :n_pages] = np.arange(n_pages)[::-1]
+    pool = insert(pool, row, np.zeros((1,), np.int32),
+                  np.int32(prompt_len), np.int32(0), table)
+    n_fed = len(toks) - 1 - prompt_len
+    fed = np.zeros((most_out - 1,), toks.dtype)
+    fed[:n_fed] = toks[prompt_len:-1]  # the steps past n_fed are not read
+    steps, after = jax.tree.map(
+        lambda v: np.asarray(v)[:n_fed],
+        jax.jit(served_steps)(params, pool, fed))
     by_turn = _as_sets(cfg, turn, prompt_len - ctx_len)
-    by_step = _as_sets(cfg, steps, len(fed))
+    by_step = _as_sets(cfg, steps, n_fed)
     selected, routed = ({i: np.concatenate([a[i], b[i]]) for i in a}
                         for a, b in zip(by_turn, by_step))
-    return selected, routed, np.concatenate(
-        [np.asarray(nxt)[-1:], np.asarray(after)])
+    return selected, routed, np.concatenate([np.asarray(nxt)[-1:], after])
 
 
 def _check_score(run: Run, cfg: Any, client: Any, params: Any,
@@ -382,13 +368,13 @@ def _check_score(run: Run, cfg: Any, client: Any, params: Any,
 
 
 def _check_served_sets(cfg: Any, serving: Any, params: Any, toks: np.ndarray,
-                       ctx_len: int, prompt_len: int,
+                       ctx_len: int, prompt_len: int, most_out: int,
                        masks: Dict[int, np.ndarray],
                        routes: Dict[int, np.ndarray]) -> bool:
     """One reply's sets on the served path (:func:`_served_sets`) against
     the reference's on the same rows, ``ctx_len`` to the last token fed."""
     selected, routed, greedy = _served_sets(cfg, serving, params, toks,
-                                            ctx_len, prompt_len)
+                                            ctx_len, prompt_len, most_out)
     width = next(iter(masks.values())).shape[1]
     overlap = _held({i: m[:, :width] for i, m in selected.items()}, masks)
     routing = _held(routed, routes)
@@ -405,10 +391,14 @@ def _check_replies(run: Run, cfg: Any, serving: Any, params: Any,
                    records: List[Dict[str, Any]], callers: _Callers,
                    reqs: List[Any]) -> bool:
     """Every reply echoes its prompt at the asked length; per context
-    length a seeded sample is re-scored by the reference, token by token,
-    all padded to the longest reply's length: one set of programs. The
-    first of the shortest is also replayed on the served path, and its sets
-    compared with the reference's."""
+    length a seeded sample is re-scored by the reference, token by token.
+    A reply of the longest context is padded to the longest reply that
+    context can have, every other to the longest of the next context down:
+    two sets of programs whatever the seed, and a short context does not
+    pay for the longest one's attention (a set costs a minute of compiling
+    on a first run, so not one a context length). The first of the shortest
+    is also replayed on the served path, and its sets compared with the
+    reference's."""
     import jax.numpy as jnp
 
     t = run.traffic
@@ -424,7 +414,10 @@ def _check_replies(run: Run, cfg: Any, serving: Any, params: Any,
             ok = False
     rng = np.random.default_rng(run.seed)
     most_out = int(t["output_tokens"]["max"])
-    length = max(r.prompt_len for r in reqs) + most_out
+    prompts = sorted({r.prompt_len for r in reqs})
+    shared = prompts[-2] if len(prompts) > 1 else prompts[-1]
+    pad_to = {p: (p if p == prompts[-1] else shared) + most_out
+              for p in prompts}
     model = reference_model(run.config)
     worst, hits, misses, total, checked = 0.0, 0, 0, 0, 0
     turn = int(t["turn_tokens"])
@@ -433,7 +426,8 @@ def _check_replies(run: Run, cfg: Any, serving: Any, params: Any,
         n_check = min(int(t["check_replies_per_length"]), len(mine))
         for i in rng.choice(len(mine), size=n_check, replace=False):
             toks = mine[int(i)]["tokens"]
-            padded = np.zeros((length,), np.int32)
+            began = time.monotonic()
+            padded = np.zeros((pad_to[plen],), np.int32)
             padded[:len(toks)] = toks  # causal: the tail cannot reach back
             positions = np.arange(plen - 1, len(toks) - 1)
             asked = np.full((most_out,), positions[-1])
@@ -442,18 +436,23 @@ def _check_replies(run: Run, cfg: Any, serving: Any, params: Any,
                 logp = reference.log_probs(params, jnp.asarray(padded),
                                            jnp.asarray(asked), model)
             else:
+                # the turn's rows and the longest reply's: one gather program
+                rows = np.arange(plen - turn, plen + most_out - 1)
                 logp, masks, routes = reference.log_probs(
                     params, jnp.asarray(padded), jnp.asarray(asked), model,
-                    return_sets=True,
-                    set_rows=np.arange(plen - turn, len(toks) - 1))
-                ok = _check_served_sets(cfg, serving, params, toks, plen - turn,
-                                        plen, masks, routes) and ok
+                    return_sets=True, set_rows=rows)
+                fed = len(toks) - 1 - (plen - turn)
+                ok = _check_served_sets(
+                    cfg, serving, params, toks, plen - turn, plen, most_out,
+                    {i: m[:fed] for i, m in masks.items()},
+                    {i: r[:fed] for i, r in routes.items()}) and ok
             logp = np.asarray(logp)[:len(positions)]
             gap = logp.max(-1) - logp[np.arange(len(positions)),
                                       toks[positions + 1]]
             say(f"  reply {mine[int(i)]['index']} ({plen} + "
                 f"{len(positions)} tokens): worst gap {gap.max():.4f}, "
-                f"{int((gap > 0).sum())} not the reference's argmax")
+                f"{int((gap > 0).sum())} not the reference's argmax, "
+                f"{time.monotonic() - began:.1f}s")
             worst = max(worst, float(gap.max()))
             hits += int((gap == 0).sum())
             misses += int((gap > GREEDY_MARGIN_NATS).sum())
@@ -623,8 +622,10 @@ def run(run: Run) -> None:
         t1 = t0 + run.seconds
         run.window = (t0, t1)
         run.compile_window = run.meter.since(window_mark)
-        if profiler is not None:
-            profiler.join(timeout=120.0)
+        # the traced seconds end before anything else reaches the chip
+        while (profiler is not None and profiler.is_alive()
+               and run.trace_window == (0.0, 0.0)):
+            time.sleep(0.05)
         counters1 = session.telemetry.snapshot()["counters"]
         evicted = session.evicted()
         free_pages = session.server._pool.free_pages
@@ -679,6 +680,10 @@ def run(run: Run) -> None:
     with run.phase("replies against the reference (after the window)"):
         replies_ok = _check_replies(run, session.cfg, session.serving,
                                     session.params, measured, callers, reqs)
+    if profiler is not None:
+        # stop_trace() has been writing the trace out on the host since the
+        # traced seconds ended, beside the check above
+        profiler.join(timeout=120.0)
     turn = int(t["turn_tokens"])
     by_index = {r.index: r for r in reqs}
     # every page of the session's context was a prefix hit, and no more

@@ -207,34 +207,72 @@ def routing(p: Dict[str, Any], m: jax.Array, k: int, scaling: float):
         return chosen, scaling * picked / jnp.sum(picked, -1, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "first", "count", "cap"))
-def _ffn(p: Dict[str, Any], x: jax.Array, k: int, scaling: float,
-         first: int, count: int, cap: int):
-    """``(x + FFN, fits, routed)``. An expert runs on the ``cap`` tokens it
-    gates highest; ``fits`` says no expert was chosen by more than ``cap``
-    (with ``cap`` the sequence length it cannot be); ``routed [S, count]``
-    says which tokens chose which held expert."""
+@jax.jit
+def _dense_ffn(norm: jax.Array, mlp: Dict[str, Any], x: jax.Array) -> jax.Array:
     with jax.default_matmul_precision("highest"):
-        m = rms_norm(x, p["post_attn_norm"]["scale"])
-        mlp = p["mlp"]
-        if "router" not in mlp:
-            return (x + _swiglu(mlp, m), jnp.bool_(True),
-                    jnp.zeros((x.shape[0], 0), bool))
-        chosen, gates = routing(mlp, m, k, scaling)
-        y = _swiglu(mlp["shared_expert"], m)
+        return x + _swiglu(mlp, rms_norm(x, norm))
 
-        fits = jnp.bool_(True)
-        for e in range(count):  # this chip's experts: first + e of the 256
-            g = jnp.sum(jnp.where(chosen == first + e, gates, 0.0), -1)  # [S]
-            rows = jnp.argsort(g <= 0, stable=True)[:cap]  # its tokens first
-            mb = m[rows]
-            h = jax.nn.silu(mb @ _f32(mlp[f"expert_{e}_gate"])) * (
-                mb @ _f32(mlp[f"expert_{e}_up"]))
-            y = y.at[rows].add(
-                g[rows, None] * (h @ _f32(mlp[f"expert_{e}_down"])))
-            fits = fits & (jnp.sum(g > 0) <= cap)
+
+@functools.partial(jax.jit, static_argnames=("k", "first", "count"))
+def _shared_and_routing(norm: jax.Array, shared: Dict[str, Any],
+                        router: Dict[str, jax.Array], x: jax.Array, k: int,
+                        scaling: float, first: int, count: int):
+    """``(m, x + SwiGLU_shared(m), gate, routed)``: ``gate [count, S]`` is
+    ``g_e`` of each held expert for each token, 0 where it was not chosen;
+    ``routed [S, count]`` says which tokens chose which held expert."""
+    with jax.default_matmul_precision("highest"):
+        m = rms_norm(x, norm)
+        chosen, gates = routing(router, m, k, scaling)
         held = first + jnp.arange(count)
-        return x + y, fits, jnp.any(chosen[:, :, None] == held, axis=1)
+        gate = jnp.sum(jnp.where(chosen[None] == held[:, None, None],
+                                 gates[None], 0.0), -1)
+        return (m, x + _swiglu(shared, m), gate,
+                jnp.any(chosen[:, :, None] == held, axis=1))
+
+
+@functools.partial(jax.jit, static_argnames=("cap",), donate_argnums=(0,))
+def _add_expert(out: jax.Array, m: jax.Array, gate: jax.Array, e: jax.Array,
+                w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                cap: int) -> jax.Array:
+    """``out + g_e SwiGLU_e(m)`` for held expert ``e``, run on the tokens
+    that chose it, in a pass of ``cap`` (the caller counts: they are at most
+    ``cap``)."""
+    with jax.default_matmul_precision("highest"):
+        g = gate[e]
+        # its tokens, then row 0 again and again at weight 0
+        rows = jnp.nonzero(g > 0, size=cap, fill_value=0)[0]
+        g = jnp.where(jnp.arange(cap) < jnp.sum(g > 0), g[rows], 0.0)
+        mb = m[rows]
+        h = jax.nn.silu(mb @ _f32(w_gate)) * (mb @ _f32(w_up))
+        return out.at[rows].add(g[:, None] * (h @ _f32(w_down)))
+
+
+def _room(n: int) -> int:
+    """Tokens one pass of an expert holds, of a sequence of ``n``: an expert
+    is chosen by n * k / E tokens on average, and a quarter of a long
+    sequence is eight times that."""
+    return n if n <= 2048 else n // 4
+
+
+def _ffn(p: Dict[str, Any], x: jax.Array, k: int, scaling: float,
+         first: int, count: int):
+    """``(x + FFN, routed)`` of one layer ``p``; the held experts one at a
+    time, so that one small program serves them all."""
+    norm, mlp = p["post_attn_norm"]["scale"], p["mlp"]
+    if "router" not in mlp:
+        return _dense_ffn(norm, mlp, x), None
+    m, out, gate, routed = _shared_and_routing(
+        norm, mlp["shared_expert"],
+        {name: mlp[name] for name in ("router", "e_score_correction_bias")},
+        x, k, scaling, first, count)
+    n = x.shape[0]
+    load = np.asarray(jnp.sum(gate > 0, axis=-1))
+    for e in range(count):  # this chip's experts: first + e of the 256
+        # an expert chosen by more than its room takes the whole sequence
+        out = _add_expert(out, m, gate, np.int32(e), mlp[f"expert_{e}_gate"],
+                          mlp[f"expert_{e}_up"], mlp[f"expert_{e}_down"],
+                          _room(n) if load[e] <= _room(n) else n)
+    return out, routed
 
 
 @jax.jit
@@ -264,7 +302,6 @@ def log_probs(params: Any, tokens: jax.Array, positions: jax.Array,
     theta = float(model["rope_theta"])
     first, count = model["experts_held"]
     x = _embed(p["embed"]["embedding"], tokens)
-    n = int(tokens.shape[0])
     mask, masks, routes = None, {}, {}
     for i, kind in enumerate(model["indexer_types"]):
         layer = p[f"layers_{i}"]
@@ -277,18 +314,11 @@ def log_probs(params: Any, tokens: jax.Array, positions: jax.Array,
                 masks[i] = np.asarray(mask if set_rows is None
                                       else mask[set_rows])
         x = _attention(layer, x, mask, theta)
-        # an expert is chosen by n * k / E tokens on average; eight times
-        # that is room, and the whole sequence is the fall-back
-        for cap in (n if n <= 2048 else n // 4, n):
-            out, fits, routed = _ffn(
-                layer, x, int(model["num_experts_per_tok"]),
-                float(model["routed_scaling_factor"]), first, count, cap)
-            if bool(fits):
-                break
-        x = out
-        if return_sets and routed.shape[1]:
-            routes[i] = np.asarray(routed if set_rows is None
-                                   else routed[set_rows])
+        x, routed = _ffn(layer, x, int(model["num_experts_per_tok"]),
+                         float(model["routed_scaling_factor"]), first, count)
+        if return_sets and routed is not None:
+            routes[i] = np.asarray(routed)[
+                slice(None) if set_rows is None else set_rows]
     out = _head_logprobs(p["norm"]["scale"], p["lm_head"]["kernel"], x,
                          positions)
     return (out, masks, routes) if return_sets else out

@@ -487,13 +487,23 @@ class ExpertShare(nn.Module):
                           nn.initializers.normal(0.05),
                           (cfg.n_routed_experts,), jnp.float32)
         # one leaf per matrix per expert: a stacked array would be sliced
-        # (copied) ahead of the conditional below, chosen or not
-        init = nn.initializers.lecun_normal()
-        experts = [
-            (self.param(f"expert_{e}_gate", init, (d, f), cfg.param_dtype),
-             self.param(f"expert_{e}_up", init, (d, f), cfg.param_dtype),
-             self.param(f"expert_{e}_down", init, (f, d), cfg.param_dtype))
-            for e in range(count)]
+        # (copied) ahead of the conditional below, chosen or not. They are
+        # drawn stacked, one draw a matrix kind, and cut into leaves: a draw
+        # a leaf (192 of them) was two minutes of compiling the seeded init
+        shapes = {"gate": (d, f), "up": (d, f), "down": (f, d)}
+        drawn = {}
+        if self.is_initializing():
+            init = nn.initializers.lecun_normal(batch_axis=(0,))
+            keys = jax.random.split(self.make_rng("params"), len(shapes))
+            for key, (kind, shape) in zip(keys, shapes.items()):
+                drawn[kind] = init(key, (count,) + shape, cfg.param_dtype)
+
+        def cut(e, kind):  # flax also asks, outside init, for the shape alone
+            return lambda _: (drawn[kind][e] if drawn else
+                              jnp.zeros(shapes[kind], cfg.param_dtype))
+
+        experts = [tuple(self.param(f"expert_{e}_{kind}", cut(e, kind))
+                         for kind in shapes) for e in range(count)]
         affinity = router_affinity(flat, router, cfg.norm_router_dtype)
         gates = route(affinity, bias, cfg.n_experts_per_tok,
                       cfg.routed_scaling_factor)[:, first:first + count]

@@ -78,6 +78,17 @@ def test_prefill_matches_reference(params, n):
     assert np.abs(got - _want(params, toks, np.arange(n))).max() < TOL
 
 
+@pytest.mark.parametrize("room", [3, 39], ids=["overflows", "holds"])
+def test_reference_expert_pass_does_not_depend_on_its_room(params, room,
+                                                           monkeypatch):
+    """An expert runs on the tokens that chose it, gathered into a pass of
+    ``_room`` tokens, or on the whole sequence when more chose it."""
+    toks = _tokens(40, seed=7)
+    want = _want(params, toks, np.arange(40))
+    monkeypatch.setattr(ref, "_room", lambda n: room)
+    assert np.abs(_want(params, toks, np.arange(40)) - want).max() < 1e-5
+
+
 @pytest.mark.parametrize("first,more", [(8, 4), (12, 9), (24, 1), (32, 17)],
                          ids=["under_topk", "across_topk", "one_token",
                               "past_topk_odd_block"])

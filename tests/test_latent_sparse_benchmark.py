@@ -140,7 +140,9 @@ def test_whole_model_limits_catch_a_planted_fault(fault, what):
     toy = harness.toy(CONFIG)
     true = serve_sessions.program_config(toy)
     model = serve_sessions.reference_model(toy)
-    params = init_params(true, jax.random.PRNGKey(3))
+    # a seed at which the held experts see a token in two (two held of
+    # eight, top-2): at one where they see few, the gates' scale hides
+    params = init_params(true, jax.random.PRNGKey(6))
     tokens = np.random.default_rng(3).integers(
         0, toy["vocab_size"], 192).astype(np.int32)
     n = len(tokens)
