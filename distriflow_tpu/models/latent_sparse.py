@@ -40,7 +40,10 @@ cache entries, then selects and attends against the cache, in blocks of
 ``query_block`` queries. The cache contract is ``TransformerLM``'s
 (``models/generate.py``): ``cache_index`` scalar or per row, paged when a
 ``page_table`` leaf is present; retired rows (an all-sentinel table row)
-are not routed.
+are not routed, and where a paged call holds more than ``ROWS`` rows, as
+the engine's decode step over its slots does, the selector and the
+attention run over the rows the table backs and no others
+(:func:`_over_live`).
 """
 
 from __future__ import annotations
@@ -122,16 +125,22 @@ class LatentSparseConfig:
     def decode_family(self) -> DecodeFamily:
         return _FAMILY
 
-    def decode_work(self, ctx: Sequence[int], steps: int) -> Dict[str, int]:
+    def decode_work(self, ctx: Sequence[int], steps: int,
+                    slots: int) -> Dict[str, int]:
         """What one decode dispatch of ``steps`` steps over live rows with
-        ``ctx`` cached tokens each selects and routes: ``sel_tokens`` (the
-        tokens attention reads per step, summed over rows) and
-        ``assignments`` ((token, expert) choices over all sparse layers and
-        steps, held here or not). The engine annotates and counts them."""
+        ``ctx`` cached tokens each, among ``slots`` rows, selects and
+        routes: ``sel_tokens`` (the tokens attention reads per step, summed
+        over rows), ``assignments`` ((token, expert) choices over all
+        sparse layers and steps, held here or not) and ``rows_run`` (the
+        rows whose selection and attention the program computes, summed
+        over steps: the live ones in whole groups of ``ROWS``, every layer
+        of a step the same). The engine annotates and counts them."""
         sparse = sum(kind == "sparse" for kind in self.mlp_layer_types)
+        run = slots if slots <= ROWS else -(-len(ctx) // ROWS) * ROWS
         return {"sel_tokens": sum(min(c, self.index_topk) for c in ctx),
                 "assignments": len(ctx) * steps * sparse
-                * self.n_experts_per_tok}
+                * self.n_experts_per_tok,
+                "rows_run": run * steps}
 
 
 def rope_interleaved(x: jnp.ndarray, pos: jnp.ndarray,
@@ -166,6 +175,45 @@ def _blocked(fn, block: int, *arrays):
     return jax.tree.map(
         lambda o: jnp.moveaxis(o, 0, 1).reshape(
             (o.shape[1], n * block) + o.shape[3:])[:, :s], out)
+
+
+ROWS = 4  # rows a trip of _over_live selects and attends for
+
+
+def live_first(live: jnp.ndarray):
+    """``(order, n_live)`` of ``live [B]``: the rows in an order that puts
+    the live ones first, each kind in its own order (a cumsum, no sort),
+    and how many are live."""
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    at = jnp.where(live, jnp.cumsum(live) - 1,
+                   n_live + jnp.cumsum(~live) - 1)
+    order = jnp.zeros_like(at).at[at].set(
+        jnp.arange(live.shape[0], dtype=at.dtype), unique_indices=True)
+    return order, n_live
+
+
+def _over_live(fn, rows, *arrays):
+    """``fn(ids, *(a[ids] for a in arrays))`` for the first ``trips``
+    groups of ``ROWS`` rows ``ids`` of ``order``, ``rows = (order,
+    trips)``: a run-time trip count, one loop body whatever is live. Each
+    result lands at its rows' places, and the rows of groups not run read
+    zeros. What ``fn`` closes over (the pools) is the loop's operand, not
+    its carry. A last group that would pass the end is moved back over
+    rows already done."""
+    order, trips = rows
+    like = jax.eval_shape(fn, *(
+        jax.ShapeDtypeStruct((ROWS,) + a.shape[1:], a.dtype)
+        for a in (order,) + arrays))
+    out = jax.tree.map(
+        lambda o: jnp.zeros(order.shape + o.shape[1:], o.dtype), like)
+
+    def trip(t, out):
+        ids = jax.lax.dynamic_slice(order, (t * ROWS,), (ROWS,))
+        got = fn(ids, *(a[ids] for a in arrays))
+        return jax.tree.map(
+            lambda o, g: o.at[ids].set(g, unique_indices=True), out, got)
+
+    return jax.lax.fori_loop(0, trips, trip, out)
 
 
 CHUNK = 128  # positions compacted together in top_positions: a lane tile
@@ -277,11 +325,13 @@ class LatentSparseAttention(nn.Module):
     layer: int
 
     @nn.compact
-    def __call__(self, x, selection):
+    def __call__(self, x, selection, rows):
         """``selection`` is the ``(idx, valid, where)`` of the nearest
         earlier ``full`` layer, or None: the chosen logical positions,
         which of them are positions at all, and where each lies in the
-        (flattened) cache. Returns ``(out, selection, live)``: ``live
+        (flattened) cache. ``rows`` is the first layer's ``(order,
+        trips)`` for :func:`_over_live`, or None in the first layer and
+        wherever all rows are run at once. Returns ``(out, selection, rows, live)``: ``live
         [B]`` says which rows the page table backs (None unless paged)."""
         cfg = self.config
         b, s, _ = x.shape
@@ -309,6 +359,14 @@ class LatentSparseAttention(nn.Module):
         # queries a block, fewer for more rows: the [rows, queries, cached
         # tokens] temporaries of a group of turns stay those of one
         block = max(8, cfg.query_block >> (b - 1).bit_length())
+        live = (table[:, 0] < latent_var.value.shape[0]) if paged else None
+        if self.layer == 0 and paged and b > ROWS:
+            # the step's rows, once for every layer and shared index set:
+            # more rows than a group means slots, of which some are retired
+            order, n_live = live_first(live)
+            rows = (order, -(-n_live // ROWS))  # groups that hold a live row
+            # read only by a caller that asks for "intermediates"
+            self.sow("intermediates", "rows_run", rows[1] * ROWS)
         pos0 = idx if idx.ndim == 1 else jnp.broadcast_to(idx, (b,))
         q_pos = pos0[:, None] + jnp.arange(s)[None, :]  # [B, s]
 
@@ -343,16 +401,24 @@ class LatentSparseAttention(nn.Module):
                 return buf.at[jnp.arange(b)[:, None], q_pos].set(new)
             return jax.lax.dynamic_update_slice(buf, new, (0, idx, 0))
 
-        def rows(buf):
-            """Every cached entry of every row in logical order, ``[B, K,
-            F]``: the slab itself, or the row's pages by its table."""
+        def per_row(fn, *arrays):
+            """``fn(ids, *arrays)`` over all rows at once (``ids`` None),
+            or over the live ones in groups (``ids`` the group's rows)."""
+            if rows is None:
+                return fn(None, *arrays)
+            return _over_live(fn, rows, *arrays)
+
+        def entries(buf, tab):
+            """Every cached entry of the rows in logical order, ``[b, K,
+            F]``: the slab itself, or the rows' pages by their table
+            rows ``tab``."""
             if not paged:
                 return buf
-            tab = jnp.minimum(table[:, :-1], buf.shape[0] - 1)
-            return buf[tab].reshape(b, -1, buf.shape[-1])
+            tab = jnp.minimum(tab[:, :-1], buf.shape[0] - 1)
+            return buf[tab].reshape(tab.shape[0], -1, buf.shape[-1])
 
-        def locate(sel):
-            """Logical positions ``sel [B, q, T]`` as rows of the pool
+        def locate(sel, tab):
+            """Logical positions ``sel [b, q, T]`` as rows of the pool
             flattened over pages (paged), else as they are. The page
             table is read by a one-hot sum: a gather of one number a
             position costs more than the attention it feeds."""
@@ -361,8 +427,8 @@ class LatentSparseAttention(nn.Module):
             n_pg, ps = latent_var.value.shape[:2]
             page = sel // ps
             phys = jnp.sum(jnp.where(
-                page[..., None] == jnp.arange(table.shape[1]),
-                table[:, None, None, :], 0), axis=-1)
+                page[..., None] == jnp.arange(tab.shape[1]),
+                tab[:, None, None, :], 0), axis=-1)
             return jnp.minimum(phys, n_pg - 1) * ps + sel % ps
 
         def pick(buf, where):
@@ -397,14 +463,19 @@ class LatentSparseAttention(nn.Module):
                                     * cfg.index_head_dim ** -0.5)
                 index_var.value = store(index_var.value,
                                         k_idx.astype(cfg.dtype))
-                keys = rows(index_var.value)
 
-                def select(qi, wi, pi):
-                    sel, valid = select_tokens(qi, wi, keys, pi,
-                                               cfg.index_topk)
-                    return sel, valid, locate(sel)
+                def select(ids, *queries):
+                    tab = table if ids is None else table[ids]
+                    keys = entries(index_var.value, tab)
 
-                selection = _blocked(select, block, q_idx, w, q_pos)
+                    def choose(qi, wi, pi):
+                        sel, valid = select_tokens(qi, wi, keys, pi,
+                                                   cfg.index_topk)
+                        return sel, valid, locate(sel, tab)
+
+                    return _blocked(choose, block, *queries)
+
+                selection = per_row(select, q_idx, w, q_pos)
                 # read only by a caller that asks for "intermediates"
                 self.sow("intermediates", "selected", selection[:2])
         ci.value = idx + s
@@ -430,11 +501,12 @@ class LatentSparseAttention(nn.Module):
                                   got[..., :cfg.kv_lora_rank],
                                   preferred_element_type=jnp.float32)
 
-            o_lat = _blocked(attend, block, q_cat, *selection)
+            o_lat = per_row(
+                lambda _ids, *group: _blocked(attend, block, *group),
+                q_cat, *selection)
             out = jnp.einsum("bshc,chv->bshv", o_lat.astype(cfg.dtype), w_vb)
         out = _dense(cfg, cfg.d_model, "o_proj", axis=(-2, -1))(out)
-        live = (table[:, 0] < latent_var.value.shape[0]) if paged else None
-        return out, selection, live
+        return out, selection, rows, live
 
 
 class SwiGLU(nn.Module):
@@ -541,11 +613,11 @@ class LatentSparseBlock(nn.Module):
     layer: int
 
     @nn.compact
-    def __call__(self, x, selection):
+    def __call__(self, x, selection, rows):
         cfg = self.config
-        attn, selection, live = LatentSparseAttention(
+        attn, selection, rows, live = LatentSparseAttention(
             cfg, self.layer, name="attn")(
-                _norm(cfg, "input_norm")(x), selection)
+                _norm(cfg, "input_norm")(x), selection, rows)
         x = x + attn
         h = _norm(cfg, "post_attn_norm")(x)
         if cfg.mlp_layer_types[self.layer] == "dense":
@@ -553,7 +625,7 @@ class LatentSparseBlock(nn.Module):
         else:
             with jax.named_scope("moe_experts"):
                 y = ExpertShare(cfg, name="mlp")(h, live)
-        return x + y, selection
+        return x + y, selection, rows
 
 
 class LatentSparseLM(nn.Module):
@@ -566,10 +638,10 @@ class LatentSparseLM(nn.Module):
         cfg = self.config
         x = nn.Embed(cfg.vocab_size, cfg.d_model, name="embed",
                      dtype=cfg.dtype, param_dtype=cfg.param_dtype)(tokens)
-        selection = None
+        selection = rows = None
         for i in range(cfg.n_layers):
-            x, selection = LatentSparseBlock(cfg, i, name=f"layers_{i}")(
-                x, selection)
+            x, selection, rows = LatentSparseBlock(
+                cfg, i, name=f"layers_{i}")(x, selection, rows)
         x = _norm(cfg, "norm")(x)
         return _dense(cfg, cfg.vocab_size, "lm_head")(x).astype(jnp.float32)
 

@@ -497,6 +497,14 @@ class InferenceServer:
                 help="(token, expert) choices of live rows in decode steps, "
                      "by whether this server holds the expert")
                 for held in ("yes", "no")}
+            self._m_rows_run = tel.counter(
+                "serving_sparse_rows_run_total",
+                help="rows the sparse selector and attention run, summed "
+                     "over decode steps (every layer of a step runs the "
+                     "same rows)")
+            self._m_rows_live = tel.counter(
+                "serving_sparse_rows_live_total",
+                help="of those, the rows that are live")
         # what the decode loop reads: ``params`` with every weight the
         # block consumes in ``config.dtype`` already cast (compute_view),
         # made here and at each set_params, never at a dispatch
@@ -1479,8 +1487,10 @@ class InferenceServer:
                 stats["live_pages"] = sum(-(-c // ps) for c in ctx)
                 stats["table_pages"] = len(self._slot_req) * self._pp
             if self._decode_work is not None:
-                work = self._decode_work(ctx, srv.decode_chunk)
+                work = self._decode_work(ctx, srv.decode_chunk,
+                                         len(self._slot_req))
                 stats["sel_tokens"] = work["sel_tokens"]
+                stats["rows_run"] = work["rows_run"]
         with self._prof.phase("decode_iter", **stats):
             sampling = bool((self._temps[active] > 0).any())
             _insert, _pick, decode = _build_slot_fns(
@@ -1531,7 +1541,7 @@ class InferenceServer:
             sparse = {}
             if "experts_hit" in stats:
                 sparse = {k: stats[k] for k in (
-                    "ctx_tokens", "sel_tokens", "experts_hit",
+                    "ctx_tokens", "sel_tokens", "rows_run", "experts_hit",
                     "local_assignments")}
                 self._m_ctx_tokens.inc(stats["ctx_tokens"])
                 self._m_sel_tokens.inc(stats["sel_tokens"])
@@ -1539,6 +1549,8 @@ class InferenceServer:
                 self._m_assignments["yes"].inc(stats["local_assignments"])
                 self._m_assignments["no"].inc(
                     work["assignments"] - stats["local_assignments"])
+                self._m_rows_run.inc(stats["rows_run"])
+                self._m_rows_live.inc(len(active) * srv.decode_chunk)
             self.decode_batches += 1
             self._m_batches.inc()
             self._tok = tok

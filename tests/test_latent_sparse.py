@@ -23,6 +23,7 @@ from distriflow_tpu.models.generate import (
     generate,
     paged_cache,
     sequence_logprob,
+    set_page_tables,
 )
 from distriflow_tpu.models.latent_sparse import (
     ExpertShare,
@@ -170,6 +171,159 @@ def test_paged_decode_matches_reference(params):
     assert 0 < stats[1] <= 8 * 3 * 2 and 0 < stats[0] <= 8 * 2
 
 
+# -- the selector and the attention run over live rows only -----------------
+
+PS, N_PAGES = 8, 48
+FULL = [i for i, kind in enumerate(CFG.indexer_types) if kind == "full"]
+
+
+def _all_rows(fn):
+    """``fn`` as it is traced with ``ROWS`` past any batch: the form that
+    runs every row at once, the oracle of the grouped one."""
+    jitted = jax.jit(lambda *args: fn(*args))  # a trace of its own
+
+    def run(*args):
+        old, ls.ROWS = ls.ROWS, 1 << 20
+        try:
+            return jitted(*args)
+        finally:
+            ls.ROWS = old
+
+    return run
+
+
+def _one_step(p, cache, tok):
+    """A decode step with what its ``full`` layers selected."""
+    logits, state = LatentSparseLM(CFG).apply(
+        {**p, "cache": cache}, tok[:, None],
+        mutable=["cache", "intermediates"])
+    sown = state["intermediates"]
+    picked = {i: sown[f"layers_{i}"]["attn"]["selected"][0] for i in FULL}
+    # the program's own trip count x ROWS; all rows where it takes no loop
+    run = sown["layers_0"]["attn"].get("rows_run", (tok.shape[0],))[0]
+    return logits[:, 0], state["cache"], picked, run
+
+
+def _resident(params, slots):
+    """``slots`` rows of 10..38 tokens resident in a paged pool, their pages
+    interleaved: ``(cache, tables, next tokens)``."""
+    cache = paged_cache(CFG, params, slots, PS, N_PAGES)
+    prefill, _ = _build_prefill(CFG)
+    insert, _ = _build_paged_fns(CFG, PS)
+    tables = np.full((slots, CFG.max_seq // PS + 1), N_PAGES, np.int32)
+    tok = np.zeros((slots,), np.int32)
+    for slot in range(slots):
+        n = 10 + 4 * slot
+        # a row's pages lie ``slots`` apart: no two rows share one, and
+        # room for a chunk of steps past the prompt
+        tables[slot, :-(-(n + 8) // PS)] = slot + slots * np.arange(
+            -(-(n + 8) // PS))
+        logits, row = prefill(params, _tokens(n, seed=20 + slot)[None])
+        cache = insert(cache, row, np.array([slot], np.int32), np.int32(n),
+                       np.int32(0), tables)
+        tok[slot] = int(jnp.argmax(logits[0]))
+    return cache, tables, tok
+
+
+@pytest.fixture(scope="module")
+def resident(params):
+    return {slots: _resident(params, slots) for slots in (6, 8)}
+
+
+@pytest.fixture(scope="module")
+def step_forms():
+    return jax.jit(_one_step), _all_rows(_one_step)
+
+
+def _retire(cache, tables, live):
+    """A copy of ``cache`` in which only the slots ``live`` keep their
+    table rows."""
+    tables = tables.copy()
+    tables[[s for s in range(len(tables)) if s not in live]] = N_PAGES
+    return set_page_tables(jax.tree.map(jnp.copy, cache), tables), tables
+
+
+LIVE_SETS = [(8, (5,)), (8, (0, 1, 2)), (8, (1, 4, 6)), (8, (0, 2, 3, 5, 7)),
+             (8, tuple(range(8))), (6, (0, 1, 2, 4, 5)), (6, ())]
+
+
+@pytest.mark.parametrize("slots,live", LIVE_SETS,
+                         ids=[f"{s}slots_live_{'_'.join(map(str, l)) or 'none'}"
+                              for s, l in LIVE_SETS])
+def test_a_step_over_live_rows_is_the_step_over_all_rows(
+        params, resident, step_forms, slots, live):
+    """More slots than ``ROWS``: the served step runs the live rows in
+    groups of ``ROWS`` and gives each of them the set and the output that
+    the step over all rows gives it; a retired row writes no page."""
+    cache, tables, tok = resident[slots]
+    grouped, at_once = step_forms
+    live = list(live)
+    mine, tables = _retire(cache, tables, live)
+    before = {name: np.asarray(_find_cache_leaf(mine, name))
+              for name in ("cached_latent", "cached_index_k")}
+    theirs = jax.tree.map(jnp.copy, mine)
+    got, got_cache, got_sets, got_run = grouped(params, mine, tok)
+    want, want_cache, want_sets, want_run = at_once(params, theirs, tok)
+    for layer in FULL:
+        (idx, valid), (want_idx, want_valid) = (
+            [np.asarray(v)[live] for v in sets[layer]]
+            for sets in (got_sets, want_sets))
+        assert np.array_equal(valid, want_valid)
+        assert np.array_equal(idx[valid], want_idx[valid])
+        assert valid.sum() >= len(live) * 10  # every row chose something
+    assert np.abs(np.asarray(got)[live] - np.asarray(want)[live]).max(
+        initial=0) < TOL
+    written = np.unique(tables[tables < N_PAGES])
+    for name, old in before.items():
+        for layer in range(CFG.n_layers):
+            if name not in got_cache[f"layers_{layer}"]["attn"]:
+                continue  # a shared layer keeps no selector keys
+            new = np.asarray(got_cache[f"layers_{layer}"]["attn"][name])
+            ref = np.asarray(want_cache[f"layers_{layer}"]["attn"][name])
+            assert np.abs(new - ref)[written].max(initial=0) < TOL
+            if layer == 0:  # `before` is the first layer's leaf
+                rest = np.setdiff1d(np.arange(N_PAGES), written)
+                assert np.array_equal(new[rest], old[rest])
+    # the program's own trip count is what the engine's arithmetic says
+    n_run = -(-len(live) // ls.ROWS) * ls.ROWS
+    assert (int(got_run), int(want_run)) == (n_run, slots)
+    assert CFG.decode_work([12] * len(live), 3, slots)["rows_run"] == 3 * n_run
+
+
+@pytest.mark.parametrize("live", [(1, 4, 6), (0, 1, 2, 3, 4)],
+                         ids=["scattered_3", "contiguous_5"])
+def test_a_chunk_of_steps_over_live_rows(params, resident, live):
+    """The engine's decode program, four steps: the live rows' tokens are
+    those of the all-rows form and of the reference."""
+    cache, tables, tok = resident[8]
+    live = list(live)
+    _, _, decode = _build_slot_fns(CFG, 4, False)
+    mine, _ = _retire(cache, tables, live)
+    theirs = jax.tree.map(jnp.copy, mine)
+    done = np.array([s not in live for s in range(8)])
+    zeros = np.zeros((8,), np.int32)
+    args = (tok, done, np.zeros((8,), np.float32), zeros,
+            np.ones((8,), np.float32), zeros, zeros - 1)
+    _, _, _, got = decode(params, mine, *args)
+    _, _, _, want = _all_rows(decode.body)(params, theirs, *args)
+    assert np.array_equal(np.asarray(got)[live], np.asarray(want)[live])
+    s = live[1]
+    seq = np.concatenate([_tokens(10 + 4 * s, seed=20 + s), [tok[s]],
+                          np.asarray(got)[s]]).astype(np.int32)
+    at = np.arange(10 + 4 * s, len(seq) - 1)
+    logp = _want(params, seq, at)
+    assert (logp.max(-1) - logp[np.arange(len(at)), seq[at + 1]]).max() < TOL
+
+
+def test_live_first_is_stable_and_counts():
+    live = jnp.asarray([False, True, True, False, False, True, False, True])
+    order, n_live = ls.live_first(live)
+    assert int(n_live) == 4
+    assert np.asarray(order).tolist() == [1, 2, 5, 7, 0, 3, 4, 6]
+    order, n_live = ls.live_first(jnp.zeros((5,), bool))
+    assert int(n_live) == 0 and np.asarray(order).tolist() == [0, 1, 2, 3, 4]
+
+
 def test_shared_layer_uses_the_preceding_full_layers_set(params, monkeypatch):
     calls = []
     real = ls.select_tokens
@@ -182,9 +336,9 @@ def test_shared_layer_uses_the_preceding_full_layers_set(params, monkeypatch):
     seen = []
     real_call = ls.LatentSparseAttention.__call__
 
-    def call(self, x, selection):
+    def call(self, x, selection, rows):
         seen.append((self.layer, selection))
-        return real_call(self, x, selection)
+        return real_call(self, x, selection, rows)
 
     monkeypatch.setattr(ls.LatentSparseAttention, "__call__", call)
     with jax.disable_jit():
@@ -291,10 +445,14 @@ def test_top_positions_keeps_leading_axes():
         assert got.tolist() == want[row]
 
 
-def test_engine_counts_the_familys_work_through_its_declared_leaf(params):
+@pytest.mark.parametrize("slots,rows_run", [(2, 2), (6, 4)],
+                         ids=["all_rows", "live_groups"])
+def test_engine_counts_the_familys_work_through_its_declared_leaf(
+        params, slots, rows_run):
     """With telemetry on, a decode dispatch's spans and the counters carry
     what the layers counted in the family's ``work_leaf``; the server names
-    no leaf of its own."""
+    no leaf of its own. One row is live: with two slots the step runs both,
+    with six one group of ``ROWS``."""
     import inspect
 
     from distriflow_tpu import InferenceClient, InferenceServer, ServingConfig
@@ -306,8 +464,9 @@ def test_engine_counts_the_familys_work_through_its_declared_leaf(params):
     assert "expert_stats" not in inspect.getsource(inference_server)
     tel = Telemetry(enabled=True)
     tel.tracer = Tracer(enabled=True, max_spans=10_000)
-    serving = ServingConfig(max_slots=2, decode_chunk=4, kv_layout="paged",
-                            page_size=8, page_pool_pages=16)
+    serving = ServingConfig(max_slots=slots, decode_chunk=4,
+                            kv_layout="paged", page_size=8,
+                            page_pool_pages=16)
     server = InferenceServer(CFG, params, port=0, serving=serving,
                              telemetry=tel)
     server.setup()
@@ -324,7 +483,10 @@ def test_engine_counts_the_familys_work_through_its_declared_leaf(params):
         # one row, 4 steps, 2 sparse layers, 2 held experts, top-2 of 8
         assert 0 <= attrs["local_assignments"] <= 4 * 2 * 2
         assert attrs["experts_hit"] <= attrs["local_assignments"]
+        assert attrs["rows_run"] == 4 * rows_run  # steps x rows a step
     counters = tel.snapshot()["counters"]
+    assert counters["serving_sparse_rows_run_total"] == 2 * 4 * rows_run
+    assert counters["serving_sparse_rows_live_total"] == 2 * 4
     held = {k: v for k, v in counters.items()
             if k.startswith("serving_expert_assignments_total")}
     assert sum(held.values()) == 2 * 4 * 2 * 2  # dispatches x steps x layers x k
