@@ -89,7 +89,15 @@ class DecodeFamily(NamedTuple):
     cache leaf in which a layer adds up, over its calls, small integer
     counts of the work it chose to do (which counts is the family's to
     say, in its configuration's ``decode_work``); with telemetry on the
-    engine fetches those leaves with a decode dispatch's tokens. A
+    engine fetches those leaves with a decode dispatch's tokens.
+    ``slot_leaves`` names cache leaves that hold a fixed-size state per row
+    and no token axis (``[B, ...]``; a recurrent layer's state): they stay
+    ``[max_slots, ...]`` under either layout, ``insert`` writes a prefilled
+    row's state at its slot, and the engine's programs donate them with the
+    pools. A family whose cache is more than its tokens' entries cannot be
+    rebuilt from cached pages, nor rolled back by moving an index: it says
+    ``prefix_reusable=False``, ``gather_rows`` raises for it, and the
+    engine runs it without prefix reuse and refuses speculation. A
     configuration class names its family in a ``decode_family`` attribute;
     one without it is ``TransformerConfig``'s.
     """
@@ -98,6 +106,14 @@ class DecodeFamily(NamedTuple):
     decode_module: Callable[[Any], Any]
     score_logits: Callable[[Any], Callable[[Any, jnp.ndarray], jnp.ndarray]]
     work_leaf: Optional[str] = None
+    slot_leaves: Tuple[str, ...] = ()
+    prefix_reusable: bool = True
+
+    @property
+    def donated_leaves(self) -> Tuple[str, ...]:
+        """The leaves that hold the cache's bytes: what a program that
+        returns the cache's successor donates."""
+        return self.pool_leaves + self.slot_leaves
 
 
 def _transformer_decode_module(config: TransformerConfig) -> TransformerLM:
@@ -578,7 +594,7 @@ class _CacheProgram:
 def _donates_cache(cache_arg: int, config: Any):
     """Decorator form of :class:`_CacheProgram`, for ``config``'s pools."""
     return functools.partial(_CacheProgram, cache_arg=cache_arg,
-                             pool_leaves=decode_family(config).pool_leaves)
+                             pool_leaves=decode_family(config).donated_leaves)
 
 
 def pages_per_slot(max_seq: int, page_size: int) -> int:
@@ -671,7 +687,8 @@ def _build_paged_fns(config: TransformerConfig, page_size: int):
     :func:`_build_slot_fns` programs serve both layouts."""
     max_seq = config.max_seq
     pp = pages_per_slot(max_seq, page_size)
-    pool_leaves = decode_family(config).pool_leaves
+    family = decode_family(config)
+    pool_leaves, slot_leaves = family.pool_leaves, family.slot_leaves
 
     @_donates_cache(0, config)
     def insert(cache, row_cache, slots, length, start, table):
@@ -700,6 +717,8 @@ def _build_paged_fns(config: TransformerConfig, page_size: int):
                         jnp.broadcast_to(length, slots.shape).astype(d.dtype))
                 elif name in pool_leaves:
                     out[name] = scatter_pool(d, src[name].astype(d.dtype))
+                elif name in slot_leaves:  # a row's whole state, at its slot
+                    out[name] = d.at[slots].set(src[name].astype(d.dtype))
                 elif hasattr(d, "items"):
                     out[name] = walk(d, src[name])
                 else:
@@ -707,6 +726,15 @@ def _build_paged_fns(config: TransformerConfig, page_size: int):
             return out
 
         return walk(cache, row_cache)
+
+    if not family.prefix_reusable:
+        def no_gather(cache, tables, start):
+            raise ValueError(
+                f"{type(config).__name__}'s cache holds per-row state "
+                f"({', '.join(slot_leaves)}) that is not a function of cached "
+                "pages: a row cannot be rebuilt from a shared prefix")
+
+        return insert, no_gather
 
     @jax.jit
     def gather_rows(cache, tables, start):
@@ -798,7 +826,7 @@ def _build_slot_fns(config: TransformerConfig, chunk: int,
     cache, so switching mid-flight is free)."""
     module = _decode_module(config)
 
-    pool_leaves = decode_family(config).pool_leaves
+    pool_leaves = decode_family(config).donated_leaves  # slabs: rows alike
 
     @_donates_cache(0, config)
     def insert(cache, row_cache, slots, length):

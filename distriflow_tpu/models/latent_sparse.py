@@ -96,6 +96,12 @@ class LatentSparseConfig:
     norm_router_dtype: Any = jnp.float32
     query_block: int = 128
 
+    #: what :class:`ExpertShare` reads of its family (:data:`SCORING`): how it
+    #: scores and weighs, and whether the held experts are one leaf a matrix
+    #: kind (``ExpertShare._stacked``) or, as here, one a matrix an expert
+    scoring_func = "sigmoid"
+    experts_stacked = False
+
     def __post_init__(self):
         if not (len(self.indexer_types) == len(self.mlp_layer_types)
                 == self.n_layers):
@@ -120,6 +126,11 @@ class LatentSparseConfig:
         way; a 576-wide pool is handed over with its token axis minor, and
         every dispatch re-lays it out twice (PERF.md §6 PR 33)."""
         return -(-self.latent_dim // 128) * 128
+
+    @property
+    def shared_d_ff(self) -> int:
+        """Width of the shared expert: one routed expert's."""
+        return self.moe_d_ff
 
     @property
     def decode_family(self) -> DecodeFamily:
@@ -315,7 +326,7 @@ def _norm(cfg: LatentSparseConfig, name: str) -> RMSNorm:
     return RMSNorm(cfg.rms_eps, cfg.norm_router_dtype, name=name)
 
 
-def _dense(cfg: LatentSparseConfig, features, name: str, **kw):
+def _dense(cfg: Any, features, name: str, **kw):
     return nn.DenseGeneral(features, name=name, use_bias=False,
                            dtype=cfg.dtype, param_dtype=cfg.param_dtype, **kw)
 
@@ -510,7 +521,7 @@ class LatentSparseAttention(nn.Module):
 
 
 class SwiGLU(nn.Module):
-    config: LatentSparseConfig
+    config: Any  # ``dtype`` and ``param_dtype`` are read
     width: int
 
     @nn.compact
@@ -530,6 +541,13 @@ def router_affinity(x: jnp.ndarray, router: jnp.ndarray,
         precision=jax.lax.Precision.HIGHEST)).astype(jnp.float32)
 
 
+def router_logits(x: jnp.ndarray, router: jnp.ndarray,
+                  dtype: Any = jnp.float32) -> jnp.ndarray:
+    """``x W_r`` in float32, as :func:`router_affinity` and for its reason."""
+    return jnp.dot(x.astype(dtype), router.astype(dtype),
+                   precision=jax.lax.Precision.HIGHEST).astype(jnp.float32)
+
+
 def route(scores: jnp.ndarray, bias: jnp.ndarray, k: int, scaling: float):
     """``scores [T, E]`` float32 sigmoid affinities. The ``k`` largest of
     ``scores + bias`` are chosen; their gates are ``scaling * score / sum
@@ -541,28 +559,51 @@ def route(scores: jnp.ndarray, bias: jnp.ndarray, k: int, scaling: float):
     return jnp.einsum("tk,tke->te", gates, onehot)
 
 
+def route_softmax_topk(logits: jnp.ndarray, k: int):
+    """``logits [T, E]`` float32. The ``k`` largest are chosen; their gates
+    are the softmax over those ``k`` values alone (no bias, no scaling).
+    Returns the dense ``[T, E]`` gate matrix."""
+    top, chosen = jax.lax.top_k(logits, k)
+    onehot = jax.nn.one_hot(chosen, logits.shape[-1], dtype=logits.dtype)
+    return jnp.einsum("tk,tke->te", jax.nn.softmax(top, axis=-1), onehot)
+
+
+#: tokens from which :class:`ExpertShare` runs stacked experts in one loop
+DENSE_TOKENS = 64
+
+#: the scoring rules of :class:`ExpertShare`, a configuration's
+#: ``scoring_func``: sigmoid affinities, a bias that only chooses, gates
+#: normalised over the chosen and scaled (:func:`route`); or the top-k of
+#: the logits and a softmax over the chosen (:func:`route_softmax_topk`)
+SCORING = ("sigmoid", "softmax_topk")
+
+
+def _expert_term(xc, w_gate, w_up, w_down, gate):
+    """One expert's gated MLP over the tokens ``xc``, weighed by its
+    ``gate [T]`` (0 for a token that did not choose it), float32."""
+    h = jax.nn.silu(xc @ w_gate.astype(xc.dtype)) * (
+        xc @ w_up.astype(xc.dtype))
+    out = (h @ w_down.astype(xc.dtype)).astype(jnp.float32)
+    return out * gate[:, None]
+
+
 class ExpertShare(nn.Module):
     """The sparse FFN as the holder of ``experts_held`` computes it: the
-    shared expert plus its own experts' terms, dropless."""
-    config: LatentSparseConfig
+    shared expert plus its own experts' terms, dropless. ``config`` is any
+    family's that has ``d_model`` wide tokens and the attributes read here
+    (``experts_held``, ``moe_d_ff``, ``shared_d_ff``, ``n_routed_experts``,
+    ``n_experts_per_tok``, ``scoring_func`` and, for ``"sigmoid"``,
+    ``routed_scaling_factor``; ``experts_stacked``; ``dtype``,
+    ``param_dtype``, ``norm_router_dtype``)."""
+    config: Any
 
-    @nn.compact
-    def __call__(self, x, live):
+    def _leaves(self, shapes, count):
+        """One leaf per matrix per expert: a stacked array handed to the
+        conditional in slices would be sliced (copied) ahead of it, chosen
+        or not. They are drawn stacked, one draw a matrix kind, and cut into
+        leaves: a draw a leaf (192 of them) was two minutes of compiling the
+        seeded init."""
         cfg = self.config
-        b, s, d = x.shape
-        first, count = cfg.experts_held
-        f = cfg.moe_d_ff
-        flat = x.reshape(b * s, d)
-        router = self.param("router", nn.initializers.lecun_normal(),
-                            (d, cfg.n_routed_experts), jnp.float32)
-        bias = self.param("e_score_correction_bias",
-                          nn.initializers.normal(0.05),
-                          (cfg.n_routed_experts,), jnp.float32)
-        # one leaf per matrix per expert: a stacked array would be sliced
-        # (copied) ahead of the conditional below, chosen or not. They are
-        # drawn stacked, one draw a matrix kind, and cut into leaves: a draw
-        # a leaf (192 of them) was two minutes of compiling the seeded init
-        shapes = {"gate": (d, f), "up": (d, f), "down": (f, d)}
         drawn = {}
         if self.is_initializing():
             init = nn.initializers.lecun_normal(batch_axis=(0,))
@@ -574,11 +615,65 @@ class ExpertShare(nn.Module):
             return lambda _: (drawn[kind][e] if drawn else
                               jnp.zeros(shapes[kind], cfg.param_dtype))
 
-        experts = [tuple(self.param(f"expert_{e}_{kind}", cut(e, kind))
-                         for kind in shapes) for e in range(count)]
-        affinity = router_affinity(flat, router, cfg.norm_router_dtype)
-        gates = route(affinity, bias, cfg.n_experts_per_tok,
-                      cfg.routed_scaling_factor)[:, first:first + count]
+        return [tuple(self.param(f"expert_{e}_{kind}", cut(e, kind))
+                      for kind in shapes) for e in range(count)]
+
+    def _stacked(self, stack, xc, gates, hit, y):
+        """``y`` plus the held experts' terms from stacked weights ``stack =
+        (gate, up, down)``, each ``[count, ...]``. A few tokens (a decode
+        step): one conditional an expert, so that an expert nobody chose is
+        not read. ``DENSE_TOKENS`` or more (a prefill, where every expert is
+        chosen by some token): one loop body for all experts, each run over
+        every token with its gates (0 where it was not chosen), which
+        compiles to a thirty-sixth of the code."""
+        if xc.shape[0] >= DENSE_TOKENS:
+            return jax.lax.scan(
+                lambda y, one: (y + _expert_term(xc, *one), None), y,
+                stack + (gates.T,))[0]
+        for e in range(gates.shape[1]):
+            y = y + jax.lax.cond(
+                hit[e], lambda g, u, dn, gate, e=e: _expert_term(
+                    xc, g[e], u[e], dn[e], gate),
+                lambda *_: jnp.zeros_like(y), *stack, gates[:, e])
+        return y
+
+    @nn.compact
+    def __call__(self, x, live):
+        cfg = self.config
+        b, s, d = x.shape
+        first, count = cfg.experts_held
+        f = cfg.moe_d_ff
+        flat = x.reshape(b * s, d)
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (d, cfg.n_routed_experts), jnp.float32)
+        if cfg.scoring_func not in SCORING:
+            raise ValueError(f"scoring_func {cfg.scoring_func!r} is not one "
+                             f"of {SCORING}")
+        sigmoid = cfg.scoring_func == "sigmoid"
+        if sigmoid:
+            bias = self.param("e_score_correction_bias",
+                              nn.initializers.normal(0.05),
+                              (cfg.n_routed_experts,), jnp.float32)
+        shapes = {"gate": (d, f), "up": (d, f), "down": (f, d)}
+        if cfg.experts_stacked:
+            # one leaf per matrix kind, ``[count, ...]``: an expert's slice is
+            # taken inside its conditional, where the chip's compiler fuses
+            # it into the matmul that reads it
+            init = nn.initializers.lecun_normal(batch_axis=(0,))
+            stack = tuple(self.param(f"experts_{kind}", init, (count,) + shape,
+                                     cfg.param_dtype)
+                          for kind, shape in shapes.items())
+            experts = None
+        else:
+            experts = self._leaves(shapes, count)
+        if sigmoid:
+            affinity = router_affinity(flat, router, cfg.norm_router_dtype)
+            gates = route(affinity, bias, cfg.n_experts_per_tok,
+                          cfg.routed_scaling_factor)
+        else:  # what is sown below as the affinity is then the logits
+            affinity = router_logits(flat, router, cfg.norm_router_dtype)
+            gates = route_softmax_topk(affinity, cfg.n_experts_per_tok)
+        gates = gates[:, first:first + count]
         if live is not None:  # a retired row routes nowhere
             gates = gates * jnp.repeat(live, s)[:, None]
         hit = jnp.any(gates > 0, axis=0)  # [count]
@@ -592,19 +687,18 @@ class ExpertShare(nn.Module):
         stats.value = stats.value + jnp.stack(
             [jnp.sum(hit), jnp.sum(gates > 0)]).astype(jnp.int32)
 
-        y = SwiGLU(cfg, f, name="shared_expert")(flat).astype(jnp.float32)
+        y = SwiGLU(cfg, cfg.shared_d_ff, name="shared_expert")(flat).astype(
+            jnp.float32)
         xc = flat.astype(cfg.dtype)
-        for e, (w_gate, w_up, w_down) in enumerate(experts):
-            def run(w_gate, w_up, w_down, gate):
-                h = jax.nn.silu(xc @ w_gate.astype(cfg.dtype)) * (
-                    xc @ w_up.astype(cfg.dtype))
-                out = (h @ w_down.astype(cfg.dtype)).astype(jnp.float32)
-                return out * gate[:, None]
-            # an expert nobody chose is not run: its weights are not read
-            y = y + jax.lax.cond(
-                hit[e], run,
-                lambda *_: jnp.zeros((b * s, d), jnp.float32),
-                w_gate, w_up, w_down, gates[:, e])
+        if experts is None:
+            y = self._stacked(stack, xc, gates, hit, y)
+        else:
+            for e, (w_gate, w_up, w_down) in enumerate(experts):
+                # an expert nobody chose is not run: its weights are not read
+                y = y + jax.lax.cond(
+                    hit[e], lambda *one: _expert_term(xc, *one),
+                    lambda *_: jnp.zeros((b * s, d), jnp.float32),
+                    w_gate, w_up, w_down, gates[:, e])
         return y.astype(x.dtype).reshape(b, s, d)
 
 

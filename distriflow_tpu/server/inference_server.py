@@ -339,9 +339,26 @@ class InferenceServer:
         # next insert/decode dispatch re-uploads it, so a retired slot's
         # frozen writes can never land in a page the pool has re-issued.
         self._paged = self.serving.kv_layout == "paged"
-        # a cache leaf every layer of either family holds: the one whose
+        family = decode_family(config)
+        # a cache leaf some layer of every family holds: the one whose
         # buffer says whether a donating call took the pools over
-        self._pool_leaf = decode_family(config).pool_leaves[0]
+        self._pool_leaf = family.pool_leaves[0]
+        # per-row state leaves (a recurrent family's): a row's cache is then
+        # more than its pages, so a prefix hit cannot rebuild it and a
+        # speculative round cannot roll it back
+        self._slot_leaves = family.slot_leaves
+        if self.serving.speculate_k and not family.prefix_reusable:
+            raise ValueError(
+                f"speculate_k={self.serving.speculate_k} with "
+                f"{type(config).__name__}: its cache holds per-row state "
+                f"({', '.join(family.slot_leaves)}) that a rejected draft "
+                "token cannot be rolled out of by moving cache_index back; "
+                "serve this family with speculate_k=0")
+        #: whether admissions look prompts up in the prefix map; off for a
+        #: family that is not prefix_reusable, whatever the config asks
+        self._prefix_sharing = bool(
+            self._paged and self.serving.prefix_sharing
+            and family.prefix_reusable)
         self._pp = pages_per_slot(config.max_seq, self.serving.page_size)
         self._n_pages = self.serving.pool_pages(config.max_seq)
         self._pool = _PagePool(self._n_pages) if self._paged else None
@@ -505,6 +522,16 @@ class InferenceServer:
             self._m_rows_live = tel.counter(
                 "serving_sparse_rows_live_total",
                 help="of those, the rows that are live")
+        if self._slot_leaves:
+            self._m_ssm_rows = tel.counter(
+                "serving_ssm_rows_stepped_total",
+                help="rows whose recurrent state a decode step read and "
+                     "wrote, summed over decode steps (every state-space "
+                     "layer of a step steps the same rows)")
+            self._m_row_state = tel.gauge(
+                "serving_row_state_bytes",
+                help="bytes the per-row state leaves of the slot cache hold "
+                     "(all slots, every layer that keeps one)")
         # what the decode loop reads: ``params`` with every weight the
         # block consumes in ``config.dtype`` already cast (compute_view),
         # made here and at each set_params, never at a dispatch
@@ -710,7 +737,7 @@ class InferenceServer:
             "max_slots": self.serving.max_slots,
             "draining": self._draining,
             "page_size": self.serving.page_size,
-            "prefix_sharing": bool(paged and self.serving.prefix_sharing),
+            "prefix_sharing": self._prefix_sharing,
             "page_occupancy": (
                 self._pool.used_pages / self._n_pages) if paged else 0.0,
             "free_pages": self._pool.free_pages if paged else -1,
@@ -989,7 +1016,7 @@ class InferenceServer:
         the fleet router's affinity scoring, so the two can never drift
         (the golden-hash test pins the chain)."""
         shared: List[int] = []
-        if not self.serving.prefix_sharing:
+        if not self._prefix_sharing:
             return shared, []
         hashes = page_hashes(tokens, self.serving.page_size)
         for hj in hashes:
@@ -1225,6 +1252,11 @@ class InferenceServer:
                     else:
                         self._slot_cache = slot_cache(
                             self.config, self.params, self.serving.max_slots)
+                if self._slot_leaves:
+                    self._m_row_state.set(sum(
+                        leaf.nbytes for path, leaf in
+                        jax.tree_util.tree_leaves_with_path(self._slot_cache)
+                        if path[-1].key in self._slot_leaves))
             now = time_mod.monotonic()
             # group key: (prompt length, shared-prefix tokens) — rows with
             # the same plen but different prefix depths run different
@@ -1489,7 +1521,8 @@ class InferenceServer:
             if self._decode_work is not None:
                 work = self._decode_work(ctx, srv.decode_chunk,
                                          len(self._slot_req))
-                stats["sel_tokens"] = work["sel_tokens"]
+                if "sel_tokens" in work:  # a family with sparse attention
+                    stats["sel_tokens"] = work["sel_tokens"]
                 stats["rows_run"] = work["rows_run"]
         with self._prof.phase("decode_iter", **stats):
             sampling = bool((self._temps[active] > 0).any())
@@ -1542,15 +1575,18 @@ class InferenceServer:
             if "experts_hit" in stats:
                 sparse = {k: stats[k] for k in (
                     "ctx_tokens", "sel_tokens", "rows_run", "experts_hit",
-                    "local_assignments")}
+                    "local_assignments") if k in stats}
                 self._m_ctx_tokens.inc(stats["ctx_tokens"])
-                self._m_sel_tokens.inc(stats["sel_tokens"])
                 self._m_experts_run.inc(stats["experts_hit"])
                 self._m_assignments["yes"].inc(stats["local_assignments"])
                 self._m_assignments["no"].inc(
                     work["assignments"] - stats["local_assignments"])
-                self._m_rows_run.inc(stats["rows_run"])
-                self._m_rows_live.inc(len(active) * srv.decode_chunk)
+                if "sel_tokens" in stats:
+                    self._m_sel_tokens.inc(stats["sel_tokens"])
+                    self._m_rows_run.inc(stats["rows_run"])
+                    self._m_rows_live.inc(len(active) * srv.decode_chunk)
+                if self._slot_leaves:
+                    self._m_ssm_rows.inc(stats["rows_run"])
             self.decode_batches += 1
             self._m_batches.inc()
             self._tok = tok
