@@ -14,6 +14,8 @@ seed:
 
 1. **kernels** — every Pallas kernel on that path, compiled by Mosaic, against
    the pure-XLA oracle the repo already has, at the shapes the smoke uses;
+   and the grouped expert matmul of the third model family's decode step
+   (``ops/expert_grouped.py``) at that family's widths;
 2. **train** — a few ``step()`` calls and three ``step_many`` dispatches via
    ``run_chunked`` on the seeded Markov corpus; finite falling loss, no
    compilation after each warm-up dispatch, the Mosaic custom calls present
@@ -75,6 +77,8 @@ class Size:
     # the two-kernel attention backward runs past 8 KV blocks at the tile cap
     # (1024 for 2-byte inputs, 256 for f32): (S, dtype name)
     long_attention: Tuple[int, str]
+    # the grouped expert matmul: (tokens, d, f, experts held, live tokens)
+    experts: Tuple[int, int, int, int, int]
 
 
 FULL = Size(
@@ -83,6 +87,7 @@ FULL = Size(
     greedy=((100, 32), (384, 64), (384, 64), (640, 32), (1024, 64),
             (1500, 32)),
     shared_prefix=256, side_prompt=100, long_attention=(16384, "bfloat16"),
+    experts=(32, 4096, 768, 36, 5),  # granite-4.0-h-small's, a decode step
 )
 # same code, toy dims: the interpreter is slow and the test suite has a budget
 REHEARSAL = Size(
@@ -90,6 +95,7 @@ REHEARSAL = Size(
     seq=128, batch_per_device=2, lr=3e-3, serve_max_seq=512,
     greedy=((24, 8), (300, 16), (300, 16), (96, 8), (160, 16), (380, 8)),
     shared_prefix=256, side_prompt=40, long_attention=(2304, "float32"),
+    experts=(16, 128, 256, 6, 2),
 )
 
 SINGLE_STEPS = 4          # trainer.step() calls
@@ -167,7 +173,8 @@ def mosaic_kernels(compiled_text: str) -> Dict[str, List[str]]:
             continue
         op = re.search(r'op_name="([^"]*)"', line)
         for name in re.findall(r"[A-Za-z_0-9]+", op.group(1) if op else ""):
-            if name.startswith(("flash_", "fused_ce_", "depthwise_gn_")):
+            if name.startswith(("flash_", "fused_ce_", "depthwise_gn_",
+                                "grouped_expert_")):
                 found.setdefault(name, []).append(line)
     return found
 
@@ -370,6 +377,52 @@ def check_decode(cfg: Any, page_size: int, max_slots: int, paged: bool,
         failures.append(f"flash-decode {label}: {err:.3e} > {tol:.3e}")
 
 
+def check_grouped_experts(t: int, d: int, f: int, count: int, live: int,
+                          dtype: Any, failures: List[str]) -> None:
+    """The grouped expert matmul vs the summed per-expert MLPs in float32:
+    ``live`` of ``t`` tokens choose 3 experts each, and an expert nobody
+    chose holds NaN, which the kernel must not read into its sum."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distriflow_tpu.models.latent_sparse import _expert_term
+    from distriflow_tpu.ops import default_interpret
+    from distriflow_tpu.ops.expert_grouped import grouped_expert_terms
+
+    keys = jax.random.split(jax.random.PRNGKey(d + f), 4)
+    xc = jax.random.normal(keys[0], (t, d), jnp.float32).astype(dtype)
+    stack = [(jax.random.normal(key, (count,) + shape, jnp.float32)
+              / shape[0] ** 0.5).astype(dtype) for key, shape in zip(
+                  keys[1:], ((d, f), (d, f), (f, d)))]
+    rng = np.random.RandomState(count)
+    gates = np.zeros((t, count), np.float32)
+    for row in range(live):
+        gates[row, rng.choice(count, 3, replace=False)] = rng.dirichlet(
+            np.ones(3))
+    unhit = np.flatnonzero(~(gates > 0).any(axis=0))
+    check(0 < len(unhit) < count, "the check needs experts hit and not hit")
+    poisoned = [stack[0].at[unhit[0]].set(jnp.nan)] + stack[1:]
+    compiled = jax.jit(grouped_expert_terms).lower(
+        xc, jnp.asarray(gates), *poisoned).compile()
+    if not default_interpret():
+        check("grouped_expert_terms" in mosaic_kernels(compiled.as_text()),
+              "grouped expert matmul: no Mosaic call")
+    got = compiled(xc, jnp.asarray(gates), *poisoned)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda x, g, *ws: sum(
+            _expert_term(x, *(w[e] for w in ws), g[:, e])
+            for e in range(count)))(
+                xc.astype(jnp.float32), jnp.asarray(gates),
+                *(w.astype(jnp.float32) for w in stack))
+    err, tol = rel_err(got, want), eps_tol(dtype, FWD_EPS_MULTIPLE)
+    say(f"  grouped expert matmul [{t} tokens, {count - len(unhit)} of "
+        f"{count} experts hit, {d} x {f}] {jnp.dtype(dtype).name}: err "
+        f"{err:.2e} (tol {tol:.2e})")
+    if err > tol:
+        failures.append(f"grouped expert matmul: {err:.3e} > {tol:.3e}")
+
+
 def phase_kernels(size: Size, serve_cfg: Any, serving: Any) -> None:
     import jax.numpy as jnp
 
@@ -388,6 +441,7 @@ def phase_kernels(size: Size, serve_cfg: Any, serving: Any) -> None:
     int8_cfg = dataclasses.replace(serve_cfg, kv_cache_dtype="int8_force")
     for cfg, paged in ((serve_cfg, True), (int8_cfg, True), (serve_cfg, False)):
         check_decode(cfg, serving.page_size, serving.max_slots, paged, failures)
+    check_grouped_experts(*size.experts, serve_cfg.dtype, failures)
     check(not failures, "kernels disagree with their oracles: "
           + "; ".join(failures))
 

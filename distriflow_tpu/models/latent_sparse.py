@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from distriflow_tpu.models.generate import DecodeFamily
+from distriflow_tpu.ops.expert_grouped import grouped_expert_terms
 
 NEG = -1e30
 
@@ -618,24 +619,20 @@ class ExpertShare(nn.Module):
         return [tuple(self.param(f"expert_{e}_{kind}", cut(e, kind))
                       for kind in shapes) for e in range(count)]
 
-    def _stacked(self, stack, xc, gates, hit, y):
+    def _stacked(self, stack, xc, gates, y):
         """``y`` plus the held experts' terms from stacked weights ``stack =
         (gate, up, down)``, each ``[count, ...]``. A few tokens (a decode
-        step): one conditional an expert, so that an expert nobody chose is
-        not read. ``DENSE_TOKENS`` or more (a prefill, where every expert is
-        chosen by some token): one loop body for all experts, each run over
-        every token with its gates (0 where it was not chosen), which
-        compiles to a thirty-sixth of the code."""
+        step): one kernel that streams the experts some token chose back to
+        back, so that an expert nobody chose is not read
+        (``ops/expert_grouped.py``). ``DENSE_TOKENS`` or more (a prefill,
+        where every expert is chosen by some token): one loop body for all
+        experts, each run over every token with its gates (0 where it was
+        not chosen)."""
         if xc.shape[0] >= DENSE_TOKENS:
             return jax.lax.scan(
                 lambda y, one: (y + _expert_term(xc, *one), None), y,
                 stack + (gates.T,))[0]
-        for e in range(gates.shape[1]):
-            y = y + jax.lax.cond(
-                hit[e], lambda g, u, dn, gate, e=e: _expert_term(
-                    xc, g[e], u[e], dn[e], gate),
-                lambda *_: jnp.zeros_like(y), *stack, gates[:, e])
-        return y
+        return y + grouped_expert_terms(xc, gates, *stack)
 
     @nn.compact
     def __call__(self, x, live):
@@ -657,8 +654,7 @@ class ExpertShare(nn.Module):
         shapes = {"gate": (d, f), "up": (d, f), "down": (f, d)}
         if cfg.experts_stacked:
             # one leaf per matrix kind, ``[count, ...]``: an expert's slice is
-            # taken inside its conditional, where the chip's compiler fuses
-            # it into the matmul that reads it
+            # a block of the kernel's grid or a trip of the loop
             init = nn.initializers.lecun_normal(batch_axis=(0,))
             stack = tuple(self.param(f"experts_{kind}", init, (count,) + shape,
                                      cfg.param_dtype)
@@ -691,7 +687,7 @@ class ExpertShare(nn.Module):
             jnp.float32)
         xc = flat.astype(cfg.dtype)
         if experts is None:
-            y = self._stacked(stack, xc, gates, hit, y)
+            y = self._stacked(stack, xc, gates, y)
         else:
             for e, (w_gate, w_up, w_down) in enumerate(experts):
                 # an expert nobody chose is not run: its weights are not read

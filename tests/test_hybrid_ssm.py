@@ -367,13 +367,18 @@ def test_a_step_over_live_rows_steps_them_as_the_step_over_all(params, live):
 # -- the share and the router ------------------------------------------------------
 
 
-def test_the_two_shares_add_up_to_the_uncut_layer_of_the_reference():
+@pytest.mark.parametrize("tokens", [18, 72], ids=["kernel", "scan"])
+def test_the_two_shares_add_up_to_the_uncut_layer_of_the_reference(tokens):
     """Shares ``[0, 4)`` and ``[4, 8)`` of the expert layer, the shared MLP
-    counted once, against the reference's layer given all eight experts."""
+    counted once, against the reference's layer given all eight experts:
+    through the grouped kernel (fewer tokens than ``DENSE_TOKENS``) and
+    through the loop over all experts."""
+    assert (tokens < ls.DENSE_TOKENS) == (tokens == 18)
     cfg = dataclasses.replace(CFG, experts_held=(0, 8))
-    flat = jax.random.normal(jax.random.PRNGKey(0), (18, 64), jnp.float32)
+    flat = jax.random.normal(jax.random.PRNGKey(0), (tokens, 64), jnp.float32)
     ones = jnp.ones((64,))
-    u = ref.rms_norm(flat, ones).reshape(2, 9, 64)  # what a layer feeds its FFN
+    # what a layer feeds its FFN
+    u = ref.rms_norm(flat, ones).reshape(2, tokens // 2, 64)
     p = ExpertShare(cfg).init(jax.random.PRNGKey(1), u, None)["params"]
     shared = SwiGLU(cfg, cfg.shared_d_ff).apply(
         {"params": p["shared_expert"]}, u)
@@ -389,9 +394,35 @@ def test_the_two_shares_add_up_to_the_uncut_layer_of_the_reference():
     # the reference's uncut layer: x + r (MoE + SharedMLP)(RMSNorm(x)), r = 1
     uncut, _ = ref.ffn({"post_mixer_norm": {"scale": ones}, "mlp": p}, flat,
                        1.0, 3, 0, 8)
-    whole = flat + (shared + routed).reshape(18, 64)
+    whole = flat + (shared + routed).reshape(tokens, 64)
     assert float(jnp.abs(whole - uncut).max()) < 1e-5
     assert float(jnp.abs(routed).max()) > 0.05  # the routed part is not nothing
+
+
+@pytest.mark.parametrize("live", [None, (1, 0, 1)], ids=["all", "a_row_retired"])
+def test_the_stacked_share_of_a_few_tokens_is_the_loops(live):
+    """The same weights and tokens through the grouped kernel (a decode
+    step's few tokens) and, among other tokens that fill the call up to
+    ``DENSE_TOKENS``, through the loop over all experts: a token's result
+    does not depend on its company, and both count the same experts."""
+    few = jax.random.normal(jax.random.PRNGKey(2), (3, 2, 64), jnp.float32)
+    more = jax.random.normal(jax.random.PRNGKey(3), (3, 30, 64), jnp.float32)
+    both = jnp.concatenate([few, more], axis=1)
+    assert few.shape[0] * few.shape[1] < ls.DENSE_TOKENS <= both.size // 64
+    share = ExpertShare(CFG)
+    p = share.init(jax.random.PRNGKey(4), few, None)["params"]
+    rows = None if live is None else jnp.asarray(live, jnp.float32)
+    got, sown = share.apply({"params": p}, few, rows, mutable=["cache"])
+    want, _ = share.apply({"params": p}, both, rows, mutable=["cache"])
+    assert float(jnp.abs(got - want[:, :2]).max()) < 1e-6
+    assert float(jnp.abs(got - SwiGLU(CFG, CFG.shared_d_ff).apply(
+        {"params": p["shared_expert"]}, few)).max()) > 0.01
+    gates = ls.route_softmax_topk(ls.router_logits(
+        few.reshape(6, 64), p["router"]), CFG.n_experts_per_tok)[:, :4]
+    if live is not None:
+        gates = gates * jnp.repeat(rows, 2)[:, None]
+    assert sown["cache"]["expert_stats"].tolist() == [
+        int((gates > 0).any(axis=0).sum()), int((gates > 0).sum())]
 
 
 def test_router_takes_the_top_k_logits_and_softmaxes_the_chosen():

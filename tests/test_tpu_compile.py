@@ -1,5 +1,5 @@
-"""The decode kernels pass the TPU's own compiler at the serving cell's widths,
-and the engine's programs alias the KV pools there.
+"""The decode kernels and the grouped expert matmul pass the TPU's own compiler
+at their cells' widths, and the engine's programs alias the KV pools there.
 
 Interpret mode says a kernel computes the right thing; it does not say that
 Mosaic accepts it (an unaligned slice, too much VMEM, a scalar op the core
@@ -24,6 +24,7 @@ from distriflow_tpu.models.generate import (
     paged_cache,
 )
 from distriflow_tpu.models.transformer import TransformerConfig, transformer_lm
+from distriflow_tpu.ops.expert_grouped import grouped_expert_terms
 from distriflow_tpu.ops.flash_decode import flash_decode, flash_decode_paged
 
 pytestmark = pytest.mark.kernels
@@ -75,6 +76,22 @@ def test_decode_kernel_compiles_for_v5e(one_chip, layout, kv):
             return flash_decode(q, k, v, lens, *s, interpret=False)
         args = (q, pool, pool, lens, *scales)
     compiled = jax.jit(call).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_grouped_expert_kernel_compiles_for_v5e(one_chip):
+    """At granite-4.0-h-small's widths, a decode step's 32 rows: whole-expert
+    blocks, double-buffered, are 37.7 MB of VMEM, over the default limit."""
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    t, d, f, count = 32, 4096, 768, 36
+    compiled = jax.jit(
+        lambda *a: grouped_expert_terms(*a, interpret=False)).lower(
+            shape((t, d), jnp.bfloat16), shape((t, count), jnp.float32),
+            shape((count, d, f), jnp.bfloat16),
+            shape((count, d, f), jnp.bfloat16),
+            shape((count, f, d), jnp.bfloat16)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
