@@ -34,7 +34,11 @@ and sampling settings share every decode iteration:
   scattered into free slots in one dispatch;
 - **iteration**: one jit program advances ALL live rows ``decode_chunk``
   tokens; finished rows freeze to eos inside the scan exactly like the
-  solo path;
+  solo path. The loop runs one chunk ahead of the host: chunk n+1 is
+  enqueued on chunk n's outputs on the device (cache, last tokens, eos
+  flags) before the host fetches chunk n's tokens, so the launch and the
+  fetch overlap a running program; a request that arrives while a chunk
+  runs is admitted before the next one is enqueued (``_launch_at``);
 - **retirement**: rows that hit eos or their budget retire at the next
   chunk boundary and their caller is answered immediately — nobody waits
   for the slowest member of a "group", because there are no groups.
@@ -78,6 +82,7 @@ from collections import OrderedDict, deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from distriflow_tpu.analysis.witness import PoolWitness
@@ -120,6 +125,39 @@ from distriflow_tpu.utils.serialization import (
 # keep working unchanged.
 MAX_PROMPT_BATCH = 64  # refuse absurd wire batches before touching the device
 BATCH_WINDOW_S = 0.004  # collection window after the first idle-state request
+# how much earlier than the running chunk's expected end, beyond the launch's
+# own measured host time, the engine enqueues the next chunk
+# (``InferenceServer._launch_at``)
+LAUNCH_MARGIN_S = 0.001
+# the recent chunks and launches whose times time the next launch
+TIMING_WINDOW = 8
+
+
+@jax.jit
+def _merge_rows(tok, done, rows, host_tok, host_done):
+    """The decode loop's device state (each slot's last token and eos flag)
+    with ``rows`` taken from the host: the first token of a row admitted
+    since the state was made, ``done`` of a row retired since."""
+    return jnp.where(rows, host_tok, tok), jnp.where(rows, host_done, done)
+
+
+class _Chunk:
+    """One decode chunk the engine enqueued and has not yet fetched: the
+    rows it runs (each with the request it ran for, so a row retired and
+    re-admitted meanwhile is told apart), its ``decode_iter`` fields, its
+    outputs on the device, and the host clock at its launch."""
+
+    __slots__ = ("rows", "reqs", "stats", "work", "tok", "done", "toks",
+                 "counts", "t0", "td0", "td1", "overlapped")
+
+    def __init__(self, rows, reqs, stats, work, outs, counts, t0, td0, td1,
+                 overlapped):
+        self.rows, self.reqs = rows, reqs
+        self.stats, self.work = stats, work
+        self.tok, self.done, self.toks = outs
+        self.counts = counts
+        self.t0, self.td0, self.td1 = t0, td0, td1
+        self.overlapped = overlapped
 
 
 class _Request:
@@ -333,6 +371,32 @@ class InferenceServer:
         self._slot_req: List[Optional[_Request]] = [None] * s
         self._slot_row = np.zeros((s,), np.int32)
         self._slot_emitted = np.zeros((s,), np.int64)
+        # the decode loop one chunk ahead of the host (``_decode_iteration``):
+        # the chunk enqueued and not yet fetched, the tokens and eos flags
+        # the next chunk reads (device arrays, a chunk's own outputs), the
+        # slots whose ``_tok``/``_done`` the host changed since those were
+        # made, and the device copies of the rows' sampling settings (None:
+        # upload before the next chunk). ``_tok``/``_done`` are then the
+        # host's staging for those slots; the speculative path reads them
+        # whole, as it always did.
+        self._flight: Optional[_Chunk] = None
+        self._loop_dev: Optional[Tuple[Any, Any]] = None
+        self._host_rows = np.zeros((s,), bool)
+        self._settings_dev: Optional[Tuple[Any, ...]] = None
+        # the error a chunk's fetch raised: the cache descends from its
+        # outputs, so the next _drop_dead_cache drops it
+        self._lost_flight: Optional[Exception] = None
+        # what times the next launch: the last few chunks' device times as
+        # the host saw them and the last few launches' host times; when the
+        # host last held a chunk's tokens; and when, while a chunk runs, the
+        # next is enqueued (at once, 0, before a chunk has been timed)
+        self._chunk_times: Deque[float] = deque(maxlen=TIMING_WINDOW)
+        self._launch_times: Deque[float] = deque(maxlen=TIMING_WINDOW)
+        self._last_fetch_t = 0.0
+        self._launch_at = 0.0
+        #: False: each chunk is fetched before the next is enqueued, the
+        #: strict order the pipelined loop is held to in the tests
+        self._lookahead = True
         # paged KV layout (round 9; kv_layout="slab" keeps the legacy
         # worst-case slabs as the bit-identity oracle). The host owns the
         # authoritative page table; every mutation marks it dirty and the
@@ -416,6 +480,10 @@ class InferenceServer:
         self._m_batches = tel.counter(
             "serving_decode_batches_total",
             help="decode batches dispatched by the engine loop")
+        self._m_overlapped = tel.counter(
+            "serving_decode_overlapped_total",
+            help="of those, the batches enqueued while the one before was "
+                 "still in flight (its tokens not yet fetched)")
         self._m_admitted = tel.counter(
             "serving_batched_requests_total",
             help="requests admitted into a decode slot")
@@ -935,6 +1003,9 @@ class InferenceServer:
         idle, with a short collection window so concurrent arrivals share
         the first admission; non-blocking between iterations), admit into
         free slots, advance every live row one ``decode_chunk``, retire.
+        While a chunk runs, the next is enqueued only once its launch is
+        due (``_launch_at``): a request that arrives before then is
+        admitted first, its prefill queued right behind the running chunk.
         On shutdown every waiter — queued, backlogged, or mid-decode — is
         errored; nobody is left to the 600 s backstop."""
         while True:
@@ -943,7 +1014,10 @@ class InferenceServer:
                     self._shutdown_engine()
                     return
                 self._admit()
-                if any(r is not None for r in self._slot_req):
+                if ((self._flight is not None
+                        or any(r is not None for r in self._slot_req))
+                        and (self._flight is None
+                             or time_mod.monotonic() >= self._launch_at)):
                     self._decode_iteration()
             except Exception as e:  # device failure: fail loud, stay up
                 self.logger.log(f"engine error: {e!r}")
@@ -952,7 +1026,8 @@ class InferenceServer:
 
     def _gather(self) -> bool:
         """Queue -> backlog. Returns True on the shutdown sentinel."""
-        idle = not self._backlog and all(r is None for r in self._slot_req)
+        idle = (not self._backlog and self._flight is None
+                and all(r is None for r in self._slot_req))
         if idle:
             # phase("gather"): the engine has no work, so a chip idle under
             # it waits for traffic, not for the scheduler
@@ -979,15 +1054,39 @@ class InferenceServer:
                     if nxt is None:
                         return True
                     self._backlog.append(nxt)
+        stop, pulled = self._drain()
+        if stop:
+            return True
+        wait = self._launch_at - time_mod.monotonic()
+        if pulled or self._flight is None or wait <= 0:
+            return False
+        # a chunk runs and nothing new waits: wait for traffic until the
+        # next chunk's launch is due, so that a request that comes first is
+        # admitted ahead of that chunk and not behind it
+        with self._prof.phase("launch_wait"):
+            try:
+                nxt = self._queue.get(timeout=wait)
+            except queue_mod.Empty:
+                return False
+        if nxt is None:
+            return True
+        self._backlog.append(nxt)
+        return self._drain()[0]
+
+    def _drain(self) -> Tuple[bool, int]:
+        """Queue -> backlog without waiting: (shutdown sentinel seen, how
+        many requests came)."""
+        pulled = 0
         with self._prof.phase("drain"):
             while True:
                 try:
                     nxt = self._queue.get_nowait()
                 except queue_mod.Empty:
-                    return False
+                    return False, pulled
                 if nxt is None:
-                    return True
+                    return True, pulled
                 self._backlog.append(nxt)
+                pulled += 1
 
     # -- paged-layout bookkeeping (scheduler thread only) ------------------
 
@@ -1184,14 +1283,19 @@ class InferenceServer:
         (its pages' contents went with the pool) and drop both caches so
         the next admission allocates fresh ones. An error raised before
         execution (trace, compile, argument check) leaves the pools alive
-        and this a no-op. Returns whether the caches were dropped."""
+        and this a no-op. A decode chunk whose fetch raised
+        (``_lost_flight``) lost them too: every later program read its
+        outputs. Returns whether the caches were dropped."""
         caches = (self._slot_cache, self._draft_cache)
-        if not any(c is not None
-                   and _find_cache_leaf(c, self._pool_leaf).is_deleted()
-                   for c in caches):
+        if self._lost_flight is None and not any(
+                c is not None
+                and _find_cache_leaf(c, self._pool_leaf).is_deleted()
+                for c in caches):
             return False
         self.logger.log(f"engine error: KV pools lost to a failed call, "
                         f"re-allocating at the next admission: {err!r}")
+        self._lost_flight = None
+        self._flight = self._loop_dev = self._settings_dev = None
         with self._device_lock:
             self._slot_cache = self._draft_cache = None
         self._fail_residents(err)
@@ -1257,6 +1361,15 @@ class InferenceServer:
                     else:
                         self._slot_cache = slot_cache(
                             self.config, self.params, self.serving.max_slots)
+                    if not self._spec_k:
+                        # the decode loop's state on the device, made by the
+                        # one program that merges host rows into it: it
+                        # compiles here, at the first admission, not later
+                        tok, done = self._tok.copy(), self._done.copy()
+                        self._loop_dev = _merge_rows(
+                            jax.device_put(tok), jax.device_put(done),
+                            np.ones_like(done), tok, done)
+                        self._host_rows[:] = False
                 if self._slot_leaves:
                     self._m_row_state.set(sum(
                         leaf.nbytes for path, leaf in
@@ -1415,11 +1528,19 @@ class InferenceServer:
                         self._slot_cache = insert(
                             self._slot_cache, row_cache, slots,
                             np.int32(plen))
+            picked = pick_rows(logits, temps, top_ks, top_ps, seeds,
+                               np.full((bucket,), plen, np.int32))
+            # a chunk still in flight runs ahead of this prefill on the
+            # device: its tokens go out while the prefill runs
+            settled = self._settle_flight()
             with self._prof.phase("first_token_fetch"):
-                first = np.asarray(pick_rows(
-                    logits, temps, top_ks, top_ps, seeds,
-                    np.full((bucket,), plen, np.int32)))[:n]
+                first = np.asarray(picked)[:n]
+                # the device array goes inside a phase (``_settle``)
+                del picked
         pf1 = time_mod.monotonic()  # first tokens are on the host now
+        # the prefill's own time starts when the device is done with that
+        # chunk, as near as the host sees it: when it held the chunk's tokens
+        own0 = pf0 if settled is None else max(pf0, settled)
         if self._spec_k:
             # the draft prefills the FULL prompt: even when the target rode
             # shared prefix pages, the draft cache holds no KV for them
@@ -1467,7 +1588,7 @@ class InferenceServer:
                             ttft_ms=req.ttft_ms)
                     self._req_span(req, "admission", req.admit_t,
                                    (pf0 - req.admit_t) * 1000.0)
-                self._req_span(req, "prefill", pf0, (pf1 - pf0) * 1000.0,
+                self._req_span(req, "prefill", own0, (pf1 - own0) * 1000.0,
                                slot=s, row=row, plen=plen, shared=shared_len)
                 if self._paged:
                     plan = req.page_plan[row]
@@ -1484,6 +1605,8 @@ class InferenceServer:
                 self._eos[s] = eos[j]
                 hit_eos = req.eos >= 0 and int(first[j]) == req.eos
                 self._done[s] = hit_eos
+                self._host_rows[s] = True
+                self._settings_dev = None
                 self._m_tokens.inc()
                 out = np.asarray([first[j]], np.int32)
                 if hit_eos and req.n_tokens > 1:
@@ -1495,161 +1618,300 @@ class InferenceServer:
                 if req.n_tokens == 1 or hit_eos:
                     self._complete_row(s)
 
+    def _retire_cancelled(self) -> None:
+        """Cancelled rows retire before a dispatch, not after it."""
+        for s, req in enumerate(self._slot_req):
+            if req is not None and req.cancelled:
+                self._retire_slot(s)
+                self._finish_error(req, RuntimeError("client disconnected"))
+
     def _decode_iteration(self) -> None:
-        """Advance every live slot ``decode_chunk`` tokens in ONE device
-        dispatch, then retire finished/cancelled rows."""
+        """One tick of the decode loop: enqueue the next chunk over the live
+        rows, then fetch and emit the chunk that was running (``_flight``),
+        which the device finishes meanwhile. The next chunk reads the
+        running one's outputs (cache, tokens, eos flags) on the device and
+        runs the rows whose budget does not end inside the running one:
+        those rows' table rows sentinel now, so that a family's live-row
+        loops skip them, and their pages go back when they retire at the
+        fetch. A row that stops at eos inside the running chunk rides the
+        next one frozen, as a frozen row always did. A page a retirement
+        frees is handed out again only by a program enqueued after every chunk that
+        can still write it: each program that touches the cache takes the
+        one before's output, so the device runs them in the host's order.
+
+        Strict order (the running chunk fetched before anything is
+        enqueued) where a request waits, so that the chunk's retirements
+        free what it needs first; before a chunk has been timed; under
+        speculation (``_spec_iteration``); and with ``_lookahead`` off."""
+        if self._spec_k:
+            self._spec_iteration()
+            return
+        flight = self._flight
+        if flight is not None and (self._backlog or not self._chunk_times):
+            self._settle_flight()
+            return
         srv = self.serving
-        stats: Dict[str, int] = {}
+        chunk = srv.decode_chunk
+        tick0 = time_mod.monotonic()
+        stats: Dict[str, Any] = {}
+        work: Dict[str, int] = {}
         with self._prof.phase("decode_plan"):
-            active = [i for i, r in enumerate(self._slot_req) if r is not None]
-            # cancelled rows retire before the dispatch, not after it
-            for s in active:
-                req = self._slot_req[s]
-                if req.cancelled:
-                    self._retire_slot(s)
-                    self._finish_error(
-                        req, RuntimeError("client disconnected"))
-            active = [i for i, r in enumerate(self._slot_req)
-                      if r is not None]
-            if active:
-                sampling = bool((self._temps[active] > 0).any())
-                if self._spec_k:
-                    fns = _build_spec_fns(
-                        self.config, self.draft_config, self._spec_k,
-                        sampling)
-                else:
-                    _insert, _pick, decode = _build_slot_fns(
-                        self.config, srv.decode_chunk, sampling)
-            if active and not self._spec_k and self._prof.enabled:
+            self._retire_cancelled()
+            # the running chunk's rows whose budget ends inside it are not
+            # run again; the others go on from where it leaves them
+            ending, ahead = [], set()
+            for s, req in (zip(flight.rows, flight.reqs) if flight else ()):
+                if self._slot_req[s] is req:
+                    if self._slot_emitted[s] + chunk >= req.n_tokens:
+                        ending.append(s)
+                    else:
+                        ahead.add(s)
+            if ending and self._paged:
+                self._tables[ending, :] = self._n_pages
+                self._tables_dirty = True
+            rows = [s for s, r in enumerate(self._slot_req)
+                    if r is not None and s not in ending]
+            if rows:
+                sampling = bool((self._temps[rows] > 0).any())
+                _insert, _pick, decode = _build_slot_fns(
+                    self.config, chunk, sampling)
+            if rows and self._prof.enabled:
                 # what single-query attention reads this dispatch, on the
-                # annotation: live rows and their cached context (prompt
-                # plus emitted), so a trace reader needs no second clock
-                # for it; under the paged layout also the pages that
-                # context fills against the pages the kernel's grid spans
+                # annotation: the rows it runs and their cached context as
+                # launched (prompt plus emitted, plus what the running chunk
+                # adds), so a trace reader needs no second clock for it;
+                # under the paged layout also the pages that context fills
+                # against the pages the kernel's grid spans
                 ctx = [self._slot_req[s].prompt.shape[1]
-                       + int(self._slot_emitted[s]) for s in active]
-                stats = {"n_active": len(active), "ctx_tokens": sum(ctx)}
+                       + int(self._slot_emitted[s])
+                       + (chunk if s in ahead else 0) for s in rows]
+                stats = {"n_active": len(rows), "ctx_tokens": sum(ctx),
+                         "overlapped": int(flight is not None)}
                 if self._paged:
                     ps = srv.page_size
                     stats["live_pages"] = sum(-(-c // ps) for c in ctx)
                     stats["table_pages"] = len(self._slot_req) * self._pp
                 if self._decode_work is not None:
-                    work = self._decode_work(ctx, srv.decode_chunk,
-                                             len(self._slot_req))
+                    work = self._decode_work(ctx, chunk, len(self._slot_req))
                     if "sel_tokens" in work:  # a family with sparse attention
                         stats["sel_tokens"] = work["sel_tokens"]
                     stats["rows_run"] = work["rows_run"]
+        if not rows:
+            if self._settle_flight() is None:
+                self._m_slots.set(0)
+            return
+        if flight is None:
+            self._flight = self._launch(decode, rows, stats, work, tick0,
+                                        overlapped=False)
+        else:
+            # the chunk is enqueued, and the one before is fetched and
+            # emitted while the device runs it
+            with self._prof.phase("decode_iter", **flight.stats):
+                self._flight = self._launch(decode, rows, stats, work, tick0,
+                                            overlapped=True)
+                self._settle(flight)
+        if self._chunk_times:
+            # the chunk's expected end (from the fastest recent chunk: a
+            # fetch the host began late can only lengthen a reading), less
+            # the median launch and a margin. A request that arrives later
+            # waits one more chunk, so the lead is kept short: a launch that
+            # runs long idles the chip for what it overruns, no more
+            due = (max(self._flight.td1, self._last_fetch_t)
+                   + min(self._chunk_times))
+            launch = sorted(self._launch_times)[len(self._launch_times) // 2]
+            self._launch_at = due - launch - LAUNCH_MARGIN_S
+        if not self._lookahead:
+            self._settle_flight()
+
+    def _launch(self, decode: Any, rows: List[int], stats: Dict[str, Any],
+                work: Dict[str, int], t0: float, overlapped: bool) -> _Chunk:
+        """Enqueue one decode chunk over ``rows``, on the outputs of the
+        chunk before: first what the device lacks (``_push_rows``), then the
+        program. ``t0``, the tick's start, is where the launch's host time
+        is measured from."""
+        with self._device_lock:
+            if (self._loop_dev is None or self._host_rows.any()
+                    or self._settings_dev is None
+                    or (self._paged and self._tables_dirty)):
+                with self._prof.phase("table_push"):
+                    self._push_rows()
+            td0 = time_mod.monotonic()
+            with self._prof.phase("decode_dispatch"), \
+                    self._donating("decode", self._slot_cache):
+                self._slot_cache, tok, done, toks = decode(
+                    self._param_view, self._slot_cache, *self._loop_dev,
+                    *self._settings_dev)
+                self._loop_dev = (tok, done)
+            td1 = time_mod.monotonic()
+        self._launch_times.append(td1 - t0)
+        # the layers' counts so far, from this chunk's output cache: the
+        # next chunk consumes that cache but not these leaves (undonated)
+        counts = (self._work_leaves()
+                  if stats and self._decode_work is not None else [])
+        return _Chunk(rows, [self._slot_req[s] for s in rows], stats, work,
+                      (tok, done, toks), counts, t0, td0, td1, overlapped)
+
+    def _push_rows(self) -> None:
+        """Bring the device's per-slot state up to the host's before a
+        decode chunk: the page tables (a retired or ending row's sentinel),
+        the tokens and eos flags of the rows the host changed since the
+        state was made (admitted, retired) merged into it, and the rows'
+        sampling settings after an admission. Each array goes as a copy:
+        the host edits its own in place while a chunk may still run."""
+        if (self._paged and self._tables_dirty
+                and self._slot_cache is not None):
+            self._slot_cache = set_page_tables(
+                self._slot_cache, self._tables.copy())
+            self._tables_dirty = False
+        if self._loop_dev is None:
+            self._loop_dev = (jax.device_put(self._tok.copy()),
+                              jax.device_put(self._done.copy()))
+        elif self._host_rows.any():
+            self._loop_dev = _merge_rows(
+                *self._loop_dev, self._host_rows.copy(), self._tok.copy(),
+                self._done.copy())
+        self._host_rows[:] = False
+        if self._settings_dev is None:
+            self._settings_dev = tuple(jax.device_put(a.copy()) for a in (
+                self._temps, self._top_ks, self._top_ps, self._seeds,
+                self._eos))
+
+    def _settle_flight(self) -> Optional[float]:
+        """Fetch and emit the chunk in flight, if there is one, under its
+        own ``decode_iter``; returns when the host held its tokens."""
+        c, self._flight = self._flight, None
+        if c is None:
+            return None
+        with self._prof.phase("decode_iter", **c.stats):
+            return self._settle(c)
+
+    def _settle(self, c: _Chunk) -> float:
+        """Fetch chunk ``c``'s tokens (and its layers' counts, in the same
+        transfer), keep its books and emit its rows' tokens: each row still
+        held by the request it ran for takes what is left of its budget and
+        completes at eos or at its budget's end. Returns when the host held
+        the tokens."""
+        srv = self.serving
+        tf0 = time_mod.monotonic()
+        # done before the host asked: then it ended at tf0 at the latest
+        ended = c.toks.is_ready()
+        with self._prof.phase("token_fetch"):
+            try:
+                tok, done, toks, *counts = jax.device_get(
+                    [c.tok, c.done, c.toks] + c.counts)
+            except Exception as e:
+                self._lost_flight = e  # _drop_dead_cache drops the cache
+                raise
+            # the chunk's device arrays go here, inside a phase: freeing
+            # one lets other threads take the interpreter for a while
+            c.tok = c.done = c.toks = None
+            c.counts = []
+        t1 = time_mod.monotonic()
+        # the host's books between the fetch and the first token out
+        with self._prof.phase("decode_account"):
+            stats = dict(c.stats)
+            if counts:
+                seen = np.sum(counts, axis=0, dtype=np.int64)
+                run_now, local = seen - self._work_seen
+                self._work_seen = seen
+                stats.update(experts_hit=int(run_now),
+                             local_assignments=int(local))
+            # the chunk's own time runs from its launch, or from when the
+            # host held the chunk before it if that came later (the device
+            # ran that one first)
+            start = max(c.td1, self._last_fetch_t)
+            self._time_chunk((tf0 if ended else t1) - start, ended)
+            span0 = max(c.t0, self._last_fetch_t)
+            self._last_fetch_t = t1
+            elapsed_ms = (t1 - span0) * 1000.0
+            dispatch_ms = round((c.td1 - c.td0) * 1000.0, 3)
+            fetch_ms = round((t1 - tf0) * 1000.0, 3)
+            sparse = {}
+            if "experts_hit" in stats:
+                sparse = {k: stats[k] for k in (
+                    "ctx_tokens", "sel_tokens", "rows_run", "experts_hit",
+                    "local_assignments") if k in stats}
+                self._m_ctx_tokens.inc(stats["ctx_tokens"])
+                self._m_experts_run.inc(stats["experts_hit"])
+                self._m_assignments["yes"].inc(stats["local_assignments"])
+                self._m_assignments["no"].inc(
+                    c.work["assignments"] - stats["local_assignments"])
+                if "sel_tokens" in stats:
+                    self._m_sel_tokens.inc(stats["sel_tokens"])
+                    self._m_rows_run.inc(stats["rows_run"])
+                    self._m_rows_live.inc(len(c.rows) * srv.decode_chunk)
+                if self._slot_leaves:
+                    self._m_ssm_rows.inc(stats["rows_run"])
+            self.decode_batches += 1
+            self._m_batches.inc()
+            if c.overlapped:
+                self._m_overlapped.inc()
+        with self._prof.phase("emit"):
+            emitted_now = 0
+            for s, req in zip(c.rows, c.reqs):
+                if self._slot_req[s] is not req:
+                    continue  # retired since the launch: cancelled, or eos
+                row = int(self._slot_row[s])
+                have = int(self._slot_emitted[s])
+                take = min(srv.decode_chunk, req.n_tokens - have)
+                chunk_toks = toks[s, :take].astype(np.int32)
+                emitted_now += take
+                self._slot_emitted[s] = have + take
+                # per-slot decode-interval TPOT (satellite 1): time since
+                # THIS slot last emitted, per token it emitted now — the
+                # old batch-level observe divided one dispatch across all
+                # active slots and conflated every co-resident request
+                if take > 0:
+                    self._m_tpot[req.tier].observe(
+                        (t1 - self._slot_emit_t[s]) * 1000.0 / take)
+                self._slot_emit_t[s] = t1
+                self._req_span(req, "decode_iter", span0, elapsed_ms,
+                               slot=s, n_active=len(c.rows), take=take,
+                               dispatch_ms=dispatch_ms, fetch_ms=fetch_ms,
+                               overlapped=int(c.overlapped), **sparse)
+                req.rows_out[row] = np.concatenate(
+                    [req.rows_out[row], chunk_toks])
+                if done[s]:
+                    # row froze to eos inside the scan; pad the remaining
+                    # budget with eos — bit-identical to the solo path's
+                    # frozen-row output — and answer the caller NOW
+                    pad = req.n_tokens - have - take
+                    if pad:
+                        req.rows_out[row] = np.concatenate([
+                            req.rows_out[row],
+                            np.full((pad,), req.eos, np.int32)])
+                    self._complete_row(s)
+                elif have + take >= req.n_tokens:
+                    self._complete_row(s)
+            self._m_tokens.inc(emitted_now)
+            self._m_slots.set(
+                sum(1 for r in self._slot_req if r is not None))
+        return t1
+
+    def _time_chunk(self, took: float, ended: bool) -> None:
+        """Keep one chunk's time as the host saw it. A chunk that had ended
+        before the host asked for its tokens gives an upper bound only: it
+        may lower the estimate, never raise it, or a host that comes late
+        lengthens every later chunk's wait."""
+        if (not ended or not self._chunk_times
+                or took < min(self._chunk_times)):
+            self._chunk_times.append(took)
+
+    def _spec_iteration(self) -> None:
+        """The speculative path's tick, in strict order: cancelled rows
+        retire, then one ``_spec_round`` over the live ones."""
+        with self._prof.phase("decode_plan"):
+            self._retire_cancelled()
+            active = [i for i, r in enumerate(self._slot_req)
+                      if r is not None]
+            if active:
+                sampling = bool((self._temps[active] > 0).any())
+                fns = _build_spec_fns(
+                    self.config, self.draft_config, self._spec_k, sampling)
         if not active:
             self._m_slots.set(0)
             return
-        if self._spec_k:
-            self._spec_round(active, fns)
-            return
-        with self._prof.phase("decode_iter", **stats):
-            t0 = time_mod.monotonic()
-            with self._device_lock:
-                if (self._paged and self._tables_dirty
-                        and self._slot_cache is not None):
-                    # retired slots re-sentineled their table rows on the
-                    # host; push the table before dispatch so a frozen
-                    # row's continued appends drop instead of landing in
-                    # pages the pool may already have re-issued
-                    with self._prof.phase("table_push"):
-                        self._slot_cache = set_page_tables(
-                            self._slot_cache, self._tables.copy())
-                    self._tables_dirty = False
-                td0 = time_mod.monotonic()
-                with self._prof.phase("decode_dispatch"), \
-                        self._donating("decode", self._slot_cache):
-                    self._slot_cache, tok, done, toks = decode(
-                        self._param_view, self._slot_cache, self._tok,
-                        self._done, self._temps, self._top_ks, self._top_ps,
-                        self._seeds, self._eos)
-                td1 = time_mod.monotonic()
-                with self._prof.phase("token_fetch"):
-                    counts = (self._work_leaves()
-                              if stats and self._decode_work is not None
-                              else [])
-                    if counts:  # the layers' counts ride with the tokens
-                        tok, done, toks, *counts = jax.device_get(
-                            [tok, done, toks] + counts)
-                    # np.array, not np.asarray: device outputs arrive as
-                    # read-only views, and the slot state is mutated in
-                    # place below
-                    tok = np.array(tok)
-                    done = np.array(done)
-                    toks = np.array(toks)
-            t1 = time_mod.monotonic()
-            # the host's books between the fetch and the first token out
-            with self._prof.phase("decode_account"):
-                if counts:
-                    seen = np.sum(counts, axis=0, dtype=np.int64)
-                    run_now, local = seen - self._work_seen
-                    self._work_seen = seen
-                    stats.update(experts_hit=int(run_now),
-                                 local_assignments=int(local))
-                elapsed_ms = (t1 - t0) * 1000.0
-                dispatch_ms = round((td1 - td0) * 1000.0, 3)
-                fetch_ms = round((t1 - td1) * 1000.0, 3)
-                sparse = {}
-                if "experts_hit" in stats:
-                    sparse = {k: stats[k] for k in (
-                        "ctx_tokens", "sel_tokens", "rows_run", "experts_hit",
-                        "local_assignments") if k in stats}
-                    self._m_ctx_tokens.inc(stats["ctx_tokens"])
-                    self._m_experts_run.inc(stats["experts_hit"])
-                    self._m_assignments["yes"].inc(stats["local_assignments"])
-                    self._m_assignments["no"].inc(
-                        work["assignments"] - stats["local_assignments"])
-                    if "sel_tokens" in stats:
-                        self._m_sel_tokens.inc(stats["sel_tokens"])
-                        self._m_rows_run.inc(stats["rows_run"])
-                        self._m_rows_live.inc(len(active) * srv.decode_chunk)
-                    if self._slot_leaves:
-                        self._m_ssm_rows.inc(stats["rows_run"])
-                self.decode_batches += 1
-                self._m_batches.inc()
-                self._tok = tok
-                self._done = done
-            with self._prof.phase("emit"):
-                emitted_now = 0
-                for s in active:
-                    req = self._slot_req[s]
-                    row = int(self._slot_row[s])
-                    have = int(self._slot_emitted[s])
-                    take = min(srv.decode_chunk, req.n_tokens - have)
-                    chunk_toks = toks[s, :take].astype(np.int32)
-                    emitted_now += take
-                    self._slot_emitted[s] = have + take
-                    # per-slot decode-interval TPOT (satellite 1): time since
-                    # THIS slot last emitted, per token it emitted now — the
-                    # old batch-level observe divided one dispatch across all
-                    # active slots and conflated every co-resident request
-                    if take > 0:
-                        self._m_tpot[req.tier].observe(
-                            (t1 - self._slot_emit_t[s]) * 1000.0 / take)
-                    self._slot_emit_t[s] = t1
-                    self._req_span(req, "decode_iter", t0, elapsed_ms,
-                                   slot=s, n_active=len(active), take=take,
-                                   dispatch_ms=dispatch_ms, fetch_ms=fetch_ms,
-                                   **sparse)
-                    req.rows_out[row] = np.concatenate(
-                        [req.rows_out[row], chunk_toks])
-                    if done[s]:
-                        # row froze to eos inside the scan; pad the remaining
-                        # budget with eos — bit-identical to the solo path's
-                        # frozen-row output — and answer the caller NOW
-                        pad = req.n_tokens - have - take
-                        if pad:
-                            req.rows_out[row] = np.concatenate([
-                                req.rows_out[row],
-                                np.full((pad,), req.eos, np.int32)])
-                        self._complete_row(s)
-                    elif have + take >= req.n_tokens:
-                        self._complete_row(s)
-                self._m_tokens.inc(emitted_now)
-                self._m_slots.set(
-                    sum(1 for r in self._slot_req if r is not None))
+        self._spec_round(active, fns)
 
     def _spec_round(self, active: List[int], fns: Tuple[Any, ...]) -> None:
         """One speculative round over every live slot: draft k tokens,
@@ -1791,6 +2053,7 @@ class InferenceServer:
             req = self._slot_req[s]
             self._slot_req[s] = None
             self._done[s] = True
+            self._host_rows[s] = True
             self._temps[s] = 0.0
             self._eos[s] = -1
             if self._paged and (self._slot_pages[s] or self._draft_pages[s]):
@@ -1880,7 +2143,10 @@ class InferenceServer:
 
     def _abort_all(self, err: Exception) -> None:
         """Device failure mid-engine: error every waiter (active slots and
-        backlog) and reset slot state so the engine can keep serving."""
+        backlog) and reset slot state so the engine can keep serving. The
+        chunk in flight is forgotten, and the next one reads the host's
+        tokens, eos flags and settings afresh."""
+        self._flight = self._loop_dev = self._settings_dev = None
         self._fail_residents(err)
         while self._backlog:
             self._finish_error(self._backlog.popleft(), err)

@@ -112,10 +112,15 @@ def test_engine_phases_land_on_the_profilers_host_plane(tmp_path):
     assert len(events["decode_iter"]) == 4
     for inner, outer in (("prefill", "admission"), ("page_insert", "prefill"),
                          ("first_token_fetch", "prefill"),
-                         ("decode_dispatch", "decode_iter"),
                          ("token_fetch", "decode_iter"),
                          ("emit", "decode_iter"), ("retire", "emit")):
         assert _inside(events, inner, outer), (inner, outer)
+    # a request's first chunk is enqueued with nothing in flight, at the top
+    # of the loop; its second inside the first's decode_iter, before the
+    # first is fetched
+    nested = [d for d in events["decode_dispatch"]
+              if _inside({"a": [d], "b": events["decode_iter"]}, "a", "b")]
+    assert len(events["decode_dispatch"]) == 4 and len(nested) == 2
     # gather is the engine without work: no other phase is open inside it
     for name, rows in events.items():
         if name not in ("gather", "batch_window"):
@@ -123,8 +128,8 @@ def test_engine_phases_land_on_the_profilers_host_plane(tmp_path):
     # the dispatch's live rows and their cached context ride the event:
     # prompt 6 + the first token, then two more
     stats = [s for _, _, s in sorted(events["decode_iter"])]
-    assert [(s["n_active"], s["ctx_tokens"]) for s in stats] == [
-        (1, 7), (1, 9)] * 2
+    assert [(s["n_active"], s["ctx_tokens"], s["overlapped"])
+            for s in stats] == [(1, 7, 0), (1, 9, 1)] * 2
     # and the pages that context fills, against the 2 slots x 3 pages the
     # paged decode kernel's grid spans
     assert [(s["live_pages"], s["table_pages"]) for s in stats] == [(1, 6)] * 4
@@ -134,11 +139,16 @@ def test_engine_phases_land_on_the_profilers_host_plane(tmp_path):
     assert [w["event"] for w in waits] == ["generate"] * 3
     assert {w["trace_id"] for w in waits} == {
         r["trace_id"] for r in tel.tracer.finished("request")}
-    # and the request spans carry the dispatch's two parts, not the old share
+    # and the request spans carry the dispatch's two parts, not the old share;
+    # an overlapped chunk was launched before its span began (inside the
+    # span of the chunk before), so only its fetch lies in its own span
     spans = tel.tracer.finished("decode_iter")
-    assert spans and all(
+    assert {s["overlapped"] for s in spans} == {0, 1}
+    assert all(
         "share" not in s and s["dispatch_ms"] >= 0 and s["fetch_ms"] >= 0
-        and s["dispatch_ms"] + s["fetch_ms"] <= s["dur_ms"] + 1e-3
+        and s["fetch_ms"] <= s["dur_ms"] + 1e-3
+        and (s["overlapped"]
+             or s["dispatch_ms"] + s["fetch_ms"] <= s["dur_ms"] + 1e-3)
         for s in spans)
 
 
@@ -177,9 +187,13 @@ def test_decode_iter_stats_count_live_pages_only_while_profiling(profiling):
     finally:
         server.stop()
     if profiling:
+        # the server's first chunk has not been timed when the second is
+        # due: that one waits for the first's fetch, and is not overlapped
         assert seen == [
-            {"n_active": 1, "ctx_tokens": 16, "live_pages": 1, "table_pages": 6},
-            {"n_active": 1, "ctx_tokens": 18, "live_pages": 2, "table_pages": 6}]
+            {"n_active": 1, "ctx_tokens": 16, "overlapped": 0,
+             "live_pages": 1, "table_pages": 6},
+            {"n_active": 1, "ctx_tokens": 18, "overlapped": 0,
+             "live_pages": 2, "table_pages": 6}]
     else:
         assert seen == [{}, {}]
 
@@ -543,8 +557,11 @@ def test_leaf_phases_cover_the_engine_loop():
     names = {r[0] for r in rows}
     assert {"drain", "admit_plan", "prefill_plan", "prefill_account",
             "decode_plan", "decode_account"} <= names
+    # a decode_iter fetches and emits its chunk, having first enqueued the
+    # next one when there is one to run
     for it in (r for r in rows if r[0] == "decode_iter"):
         kids = [r[0] for r in rows
                 if r[3] == it[3] + 1 and it[1] <= r[1] and r[2] <= it[2]]
-        assert [k for k in kids if k != "table_push"] == [
-            "decode_dispatch", "token_fetch", "decode_account", "emit"], kids
+        assert [k for k in kids if k not in ("table_push", "decode_dispatch")
+                ] == ["token_fetch", "decode_account", "emit"], kids
+        assert kids[0] != "token_fetch" or "decode_dispatch" not in kids

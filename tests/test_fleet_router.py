@@ -313,13 +313,24 @@ def test_whole_fleet_drain_is_structured_refusal(fleet, params):
     assert router._tel.counter_value("router_requests_total", tier="1") == 1.0
 
 
-def test_faultplan_kill_mid_decode_exactly_once(fleet, params):
+def test_faultplan_kill_mid_decode_exactly_once(fleet, params, monkeypatch):
     """Seeded FaultPlan tears the router->A connection on A's 3rd
     forwarded generate, while A is mid-decode on the 2nd: both requests
     complete exactly once on survivor B with bit-identical output, and
     replaying a completed request_id against B returns the cached ack
     without re-entering the engine."""
     sa, sb, _ta, _tb, mk_router = fleet
+    # A fetches each decode chunk 10 ms late, so that its ~8 chunks keep
+    # it mid-decode until the scripted reset lands: at this size a chunk
+    # takes well under a millisecond, and the engine enqueues the next
+    # chunk before it fetches the running one
+    settle = sa._settle
+
+    def late_settle(chunk):
+        time.sleep(0.01)
+        return settle(chunk)
+
+    monkeypatch.setattr(sa, "_settle", late_settle)
     plan = FaultPlan(seed=13, schedule=[
         ScriptedFault(event="generate", nth=3, action="reset")])
     router = mk_router(policy="affinity", fault_plan_a=plan)
@@ -330,9 +341,9 @@ def test_faultplan_kill_mid_decode_exactly_once(fleet, params):
         c.generate(shared, 3)
         assert c.last_replica == "A"
         results = {}
-        # one shared page (17 tokens) leaves decode room for 31 tokens —
-        # ~8 engine dispatches keep A mid-decode long enough that the
-        # scripted reset reliably lands while this request is in flight
+        # one shared page (17 tokens) leaves decode room for 31 tokens:
+        # ~8 engine dispatches, each fetched late (above), keep A
+        # mid-decode while the scripted reset lands
         long_prompt = shared[:, :17]
 
         def long_decode():
