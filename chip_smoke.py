@@ -79,6 +79,9 @@ class Size:
     long_attention: Tuple[int, str]
     # the grouped expert matmul: (tokens, d, f, experts held, live tokens)
     experts: Tuple[int, int, int, int, int]
+    # the paged decode kernel at the serving cell's geometry: (heads,
+    # head_dim, page_size, rows, pages a row)
+    paged_decode: Tuple[int, int, int, int, int]
 
 
 FULL = Size(
@@ -88,6 +91,7 @@ FULL = Size(
             (1500, 32)),
     shared_prefix=256, side_prompt=100, long_attention=(16384, "bfloat16"),
     experts=(32, 4096, 768, 36, 5),  # granite-4.0-h-small's, a decode step
+    paged_decode=(16, 128, 128, 32, 16),  # serve-rate-mixed's (PERF.md §4)
 )
 # same code, toy dims: the interpreter is slow and the test suite has a budget
 REHEARSAL = Size(
@@ -96,6 +100,7 @@ REHEARSAL = Size(
     greedy=((24, 8), (300, 16), (300, 16), (96, 8), (160, 16), (380, 8)),
     shared_prefix=256, side_prompt=40, long_attention=(2304, "float32"),
     experts=(16, 128, 256, 6, 2),
+    paged_decode=(2, 64, 16, 8, 4),
 )
 
 SINGLE_STEPS = 4          # trainer.step() calls
@@ -377,6 +382,66 @@ def check_decode(cfg: Any, page_size: int, max_slots: int, paged: bool,
         failures.append(f"flash-decode {label}: {err:.3e} > {tol:.3e}")
 
 
+def check_paged_live_rows(h: int, d: int, page: int, rows: int, width: int,
+                          dtype: Any, failures: List[str]) -> None:
+    """``flash_decode_paged`` alone at the serving cell's geometry, whose
+    steps are the live pages: 1 to all ``rows`` live at ragged lengths
+    beside retired rows (all sentinels under a stale length), against
+    attention in float32 through the table. A retired row must read
+    zeros; the compiled call must be the Mosaic kernel of that name."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distriflow_tpu.ops import default_interpret
+    from distriflow_tpu.ops.flash_decode import flash_decode_paged
+
+    hd, n_pages = h * d, rows * width
+    keys = jax.random.split(jax.random.PRNGKey(hd + page), 3)
+    k, v = (jax.random.normal(key, (n_pages, page, hd), jnp.float32).astype(dtype)
+            for key in keys[:2])
+    q = jax.random.normal(keys[2], (rows, h, d), jnp.float32).astype(dtype)
+    compiled = jax.jit(flash_decode_paged).lower(
+        q, k, v, jnp.zeros((rows, width), jnp.int32),
+        jnp.zeros((rows,), jnp.int32)).compile()
+    if not default_interpret():
+        check(DECODE_KERNEL in mosaic_kernels(compiled.as_text()),
+              f"paged decode: no Mosaic call {DECODE_KERNEL!r}")
+    rng = np.random.RandomState(rows)
+    for n_live in sorted({min(n, rows) for n in (1, 5, 12, 32)}):
+        table = np.full((rows, width), n_pages, np.int32)
+        lens = np.full((rows,), width * page - 5, np.int32)  # stale
+        live = rng.permutation(rows)[:n_live]
+        order = rng.permutation(n_pages)
+        for i, row in enumerate(live):
+            lens[row] = rng.randint(1, width * page + 1)
+            used = min(-(-int(lens[row]) // page) + 1, width)
+            table[row, :used] = order[i * width:i * width + used]
+        got = np.asarray(compiled(q, k, v, jnp.asarray(table),
+                                  jnp.asarray(lens)), np.float32)
+        qf, kf, vf = (np.asarray(a, np.float32) for a in (q, k, v))
+        want = np.zeros_like(got)
+        for row in live:
+            n = int(lens[row])
+            pages = table[row, :-(-n // page)]
+            kr = kf[pages].reshape(-1, h, d)[:n]
+            vr = vf[pages].reshape(-1, h, d)[:n]
+            s = np.einsum("hd,shd->hs", qf[row], kr) / np.sqrt(d)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            want[row] = np.einsum("hs,shd->hd", p / p.sum(-1, keepdims=True), vr)
+        dead = np.setdiff1d(np.arange(rows), live)
+        check(not got[dead].any(), f"paged decode: a retired row is not zero "
+              f"({n_live} live)")
+        err = rel_err(got[live], want[live])
+        tol = eps_tol(dtype, DECODE_EPS_MULTIPLE)
+        say(f"  paged decode alone [{n_live} of {rows} rows live, {width} "
+            f"pages of {page}, {h} heads of {d}] {jnp.dtype(dtype).name}: "
+            f"err {err:.2e} (tol {tol:.2e})")
+        if err > tol:
+            failures.append(f"paged decode, {n_live} live: {err:.3e} > "
+                            f"{tol:.3e}")
+
+
 def check_grouped_experts(t: int, d: int, f: int, count: int, live: int,
                           dtype: Any, failures: List[str]) -> None:
     """The grouped expert matmul vs the summed per-expert MLPs in float32:
@@ -441,6 +506,7 @@ def phase_kernels(size: Size, serve_cfg: Any, serving: Any) -> None:
     int8_cfg = dataclasses.replace(serve_cfg, kv_cache_dtype="int8_force")
     for cfg, paged in ((serve_cfg, True), (int8_cfg, True), (serve_cfg, False)):
         check_decode(cfg, serving.page_size, serving.max_slots, paged, failures)
+    check_paged_live_rows(*size.paged_decode, serve_cfg.dtype, failures)
     check_grouped_experts(*size.experts, serve_cfg.dtype, failures)
     check(not failures, "kernels disagree with their oracles: "
           + "; ".join(failures))

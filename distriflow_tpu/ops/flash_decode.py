@@ -35,9 +35,12 @@ rides in as a scalar-prefetch operand: positions past the cache write
 index are masked, and a tile that lies wholly past it is neither
 computed (``pl.when``) nor fetched (past the row's last live tile the
 K/V index maps repeat that tile's block index, and a grid step whose
-block index did not change issues no DMA). The grid keeps its static
-extent, rows x tiles; a dead step costs its bookkeeping alone (0.12 us
-on a v5e, PERF.md §6 PR 26).
+block index did not change issues no DMA). The slab kernel's grid keeps
+its static extent, rows x tiles, where a dead step costs its bookkeeping
+alone (0.12 us on a v5e). The paged kernel's steps are the live pages
+alone, a work list of (row, page) items whose length is the grid's
+extent, and it takes the query full-lane, building each row's
+block-diagonal operand in VMEM (see the paged section below).
 
 **int8 cache support**: with ``k_scale``/``v_scale`` operands
 (``[B, S, H]`` f32, symmetric absmax per position x head), the scales
@@ -247,55 +250,59 @@ def _qk_scores(qbd_ref, k_tile, d):
         preferred_element_type=jnp.float32) * scale
 
 
-def _decode_kernel(len_ref, qbd_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, block_k, n_kv, h):
-    """One (row, tile) grid step, slab or paged alike: the two layouts
-    differ only in their index maps."""
-    j = pl.program_id(1)
-    row_len = len_ref[pl.program_id(0)]
-    _init_scratch(j, m_ref, l_ref, acc_ref)
-
-    @pl.when(j * block_k < row_len)
-    def _live():
-        d = k_ref.shape[-1] // h
-        s2 = _qk_scores(qbd_ref, k_ref[0].astype(jnp.bfloat16), d)
-        _attend_tile(row_len, v_ref[0].astype(jnp.bfloat16),
-                     m_ref, l_ref, acc_ref, j, block_k, h, s2)
-
-    _finalize(j, n_kv, o_ref, l_ref, acc_ref, h)
+def _kv_refs(refs, quant):
+    """(q scale, K, K scales, V, V scales) from a kernel's refs after the
+    query; the scales are None for a bf16/f32 cache."""
+    if quant:
+        return refs[:5]
+    k_ref, v_ref = refs[:2]
+    return None, k_ref, None, v_ref, None
 
 
-def _decode_kernel_quant(len_ref, qbd_ref, qs_ref, k_ref, ks_ref, v_ref,
-                         vs_ref, o_ref, m_ref, l_ref, acc_ref, *, block_k,
-                         n_kv, h):
-    """int8 tile update WITHOUT materializing dequantized K/V tiles.
-
-    Scores ride the native s8 MXU: ``qbd`` arrives pre-quantized
-    (per-head absmax int8, built by the caller), so ``K8 . Qbd8``
-    contracts int8 x int8 -> int32 with NO [BK, HD] cast copy of K — the
-    int8->bf16 relayout of both tiles was the single largest exposed
-    cost of the first packed int8 kernel (measured ~35 us/call at 4k on
-    v5e against a 41 us DMA floor). All three per-(position, head)
+def _tile_update(row_len, j, block_k, h, qbd_ref, kv, m_ref, l_ref,
+                 acc_ref):
+    """One live tile: every head's scores against the block-diagonal
+    query, then the online-softmax update; ``kv`` is :func:`_kv_refs`'s.
+    With K scales (int8 tiles) the scores ride the native s8 MXU without
+    a dequantized [BK, HD] tile: ``qbd`` is the per-head absmax int8
+    query, so ``K8 . Qbd8`` contracts int8 x int8 -> int32 with NO cast
+    copy of K — the int8->bf16 relayout of both tiles was the single
+    largest exposed cost of the first packed int8 kernel (measured ~35
+    us/call at 4k on v5e against a 41 us DMA floor). All three per-(position, head)
     scales (q, K, V) factor out of the D contraction and fold into the
     [BK, H] score/prob tensors. V still casts to bf16 for the PV matmul:
     quantizing the probabilities as well measured 3.6% error (the
     per-tile absmax under-resolves peaked softmax rows), so exact f32
     probabilities are kept and only V pays a cast."""
+    qs_ref, k_ref, ks_ref, v_ref, vs_ref = kv
+    d = k_ref.shape[-1] // h
+    if ks_ref is None:
+        s2 = _qk_scores(qbd_ref, k_ref[0].astype(jnp.bfloat16), d)
+        p_scale = None
+    else:
+        s_i32 = jax.lax.dot_general(
+            k_ref[0], qbd_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)  # [BK, H] on the s8 MXU
+        scale = 1.0 / (d ** 0.5)
+        s2 = s_i32.astype(jnp.float32) * ks_ref[0] * (qs_ref[0] * scale)
+        p_scale = vs_ref[0]
+    _attend_tile(row_len, v_ref[0].astype(jnp.bfloat16),
+                 m_ref, l_ref, acc_ref, j, block_k, h, s2, p_scale=p_scale)
+
+
+def _decode_kernel(len_ref, qbd_ref, *refs, block_k, n_kv, h, quant):
+    """One (row, tile) step of the slab grid; ``refs`` are the K/V tiles
+    (int8: q scale, K, K scales, V, V scales), the output and the
+    scratch."""
+    o_ref, m_ref, l_ref, acc_ref = refs[-4:]
     j = pl.program_id(1)
     row_len = len_ref[pl.program_id(0)]
     _init_scratch(j, m_ref, l_ref, acc_ref)
 
     @pl.when(j * block_k < row_len)
     def _live():
-        d = k_ref.shape[-1] // h
-        s_i32 = jax.lax.dot_general(
-            k_ref[0], qbd_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)  # [BK, H] on the s8 MXU
-        scale = 1.0 / (d ** 0.5)
-        s2 = s_i32.astype(jnp.float32) * ks_ref[0] * (qs_ref[0] * scale)
-        _attend_tile(row_len, v_ref[0].astype(jnp.bfloat16),
-                     m_ref, l_ref, acc_ref, j, block_k, h, s2,
-                     p_scale=vs_ref[0])
+        _tile_update(row_len, j, block_k, h, qbd_ref, _kv_refs(refs, quant),
+                     m_ref, l_ref, acc_ref)
 
     _finalize(j, n_kv, o_ref, l_ref, acc_ref, h)
 
@@ -391,20 +398,25 @@ def flash_decode(
     # the homogeneous static-batch callers keep their old semantics)
     lens = jnp.broadcast_to(
         jnp.reshape(valid_len.astype(jnp.int32), (-1,)), (b,))
-    # past a row's last live tile the block index repeats, and a grid
-    # step whose block index did not change issues no DMA
-    return _tiled_decode(
-        "flash_decode", q, (k, k_scale, v, v_scale), lens, block_k, n_kv,
-        lambda bi, j, lens, last: (bi, jnp.minimum(j, last[bi]), 0),
-        (), interpret)
+    return _tiled_decode(q, (k, k_scale, v, v_scale), lens, block_k, n_kv,
+                         interpret)
 
 
-def _tiled_decode(name, q, kv, lens, block_k, n_kv, kv_index, tables,
-                  interpret):
-    """The (row, tile) grid both layouts run: ``kv_index`` maps a grid
-    step to the K/V (and scale) block, given the scalar-prefetch refs
-    ``lens`` [B], ``last`` [B] (the row's last live tile) and then
-    ``tables``."""
+def _quantize_query(q):
+    """Per-head symmetric absmax int8 query, as integer-valued float32
+    ``q8`` [B, H, D], and its scales [B, 1, H] (the q scale folds into
+    the kernels' [BK, H] score multiply)."""
+    qf32 = q.astype(jnp.float32)
+    qs = jnp.max(jnp.abs(qf32), axis=-1, keepdims=True) / 127.0
+    qs = jnp.maximum(qs, 1e-20)  # [B, H, 1]
+    q8 = jnp.clip(jnp.round(qf32 / qs), -127, 127)
+    return q8, qs[:, :, 0][:, None, :]
+
+
+def _tiled_decode(q, kv, lens, block_k, n_kv, interpret):
+    """The slab's (row, tile) grid. Past a row's last live tile the K/V
+    block index repeats, and a grid step whose block index did not change
+    issues no DMA."""
     k, k_scale, v, v_scale = kv
     b, h, d = q.shape
     hd = h * d
@@ -414,53 +426,46 @@ def _tiled_decode(name, q, kv, lens, block_k, n_kv, kv_index, tables,
     # block-diagonal query [B, HD, H]: head h's query in rows h*D:(h+1)*D
     # of column h — the operand that turns all-head scores into ONE
     # matmul. The int8 path quantizes it per head (symmetric absmax) so
-    # the score contraction runs int8 x int8 on the MXU with no K cast;
-    # the q scale folds into the kernel's [BK, H] score multiply.
+    # the score contraction runs int8 x int8 on the MXU with no K cast.
     eye = jnp.eye(h, dtype=jnp.float32)
-    qf32 = q.astype(jnp.float32)
     if quant:
-        qs = jnp.max(jnp.abs(qf32), axis=-1, keepdims=True) / 127.0
-        qs = jnp.maximum(qs, 1e-20)  # [B, H, 1]
-        q8 = jnp.clip(jnp.round(qf32 / qs), -127, 127)
+        q8, qs_row = _quantize_query(q)
         qbd = jnp.einsum("bhd,hg->bhdg", q8, eye).reshape(
             b, hd, h).astype(jnp.int8)
-        qs_row = qs[:, :, 0][:, None, :]  # [B, 1, H]
     else:
-        qbd = jnp.einsum("bhd,hg->bhdg", qf32, eye).reshape(
+        qbd = jnp.einsum("bhd,hg->bhdg", q.astype(jnp.float32), eye).reshape(
             b, hd, h).astype(jnp.bfloat16)
 
     # index maps under PrefetchScalarGridSpec receive the scalar refs last
-    def per_row(bi, j, *scalars):
+    def per_row(bi, j, lens, last):
         return (bi, 0, 0)
+
+    def kv_index(bi, j, lens, last):
+        return (bi, jnp.minimum(j, last[bi]), 0)
 
     in_specs = [pl.BlockSpec((1, hd, h), per_row)]
     arrays = [qbd]
     if quant:
         in_specs.append(pl.BlockSpec((1, 1, h), per_row))
         arrays.append(qs_row)
-    in_specs.append(pl.BlockSpec((1, block_k, hd), kv_index))
-    arrays.append(k)
-    if quant:
-        in_specs.append(pl.BlockSpec((1, block_k, h), kv_index))
-        arrays.append(k_scale)
-    in_specs.append(pl.BlockSpec((1, block_k, hd), kv_index))
-    arrays.append(v)
-    if quant:
-        in_specs.append(pl.BlockSpec((1, block_k, h), kv_index))
-        arrays.append(v_scale)
+    for pool, scale in ((k, k_scale), (v, v_scale)):
+        in_specs.append(pl.BlockSpec((1, block_k, hd), kv_index))
+        arrays.append(pool)
+        if quant:
+            in_specs.append(pl.BlockSpec((1, block_k, h), kv_index))
+            arrays.append(scale)
 
-    body = functools.partial(
-        _decode_kernel_quant if quant else _decode_kernel,
-        block_k=block_k, n_kv=n_kv, h=h)
+    body = functools.partial(_decode_kernel, block_k=block_k, n_kv=n_kv,
+                             h=h, quant=quant)
 
     def kernel(len_ref, last_ref, *refs):
-        body(len_ref, *refs[len(tables):])  # the tables serve the index maps
+        body(len_ref, *refs)  # ``last`` serves the index maps
 
     out = pl.pallas_call(
         kernel,
-        name=name,
+        name="flash_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2 + len(tables),
+            num_scalar_prefetch=2,
             grid=(b, n_kv),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, 1, hd), per_row),
@@ -475,7 +480,7 @@ def _tiled_decode(name, q, kv, lens, block_k, n_kv, kv_index, tables,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lens, last, *tables, *arrays)
+    )(lens, last, *arrays)
     return out.reshape(b, h, d)
 
 
@@ -484,23 +489,31 @@ def _tiled_decode(name, q, kv, lens, block_k, n_kv, kv_index, tables,
 # The continuous-batching server's paged cache replaces the [B, S, H*D]
 # per-row slabs with ONE pool of fixed-size pages [n_pages, page_size,
 # H*D] plus a per-row page table: row bi's logical KV positions
-# [j*page_size, (j+1)*page_size) live in physical page table[bi, j]. The
-# kernel below is the same (row, tile) grid as :func:`flash_decode` with
-# block_k == page_size (:func:`_tiled_decode`) — the ONLY change is that
-# the K/V tile index maps dereference the page table (one more
-# scalar-prefetch operand) instead of striding contiguously.
+# [j*page_size, (j+1)*page_size) live in physical page table[bi, j].
 #
 # A row's live pages are read off the kernel's own two inputs: the table
 # says which pages exist (the entries before the first sentinel, an
 # entry >= n_pages), the length says how many positions are written, and
 # ``eff_len = min(valid_len, n_real * page_size)`` is what the kernel
-# runs on. Dead pages — past the length, or at and past the first
-# sentinel — are neither computed nor fetched: a slot that has reserved
-# its whole horizon pays for the pages it has written, and a retired
-# slot (all sentinels under a stale, still growing length) pays 16 bare
-# grid steps and writes zeros. Sentinels are still clamped to the last
-# real page so the index map is a plain table read; that page is
-# fetched at most once per run of dead rows and never computed on.
+# runs on. The kernel's steps are those pages and nothing more: one
+# (row, page) item a live page, in row-major, ascending-page order, and
+# one item for a row with no live page, so that it still writes its
+# zeros; :func:`_work_list` builds the list and ``eff_len`` from the
+# table and the lengths. The grid is one axis whose extent is the list's
+# length, a traced scalar; the K/V index maps read an item's page, the
+# query and output maps its row, the scratch is reset on a row's first
+# item and the output written on its last. A call costs a step a live
+# page plus one a dead row, not rows x table width, and the pipeline
+# fetches the next row's first page behind this row's last. A dead
+# row's item names the page of the live item before it, so it fetches
+# nothing, and it computes nothing; a page past a row's length or at and
+# past its first sentinel is never named at all.
+#
+# The query comes in full-lane, [B, H, D]: the kernel builds the row's
+# [H*D, H] block-diagonal operand in VMEM on the row's first item from
+# the same values (:func:`_block_diagonal`), so the score matmul is the
+# slab kernel's, bit for bit, and no lane-padded operand is written to
+# HBM by XLA on every call.
 #
 # Accumulation order note: the paged kernel tiles at page_size, the slab
 # kernel at pick_block_k(S) — when those differ the online-softmax adds
@@ -543,6 +556,82 @@ def supports_paged(page_size: int, hd: int = 512, kv_item: int = 2) -> bool:
             "flash-decode block floor) or larger.",
             stacklevel=3)
     return False
+
+
+def _work_list(table, lens, page_size, n_pages):
+    """The items of a paged call: row r contributes ``max(1,
+    ceil(eff_len[r] / page_size))`` (row, page) items, rows in order,
+    pages ascending, where ``eff_len = min(lens, n_real * page_size)``
+    and ``n_real`` counts the row's pages before its first sentinel.
+    Returns the list's length ([1], the grid's extent), three [B * PP]
+    int32 arrays meaningful below it (an item's row, its column in the
+    row's table, the pool page its K/V block index names) and
+    ``eff_len``."""
+    b, pp = table.shape
+    m = b * pp
+    col = lax.broadcasted_iota(jnp.int32, table.shape, 1)
+    n_real = jnp.min(jnp.where(table < n_pages, pp, col), axis=1)
+    eff_len = jnp.minimum(lens, n_real * page_size)
+    tab = jnp.minimum(table, n_pages - 1)
+    count = jnp.maximum(-(-eff_len // page_size), 1)
+    ends = jnp.cumsum(count)
+    item = jnp.arange(m, dtype=jnp.int32)
+    # an item's flat table index: its own index plus the columns that the
+    # rows wholly before it leave out
+    before = ends[None, :] <= item[:, None]  # [M, B]
+    flat = jnp.minimum(
+        item + jnp.sum(jnp.where(before, pp - count, 0), axis=1), m - 1)
+    rows, cols = flat // pp, flat % pp
+    pages = tab.reshape(-1)[flat]
+    # a dead row's item names the page of the live item before it (ahead
+    # of the first live item, that item's page): no step fetches for it
+    live = cols * page_size < eff_len[rows]
+    src = lax.cummax(jnp.where(live, item, jnp.argmax(live)))
+    return ends[-1:], rows, cols, pages[src], eff_len
+
+
+def _block_diagonal(q, dtype):
+    """A row's [H, D] query as the [H*D, H] block-diagonal operand of the
+    score matmul (head h's query in rows h*D:(h+1)*D of column h, zeros
+    elsewhere), built in VMEM. ``rep[l, g] = q[g, l % D]`` is one matmul
+    against a 0/1 selection, exact (one nonzero term an output); the
+    diagonal blocks are then kept. ``q`` holds bf16 values (the int8
+    path: integers of at most 127), so the operand's values are the
+    ones the slab path builds in XLA."""
+    h, d = q.shape
+    hd = h * d
+    pos = lax.broadcasted_iota(jnp.int32, (hd, d), 0)
+    sel = pos - pos // d * d == lax.broadcasted_iota(jnp.int32, (hd, d), 1)
+    rep = jax.lax.dot_general(  # [HD, H]
+        sel.astype(jnp.bfloat16), q.astype(jnp.bfloat16),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    diag = (lax.broadcasted_iota(jnp.int32, (hd, h), 0) // d
+            == lax.broadcasted_iota(jnp.int32, (hd, h), 1))
+    return jnp.where(diag, rep, 0.0).astype(dtype)
+
+
+def _paged_kernel(row_ref, col_ref, page_ref, len_ref, q_ref, *refs,
+                  page_size, h, quant):
+    """One (row, page) item of the work list; ``refs`` as in
+    :func:`_decode_kernel`, then the output and the scratch (the row's
+    block-diagonal query first)."""
+    o_ref, qbd_ref, m_ref, l_ref, acc_ref = refs[-5:]
+    item = pl.program_id(0)
+    j = col_ref[item]
+    row_len = len_ref[row_ref[item]]
+    _init_scratch(j, m_ref, l_ref, acc_ref)
+
+    @pl.when((j == 0) & (row_len > 0))
+    def _query():
+        qbd_ref[0] = _block_diagonal(q_ref[0], qbd_ref.dtype)
+
+    @pl.when(j * page_size < row_len)
+    def _live():
+        _tile_update(row_len, j, page_size, h, qbd_ref,
+                     _kv_refs(refs, quant), m_ref, l_ref, acc_ref)
+
+    n_items = jnp.maximum((row_len + page_size - 1) // page_size, 1)
+    _finalize(j, n_items, o_ref, l_ref, acc_ref, h)
 
 
 def flash_decode_paged(
@@ -593,19 +682,53 @@ def flash_decode_paged(
         jnp.reshape(valid_len.astype(jnp.int32), (-1,)), (b,))
     # a row's live pages end at its first sentinel, whatever its length
     # says: a retired slot's row is all sentinels under a stale, still
-    # growing length, and costs its bare grid steps alone
-    col = lax.broadcasted_iota(jnp.int32, table.shape, 1)
-    n_real = jnp.min(jnp.where(table < n_pages, n_kv, col), axis=1)
-    eff_len = jnp.minimum(lens, n_real * ps)
-    # THE paged indirection: K/V tiles dereference the page table, at a
-    # column that stops at the row's last live page; sentinels are
-    # pre-clamped so the index map is a plain table read
-    tab = jnp.minimum(table, n_pages - 1)
-    return _tiled_decode(
-        "flash_decode_paged", q, (k, k_scale, v, v_scale), eff_len, ps, n_kv,
-        lambda bi, j, lens, last, tab: (
-            tab[bi, jnp.minimum(j, last[bi])], 0, 0),
-        (tab,), interpret)
+    # growing length
+    n_items, rows, cols, pages, eff_len = _work_list(table, lens, ps,
+                                                     n_pages)
+
+    # index maps under PrefetchScalarGridSpec receive the scalar refs last
+    def per_row(item, rows, cols, pages, lens):
+        return (rows[item], 0, 0)
+
+    def per_page(item, rows, cols, pages, lens):
+        return (pages[item], 0, 0)
+
+    in_specs = [pl.BlockSpec((1, h, d), per_row)]
+    if quant:
+        q8, qs_row = _quantize_query(q)
+        in_specs.append(pl.BlockSpec((1, 1, h), per_row))
+        arrays = [q8, qs_row]
+    else:
+        arrays = [q]
+    for pool, scale in ((k, k_scale), (v, v_scale)):
+        in_specs.append(pl.BlockSpec((1, ps, hd), per_page))
+        arrays.append(pool)
+        if quant:
+            in_specs.append(pl.BlockSpec((1, ps, h), per_page))
+            arrays.append(scale)
+
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, page_size=ps, h=h, quant=quant),
+        name="flash_decode_paged",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_items[0],),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, hd), per_row),
+            scratch_shapes=[
+                pltpu.VMEM((1, hd, h), jnp.int8 if quant else jnp.bfloat16),
+                pltpu.VMEM((1, h), jnp.float32),
+                pltpu.VMEM((1, h), jnp.float32),
+                pltpu.VMEM((1, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+    )(rows, cols, pages, eff_len, *arrays)
+    return out.reshape(b, h, d)
 
 
 # -- GSPMD partitioning ----------------------------------------------------

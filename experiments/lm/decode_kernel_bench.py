@@ -4,8 +4,9 @@
 
 One process, one chip. The shape is ``serve-rate-mixed``'s (``PERF.md`` §4):
 32 rows, tables 16 wide, pages of 128, packed width 2048 (16 heads of 128),
-bf16, a pool of 128 pages. Each case is a page table and a vector of lengths;
-the kernel runs ``CALLS`` times in one ``lax.scan`` (each call's query is the
+bf16, a pool of 128 pages. Each case is a page table and a vector of lengths
+(among them 1, 3, 5, 12 and 32 live rows at the cell's context lengths beside
+retired rows); the kernel runs ``CALLS`` times in one ``lax.scan`` (each call's query is the
 last call's output, so none is folded away) and the figure is microseconds a
 call: the host's clock around ``block_until_ready`` and, beside it, the
 kernel's own events in a profiler trace of one more scan. ``--tree`` puts
@@ -62,7 +63,69 @@ def cases(np):
     out["e_all_dead"] = (np.full((B, WIDTH), sentinel, np.int32),
                          np.full((B,), STALE_LENGTH, np.int32),
                          "no live row: the bare grid")
+    for n_live in (1, 3, 5, 12, 32):
+        out[f"live_{n_live:02d}"] = live_case(np, n_live) + (
+            f"{n_live} live rows of the cell's lengths, the rest retired",)
     return out
+
+
+def live_case(np, n_live):
+    """(table, lengths): ``n_live`` rows at the cell's context lengths, each
+    with one reserved page not yet written, spread over the slots; the
+    other rows retired (all sentinels, a stale length). Pages wrap round the
+    pool when the rows need more than it holds: a row still reads one page
+    a tile."""
+    table = np.full((B, WIDTH), N_PAGES, np.int32)
+    lengths = np.full((B,), STALE_LENGTH, np.int32)
+    page = 0
+    for i in range(n_live):
+        row, n = (5 * i) % B, LIVE_LENGTHS[i % len(LIVE_LENGTHS)]
+        reserved = min(-(-n // PAGE) + 1, WIDTH)
+        table[row, :reserved] = (page + np.arange(reserved)) % N_PAGES
+        page += reserved
+        lengths[row] = n
+    return table, lengths
+
+
+def time_kernel(jax, jnp, np, xplane, fn, table, lengths, pools, q0):
+    """Microseconds a call of ``fn(q, k_pool, v_pool, table, lengths)``:
+    the host's median and least over ``REPEATS`` scans of ``CALLS`` calls,
+    and the kernel's own events in a profiler trace of one more scan."""
+    @jax.jit
+    def many(q, k_pool, v_pool, table, lengths):
+        def one(q, _):
+            return fn(q, k_pool, v_pool, table, lengths), None
+        return jax.lax.scan(one, q, None, length=CALLS)[0]
+
+    operands = (q0, *pools, jnp.asarray(table), jnp.asarray(lengths))
+    many(*operands).block_until_ready()  # compile, warm
+    host = []
+    for _ in range(REPEATS):
+        t0 = time.perf_counter()
+        many(*operands).block_until_ready()
+        host.append((time.perf_counter() - t0) / CALLS * 1e6)
+    trace_dir = tempfile.mkdtemp(prefix="fd_bench_")
+    try:
+        with jax.profiler.trace(trace_dir):
+            many(*operands).block_until_ready()
+        red = xplane.reduce(xplane.load(xplane.find_xplane(trace_dir)))
+        secs, calls = red.kernel_seconds("flash_decode_paged")
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    live_tiles = int(sum(min(-(-int(n) // PAGE), int((row < N_PAGES).sum()))
+                         for row, n in zip(table, lengths)))
+    return {"tiles": B * WIDTH, "live_tiles": live_tiles,
+            "us_per_call_trace": round(secs / max(calls, 1) * 1e6, 2),
+            "trace_calls": calls,
+            "us_per_call_host_median": round(statistics.median(host), 2),
+            "us_per_call_host_min": round(min(host), 2)}
+
+
+def pools_and_query(jax, jnp):
+    kk, kv, kq = jax.random.split(jax.random.PRNGKey(0), 3)
+    k_pool = jax.random.normal(kk, (N_PAGES, PAGE, H * D), jnp.bfloat16)
+    v_pool = jax.random.normal(kv, (N_PAGES, PAGE, H * D), jnp.bfloat16)
+    return (k_pool, v_pool), jax.random.normal(kq, (B, H, D), jnp.bfloat16)
 
 
 def main():
@@ -86,43 +149,13 @@ def main():
         print(f"no TPU here ({dev.platform}): nothing to time", file=sys.stderr)
         return 2
 
-    kk, kv, kq = jax.random.split(jax.random.PRNGKey(0), 3)
-    k_pool = jax.random.normal(kk, (N_PAGES, PAGE, H * D), jnp.bfloat16)
-    v_pool = jax.random.normal(kv, (N_PAGES, PAGE, H * D), jnp.bfloat16)
-    q0 = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
-
-    @jax.jit
-    def many(q, k_pool, v_pool, table, lengths):
-        def one(q, _):
-            return flash_decode_paged(q, k_pool, v_pool, table, lengths), None
-        return jax.lax.scan(one, q, None, length=CALLS)[0]
-
+    pools, q0 = pools_and_query(jax, jnp)
     for name, (table, lengths, what) in cases(np).items():
-        operands = (q0, k_pool, v_pool, jnp.asarray(table), jnp.asarray(lengths))
-        many(*operands).block_until_ready()  # compile, warm
-        host = []
-        for _ in range(REPEATS):
-            t0 = time.perf_counter()
-            many(*operands).block_until_ready()
-            host.append((time.perf_counter() - t0) / CALLS * 1e6)
-        trace_dir = tempfile.mkdtemp(prefix="fd_bench_")
-        try:
-            with jax.profiler.trace(trace_dir):
-                many(*operands).block_until_ready()
-            red = xplane.reduce(xplane.load(xplane.find_xplane(trace_dir)))
-            secs, calls = red.kernel_seconds("flash_decode_paged")
-        finally:
-            shutil.rmtree(trace_dir, ignore_errors=True)
-        live_tiles = int(sum(min(-(-int(n) // PAGE), int((row < N_PAGES).sum()))
-                             for row, n in zip(table, lengths)))
+        timed = time_kernel(jax, jnp, np, xplane, flash_decode_paged, table,
+                            lengths, pools, q0)
         line = json.dumps({
             "case": name, "what": what, "tree": os.path.abspath(args.tree),
-            "device_kind": dev.device_kind, "tiles": B * WIDTH,
-            "live_tiles": live_tiles,
-            "us_per_call_trace": round(secs / max(calls, 1) * 1e6, 2),
-            "trace_calls": calls,
-            "us_per_call_host_median": round(statistics.median(host), 2),
-            "us_per_call_host_min": round(min(host), 2)})
+            "device_kind": dev.device_kind, **timed})
         print(line, flush=True)
         if args.out:
             os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)

@@ -466,3 +466,113 @@ def test_first_sentinel_caps_the_row(kv):
                       np.array([TILE, 300]))
     np.testing.assert_allclose(np.asarray(got, np.float32), ref, rtol=0,
                                atol=3e-2 * max(np.abs(ref).max(), 1.0))
+
+
+# -- the paged kernel's steps are the live pages ----------------------------
+#
+# The paged kernel runs a work list of (row, page) items, one a live page and
+# one for a row with none, where it ran the table's every (row, column). At
+# the serving cell's table geometry (32 rows x 16 pages of 128, PERF.md §4)
+# in small widths, a live row's output is the oracle's bit for bit, a row
+# with no live page writes zeros, and a page no live row reads may hold NaN.
+
+CELL_ROWS, CELL_WIDTH, STALE = 32, 16, 1900
+CELL_LIVE = (0, 1, 5, 12, 32)
+
+
+def _cell_table(n_live, seed):
+    """(table, lengths, n_pages) as the engine leaves them: ``n_live`` rows
+    at ragged lengths (page edges among them), each with one page reserved
+    past its length, spread over the slots; the first live row has a
+    sentinel in mid-row under a length that runs past it; the other rows
+    retired, all sentinels under a stale length."""
+    rng = np.random.RandomState(seed)
+    edges = [1, TILE - 1, TILE, TILE + 1, CELL_WIDTH * TILE]
+    sentinel = CELL_ROWS * CELL_WIDTH + 1  # >= n_pages below
+    table = np.full((CELL_ROWS, CELL_WIDTH), sentinel, np.int64)
+    lens = np.full((CELL_ROWS,), STALE, np.int64)
+    page = 0
+    for i in range(n_live):
+        row = (5 * i) % CELL_ROWS
+        n = edges[i] if i < len(edges) else rng.randint(2, CELL_WIDTH * TILE)
+        used = min(-(-n // TILE) + 1, CELL_WIDTH)
+        table[row, :used] = page + rng.permutation(used)
+        page += used
+        lens[row] = n
+        if i == 0 and n_live > 1:
+            table[row, :4] = page + np.arange(4)
+            table[row, 2] = sentinel  # the row ends at its third page
+            page += 4
+            lens[row] = 3 * TILE + 7
+    n_pages = page + 2
+    return np.minimum(table, n_pages), lens, n_pages
+
+
+def _eff_len(table, lens, n_pages):
+    first = np.where((table >= n_pages).any(1), (table >= n_pages).argmax(1),
+                     table.shape[1])
+    return np.minimum(lens, first * TILE)
+
+
+@pytest.mark.parametrize("n_live", CELL_LIVE)
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_cell_geometry_live_rows_bit_identical_to_oracle(kv, n_live):
+    table, lens, n_pages = _cell_table(n_live, seed=41 + n_live)
+    q, pools, scales = _case(kv, CELL_ROWS, seed=n_live, n_pages=n_pages)
+    got = _bits(_run("paged", q, pools, scales, table, lens))
+    eff = _eff_len(table, lens, n_pages)
+    want = _bits(_run("paged", q, pools, scales, table, eff, oracle=True))
+    live = eff > 0
+    assert live.sum() == n_live
+    np.testing.assert_array_equal(got[live], want[live])
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("n_live", [5, 12])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_cell_geometry_nan_in_unread_pages_never_reaches_a_live_row(kv, n_live):
+    """Every page that no live row reads below its length holds NaN: the
+    pages reserved past a length, the pages past a mid-row sentinel, the
+    page the sentinels clamp to and the spare ones."""
+    table, lens, n_pages = _cell_table(n_live, seed=7 + n_live)
+    q, pools, scales = _case(kv, CELL_ROWS, seed=n_live, n_pages=n_pages)
+    eff = _eff_len(table, lens, n_pages)
+    read = {int(table[r, c]) for r in range(CELL_ROWS)
+            for c in range(-(-int(eff[r]) // TILE))}
+    unread = sorted(set(range(n_pages)) - read)
+    assert n_pages - 1 in unread and len(unread) > n_live
+    clean = _run("paged", q, pools, scales, table, lens)
+    bad_pools, bad_scales = _poison(pools, scales, unread)
+    got = _run("paged", q, bad_pools, bad_scales, table, lens)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(_bits(got), _bits(clean))
+
+
+@pytest.mark.parametrize("n_live", CELL_LIVE)
+def test_work_list_is_the_live_pages_row_major(n_live):
+    """The list has ``sum(max(1, ceil(eff_len / page)))`` items: each row's
+    live pages in ascending order, one item for a row with none, rows in
+    order. A live item names its page; a dead row's item names the page of
+    the live item before it (ahead of the first live item, that item's), so
+    its step fetches nothing."""
+    from distriflow_tpu.ops.flash_decode import _work_list
+
+    table, lens, n_pages = _cell_table(n_live, seed=3 + n_live)
+    eff = _eff_len(table, lens, n_pages)
+    tab = np.minimum(table, n_pages - 1)
+    n, rows, cols, pages, got_eff = (np.asarray(a) for a in _work_list(
+        jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32), TILE,
+        n_pages))
+    np.testing.assert_array_equal(got_eff, eff)
+    n = int(n[0])
+    want = [(r, c) for r in range(CELL_ROWS)
+            for c in range(max(1, -(-int(eff[r]) // TILE)))]
+    assert n == len(want) == sum(max(1, -(-int(e) // TILE)) for e in eff)
+    assert list(zip(rows[:n].tolist(), cols[:n].tolist())) == want
+    live = [i for i, (r, c) in enumerate(want) if c * TILE < eff[r]]
+    for i, (r, c) in enumerate(want):
+        if i in live:
+            assert pages[i] == tab[r, c]
+        elif live:
+            before = [j for j in live if j < i]
+            assert pages[i] == pages[before[-1] if before else live[0]]
