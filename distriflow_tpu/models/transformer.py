@@ -304,7 +304,8 @@ class Attention(nn.Module):
     decode: bool = False  # KV-cache autoregressive mode (mutable 'cache')
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray,
+                 work_list: Optional[Tuple] = None) -> jnp.ndarray:
         cfg = self.config
         b, s, _ = x.shape
         head_dim = cfg.d_model // cfg.n_heads
@@ -317,7 +318,7 @@ class Attention(nn.Module):
         v = dense("v_proj")(x)
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, H, S, D]
         if self.decode:
-            return self._decode_attend(q, k, v, b, s, head_dim)
+            return self._decode_attend(q, k, v, b, s, head_dim, work_list)
         if cfg.use_rope:
             q, k = apply_rope(q, k, base=cfg.rope_base)
         seq_size = (
@@ -348,7 +349,7 @@ class Attention(nn.Module):
             cfg.d_model, axis=(-2, -1), name="o_proj", dtype=cfg.dtype,
             use_bias=False)
 
-    def _decode_attend(self, q, k, v, b, s, head_dim):
+    def _decode_attend(self, q, k, v, b, s, head_dim, work_list=None):
         """Incremental attention against the mutable KV cache.
 
         The first call (prefill, any ``s``) fills positions ``[0, s)``; each
@@ -370,6 +371,11 @@ class Attention(nn.Module):
         absmax-quantized K/V (``[B, max_seq, H]`` f32 scales), halving
         footprint and read traffic; the flash kernel folds the scales
         into its score/prob tensors in VMEM.
+
+        ``work_list``: the paged kernel's work list for this step, built
+        once by :class:`TransformerLM` (:func:`_step_work_list`) when the
+        step is a paged single-token one on the flash path; the layer
+        then runs the kernel on it instead of building its own.
         """
         cfg = self.config
         quant = cfg.resolved_kv_cache_dtype == "int8"
@@ -521,7 +527,7 @@ class Attention(nn.Module):
             out = out.transpose(0, 2, 1, 3)  # [B, s, H, D]
             return self._o_proj()(out)
 
-        use_fd = cfg.use_flash_decode
+        use_fd = True if work_list is not None else cfg.use_flash_decode
         if use_fd is None:
             # auto-enable only when the kernel can actually tile this
             # cache shape (no sublane-aligned divisor fitting VMEM ->
@@ -532,9 +538,7 @@ class Attention(nn.Module):
             )
 
             if paged:
-                use_fd = _default_use_flash() and supports_paged(
-                    ck.value.shape[1], hd=hd,
-                    kv_item=jnp.dtype(store_dtype).itemsize)
+                use_fd = _paged_flash_auto(ck.value, supports_paged)
             else:
                 use_fd = _default_use_flash() and supports_seq(
                     cfg.max_seq, hd=hd,
@@ -549,10 +553,11 @@ class Attention(nn.Module):
             if quant:
                 ctx = flash_decode_paged(
                     qf, ck.value, cv.value, tab, idx + s,
-                    k_scale=sk.value, v_scale=sv.value,
+                    k_scale=sk.value, v_scale=sv.value, work_list=work_list,
                 )
             else:
-                ctx = flash_decode_paged(qf, ck.value, cv.value, tab, idx + s)
+                ctx = flash_decode_paged(qf, ck.value, cv.value, tab, idx + s,
+                                         work_list=work_list)
             out = ctx[:, None, :, :].astype(cfg.dtype)  # [B, 1, H, D]
             return self._o_proj()(out)
         if use_fd and s == 1:
@@ -763,13 +768,53 @@ class Block(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray,
+                 work_list: Optional[Tuple] = None) -> jnp.ndarray:
         cfg = self.config
         h = nn.LayerNorm(name="ln_attn", dtype=jnp.float32)(x)
-        x = x + Attention(cfg, self.mesh, self.decode, name="attn")(h)
+        x = x + Attention(cfg, self.mesh, self.decode, name="attn")(
+            h, work_list)
         h = nn.LayerNorm(name="ln_mlp", dtype=jnp.float32)(x)
         ffn = MoEFFN(cfg, name="moe") if cfg.n_experts > 0 else DenseFFN(cfg, name="mlp")
         return x + ffn(h)
+
+
+def _paged_flash_auto(pool, fits) -> bool:
+    """The paged decode kernel's auto gate (``use_flash_decode=None``) for
+    ``pool`` ([n_pages, page_size, H*D]): the platform's default, and a
+    page shape the kernel tiles by ``fits`` (``supports_paged``, which
+    counts and warns a gated shape, or the bare ``paged_fits``)."""
+    _, page_size, hd = pool.shape
+    return _default_use_flash() and fits(
+        page_size, hd=hd, kv_item=jnp.dtype(pool.dtype).itemsize)
+
+
+def _step_work_list(cfg, variables, s: int) -> Optional[Tuple]:
+    """The paged decode kernel's work list for one decode step, built once
+    for all the layers, or None where no layer runs the kernel on one:
+    not a single-token step, no page table in the cache, or the kernel
+    gated off (then each layer takes its own path, gate and count as
+    before). The list depends on the table and the lengths alone, and
+    every layer holds the same ones: ``set_page_tables`` and the paged
+    ``insert`` (``models/generate.py``) write each layer's ``page_table``
+    from one array, and every program moves each layer's
+    ``cache_index`` alike; so layer 0's leaves stand for all."""
+    attn = variables.get("cache", {}).get("layers_0", {}).get("attn", {})
+    if s != 1 or "page_table" not in attn:
+        return None
+    from distriflow_tpu.ops.flash_decode import paged_fits, paged_work_list
+
+    pool = attn["cached_k"]
+    use_fd = cfg.use_flash_decode
+    if use_fd is None:
+        use_fd = _paged_flash_auto(pool, paged_fits)
+    if not use_fd:
+        return None
+    n_pages, page_size = pool.shape[:2]
+    # what each layer's kernel call would read: the table less its pinned
+    # sentinel column, and the lengths after this step's write
+    return paged_work_list(attn["page_table"][:, :-1],
+                           attn["cache_index"] + s, page_size, n_pages)
 
 
 class TransformerLM(nn.Module):
@@ -783,8 +828,11 @@ class TransformerLM(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.d_model, name="embed",
                      dtype=cfg.dtype)(tokens)
         block_cls = nn.remat(Block) if (cfg.remat and not self.decode) else Block
+        work_list = (_step_work_list(cfg, self.variables, tokens.shape[1])
+                     if self.decode else None)
         for i in range(cfg.n_layers):
-            x = block_cls(cfg, self.mesh, self.decode, name=f"layers_{i}")(x)
+            x = block_cls(cfg, self.mesh, self.decode, name=f"layers_{i}")(
+                x, work_list)
         x = nn.LayerNorm(name="ln_f", dtype=jnp.float32)(x)
         logits = nn.Dense(cfg.vocab_size, name="lm_head", dtype=cfg.dtype,
                           use_bias=False)(x)

@@ -79,7 +79,7 @@ from __future__ import annotations
 
 import functools
 import warnings
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,7 +87,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distriflow_tpu.ops.flop_count import record_pallas_cost
+from distriflow_tpu.ops.flop_count import (
+    record_pallas_cost,
+    record_pallas_count,
+)
 
 BLOCK_K = 2048  # KV positions per tile: [2048, 512] bf16 K+V tiles are
 # 2 MB each, double-buffered 8 MB — inside the 16 MB scoped-VMEM limit
@@ -498,9 +501,12 @@ def _tiled_decode(q, kv, lens, block_k, n_kv, interpret):
 # runs on. The kernel's steps are those pages and nothing more: one
 # (row, page) item a live page, in row-major, ascending-page order, and
 # one item for a row with no live page, so that it still writes its
-# zeros; :func:`_work_list` builds the list and ``eff_len`` from the
-# table and the lengths. The grid is one axis whose extent is the list's
-# length, a traced scalar; the K/V index maps read an item's page, the
+# zeros; :func:`paged_work_list` builds the list and ``eff_len`` from
+# the table and the lengths. A caller that runs the kernel on one table
+# and one set of lengths several times (the model's layers in a decode
+# step) builds the list once and hands it to every call (``work_list``).
+# The grid is one axis whose extent is the list's length, a traced
+# scalar; the K/V index maps read an item's page, the
 # query and output maps its row, the scratch is reset on a row's first
 # item and the output written on its last. A call costs a step a live
 # page plus one a dead row, not rows x table width, and the pipeline
@@ -529,15 +535,22 @@ def _tiled_decode(q, kv, lens, block_k, n_kv, interpret):
 _warned_paged: set = set()
 
 
-def supports_paged(page_size: int, hd: int = 512, kv_item: int = 2) -> bool:
+def paged_fits(page_size: int, hd: int = 512, kv_item: int = 2) -> bool:
     """True when :func:`flash_decode_paged` can run pages of
     ``page_size`` tokens at packed width ``hd``: sublane-aligned, at or
     above the sliver-DMA floor, and one double-buffered page pair fits
-    scoped VMEM. Gated shapes bump ``ops_flash_decode_gated_total`` and
-    warn once, mirroring :func:`supports_seq`."""
-    if (page_size % 8 == 0 and page_size >= MIN_BLOCK_K
+    scoped VMEM. The bare test: :func:`supports_paged` also counts and
+    warns."""
+    return (page_size % 8 == 0 and page_size >= MIN_BLOCK_K
             and _vmem_estimate_bytes(page_size, hd, kv_item)
-            <= VMEM_LIMIT_BYTES):
+            <= VMEM_LIMIT_BYTES)
+
+
+def supports_paged(page_size: int, hd: int = 512, kv_item: int = 2) -> bool:
+    """:func:`paged_fits`, where a gated shape bumps
+    ``ops_flash_decode_gated_total`` and warns once, mirroring
+    :func:`supports_seq`."""
+    if paged_fits(page_size, hd, kv_item):
         return True
     from distriflow_tpu.obs import get_telemetry
 
@@ -558,36 +571,43 @@ def supports_paged(page_size: int, hd: int = 512, kv_item: int = 2) -> bool:
     return False
 
 
-def _work_list(table, lens, page_size, n_pages):
-    """The items of a paged call: row r contributes ``max(1,
-    ceil(eff_len[r] / page_size))`` (row, page) items, rows in order,
-    pages ascending, where ``eff_len = min(lens, n_real * page_size)``
-    and ``n_real`` counts the row's pages before its first sentinel.
-    Returns the list's length ([1], the grid's extent), three [B * PP]
-    int32 arrays meaningful below it (an item's row, its column in the
-    row's table, the pool page its K/V block index names) and
-    ``eff_len``."""
-    b, pp = table.shape
-    m = b * pp
-    col = lax.broadcasted_iota(jnp.int32, table.shape, 1)
-    n_real = jnp.min(jnp.where(table < n_pages, pp, col), axis=1)
-    eff_len = jnp.minimum(lens, n_real * page_size)
-    tab = jnp.minimum(table, n_pages - 1)
-    count = jnp.maximum(-(-eff_len // page_size), 1)
-    ends = jnp.cumsum(count)
-    item = jnp.arange(m, dtype=jnp.int32)
-    # an item's flat table index: its own index plus the columns that the
-    # rows wholly before it leave out
-    before = ends[None, :] <= item[:, None]  # [M, B]
-    flat = jnp.minimum(
-        item + jnp.sum(jnp.where(before, pp - count, 0), axis=1), m - 1)
-    rows, cols = flat // pp, flat % pp
-    pages = tab.reshape(-1)[flat]
-    # a dead row's item names the page of the live item before it (ahead
-    # of the first live item, that item's page): no step fetches for it
-    live = cols * page_size < eff_len[rows]
-    src = lax.cummax(jnp.where(live, item, jnp.argmax(live)))
-    return ends[-1:], rows, cols, pages[src], eff_len
+def paged_work_list(table, lens, page_size: int, n_pages: int) -> Tuple:
+    """The work list of :func:`flash_decode_paged` over ``table`` ([B, PP]
+    int32, entries >= ``n_pages`` sentinels) and ``lens`` ([B] int32): row
+    r contributes ``max(1, ceil(eff_len[r] / page_size))`` (row, page)
+    items, rows in order, pages ascending, where ``eff_len = min(lens,
+    n_real * page_size)`` and ``n_real`` counts the row's pages before
+    its first sentinel. Returns ``(n_items, rows, cols, pages,
+    eff_len)``: the list's length ([1], the grid's extent), three
+    [B * PP] int32 arrays meaningful below it (an item's row, its column
+    in the row's table, the pool page its K/V block index names) and
+    ``eff_len``. The list depends on the table and the lengths alone, so
+    calls that share both can share one list (``work_list``)."""
+    record_pallas_count("paged_work_lists_built")
+    with jax.named_scope("paged_work_list"):
+        table = table.astype(jnp.int32)
+        lens = lens.astype(jnp.int32)
+        b, pp = table.shape
+        m = b * pp
+        col = lax.broadcasted_iota(jnp.int32, table.shape, 1)
+        n_real = jnp.min(jnp.where(table < n_pages, pp, col), axis=1)
+        eff_len = jnp.minimum(lens, n_real * page_size)
+        tab = jnp.minimum(table, n_pages - 1)
+        count = jnp.maximum(-(-eff_len // page_size), 1)
+        ends = jnp.cumsum(count)
+        item = jnp.arange(m, dtype=jnp.int32)
+        # an item's flat table index: its own index plus the columns that the
+        # rows wholly before it leave out
+        before = ends[None, :] <= item[:, None]  # [M, B]
+        flat = jnp.minimum(
+            item + jnp.sum(jnp.where(before, pp - count, 0), axis=1), m - 1)
+        rows, cols = flat // pp, flat % pp
+        pages = tab.reshape(-1)[flat]
+        # a dead row's item names the page of the live item before it (ahead
+        # of the first live item, that item's page): no step fetches for it
+        live = cols * page_size < eff_len[rows]
+        src = lax.cummax(jnp.where(live, item, jnp.argmax(live)))
+        return ends[-1:], rows, cols, pages[src], eff_len
 
 
 def _block_diagonal(q, dtype):
@@ -643,6 +663,7 @@ def flash_decode_paged(
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
     interpret: Optional[bool] = None,
+    work_list: Optional[Tuple] = None,
 ) -> jnp.ndarray:
     """Decode attention against a PAGED cache, one query token per row.
 
@@ -657,7 +678,13 @@ def flash_decode_paged(
     ``q``'s dtype; a row with no live position (length 0, or a table
     that starts with a sentinel) returns zeros. Pages past a row's
     length are neither fetched nor computed on, so what they hold
-    (NaN included) cannot reach the output."""
+    (NaN included) cannot reach the output.
+
+    ``work_list``: what :func:`paged_work_list` returns for this
+    ``page_table``, ``valid_len`` and pool, built once by a caller that
+    runs several calls on them (the model's layers in a decode step);
+    the call then builds nothing and reads ``page_table`` for its width
+    alone. Absent, the call builds its own."""
     interpret = _resolve_interpret(interpret)
     b, h, d = q.shape
     n_pages, ps, hd = k.shape
@@ -677,14 +704,20 @@ def flash_decode_paged(
             f"{VMEM_LIMIT_BYTES / 1e6:.0f} MB TPU limit — shrink page_size")
     n_kv = page_table.shape[1]
     _record_decode_cost(b * n_kv * ps, hd, kv_item, quant, h)
-    table = page_table.astype(jnp.int32)
-    lens = jnp.broadcast_to(
-        jnp.reshape(valid_len.astype(jnp.int32), (-1,)), (b,))
-    # a row's live pages end at its first sentinel, whatever its length
-    # says: a retired slot's row is all sentinels under a stale, still
-    # growing length
-    n_items, rows, cols, pages, eff_len = _work_list(table, lens, ps,
-                                                     n_pages)
+    if work_list is None:
+        lens = jnp.broadcast_to(
+            jnp.reshape(valid_len.astype(jnp.int32), (-1,)), (b,))
+        # a row's live pages end at its first sentinel, whatever its
+        # length says: a retired slot's row is all sentinels under a
+        # stale, still growing length
+        work_list = paged_work_list(page_table, lens, ps, n_pages)
+    else:
+        record_pallas_count("paged_work_lists_reused")
+    n_items, rows, cols, pages, eff_len = work_list
+    if rows.shape != (b * n_kv,) or eff_len.shape != (b,):
+        raise ValueError(
+            f"work_list of {rows.shape[0]} items over {eff_len.shape[0]} "
+            f"rows does not fit a [{b}, {n_kv}] page table")
 
     # index maps under PrefetchScalarGridSpec receive the scalar refs last
     def per_row(item, rows, cols, pages, lens):

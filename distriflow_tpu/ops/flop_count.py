@@ -80,11 +80,25 @@ def record_pallas_cost(
             cat["hw_flops"] += hw
 
 
+def record_pallas_count(name: str) -> None:
+    """Add one to the active tally's ``counts[name]``: how often a kernel
+    wrapper took a path per trace, where a cost says nothing of it. The
+    paged decode kernel files ``paged_work_lists_built`` (each build of
+    its work list, inside a call or once for a step's layers) and
+    ``paged_work_lists_reused`` (each call handed a list it did not
+    build). No-op when no tally is active."""
+    tally = _TALLY.get()
+    if tally is not None:
+        counts = tally["counts"]
+        counts[name] = counts.get(name, 0) + 1
+
+
 @contextmanager
 def tally_pallas_cost() -> Iterator[Dict[str, float]]:
     """Collect Pallas kernel costs recorded while tracing inside the block."""
     tally: Dict[str, float] = {f: 0.0 for f in _FIELDS}
     tally["by_category"] = {}  # type: ignore[assignment]
+    tally["counts"] = {}  # type: ignore[assignment]
     token = _TALLY.set(tally)
     try:
         yield tally

@@ -25,9 +25,11 @@ from distriflow_tpu.ops.flash_decode import (
     _warned_gated,
     flash_decode,
     flash_decode_paged,
+    paged_work_list,
     pick_block_k,
     supports_seq,
 )
+from distriflow_tpu.ops.flop_count import pallas_cost_of
 
 
 def _dense_reference(q, k, v, valid_len):
@@ -555,12 +557,10 @@ def test_work_list_is_the_live_pages_row_major(n_live):
     order. A live item names its page; a dead row's item names the page of
     the live item before it (ahead of the first live item, that item's), so
     its step fetches nothing."""
-    from distriflow_tpu.ops.flash_decode import _work_list
-
     table, lens, n_pages = _cell_table(n_live, seed=3 + n_live)
     eff = _eff_len(table, lens, n_pages)
     tab = np.minimum(table, n_pages - 1)
-    n, rows, cols, pages, got_eff = (np.asarray(a) for a in _work_list(
+    n, rows, cols, pages, got_eff = (np.asarray(a) for a in paged_work_list(
         jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32), TILE,
         n_pages))
     np.testing.assert_array_equal(got_eff, eff)
@@ -576,3 +576,82 @@ def test_work_list_is_the_live_pages_row_major(n_live):
         elif live:
             before = [j for j in live if j < i]
             assert pages[i] == pages[before[-1] if before else live[0]]
+
+
+# -- a list built once serves every call on the same table ------------------
+#
+# The model builds one work list a decode step and hands it to each layer's
+# kernel call: a call given the list must be the call that builds its own,
+# bit for bit, on every table the engine leaves (live rows at page edges, a
+# row at length 1, retired rows under a stale length, no live row at all).
+
+
+def _length_one_table():
+    """A row at length 1 beside a full one and two retired rows (all
+    sentinels under a stale length)."""
+    n_pages = 2 * CELL_WIDTH + 1
+    table = np.full((4, CELL_WIDTH), n_pages, np.int64)
+    table[0, :2] = [7, 3]
+    table[2] = np.arange(CELL_WIDTH) + CELL_WIDTH
+    lens = np.array([1, STALE, CELL_WIDTH * TILE, 5])
+    return table, lens, n_pages
+
+
+SHARED_CASES = [f"cell-{n}" for n in CELL_LIVE] + ["length-one"]
+
+
+def _shared_case(name):
+    if name == "length-one":
+        return _length_one_table()
+    n_live = int(name.split("-")[1])
+    return _cell_table(n_live, seed=3 + n_live)
+
+
+@pytest.mark.parametrize("case", SHARED_CASES)
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_precomputed_work_list_is_bit_identical(kv, case):
+    table, lens, n_pages = _shared_case(case)
+    q, pools, scales = _case(kv, table.shape[0], seed=len(case),
+                             n_pages=n_pages)
+    table, lens = jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32)
+    ks, vs = scales
+    own = flash_decode_paged(q, pools[0], pools[1], table, lens, k_scale=ks,
+                             v_scale=vs, interpret=True)
+    shared = paged_work_list(table, lens, TILE, n_pages)
+    for _ in range(2):  # one list, two calls: as two layers use it
+        got = flash_decode_paged(q, pools[0], pools[1], table, lens,
+                                 k_scale=ks, v_scale=vs, interpret=True,
+                                 work_list=shared)
+        np.testing.assert_array_equal(_bits(got), _bits(own))
+
+
+def test_work_list_tally_counts_builds_and_reuses():
+    """The trace-time tally says how often a list was built and how often
+    a call ran on one it did not build."""
+    table, lens, n_pages = _length_one_table()
+    q, pools, _ = _case("bf16", table.shape[0], seed=5, n_pages=n_pages)
+    table, lens = jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32)
+
+    def own(q, k, v):
+        return [flash_decode_paged(q, k, v, table, lens, interpret=True)
+                for _ in range(3)]
+
+    def shared(q, k, v):
+        wl = paged_work_list(table, lens, TILE, n_pages)
+        return [flash_decode_paged(q, k, v, table, lens, interpret=True,
+                                   work_list=wl) for _ in range(3)]
+
+    assert pallas_cost_of(own, q, *pools)["counts"] == {
+        "paged_work_lists_built": 3}
+    assert pallas_cost_of(shared, q, *pools)["counts"] == {
+        "paged_work_lists_built": 1, "paged_work_lists_reused": 3}
+
+
+def test_work_list_of_another_table_is_refused():
+    table, lens, n_pages = _length_one_table()
+    q, pools, _ = _case("bf16", table.shape[0], seed=6, n_pages=n_pages)
+    table, lens = jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32)
+    narrow = paged_work_list(table[:, :2], lens, TILE, n_pages)
+    with pytest.raises(ValueError, match="does not fit"):
+        flash_decode_paged(q, pools[0], pools[1], table, lens,
+                           interpret=True, work_list=narrow)

@@ -18,6 +18,7 @@ Everything runs on a tiny CPU transformer; the module is deliberately
 NOT in conftest's slow set — tier-1 exercises the paged path every run.
 """
 
+import dataclasses
 import threading
 import time
 
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import distriflow_tpu.models.transformer as transformer
 from distriflow_tpu.client import InferenceClient
 from distriflow_tpu.models.generate import (
     _build_paged_fns,
@@ -34,9 +36,11 @@ from distriflow_tpu.models.generate import (
     generate,
     paged_cache,
     pages_per_slot,
+    set_page_tables,
     slot_cache,
 )
 from distriflow_tpu.models.transformer import TransformerConfig, transformer_lm
+from distriflow_tpu.ops.flop_count import pallas_cost_of
 from distriflow_tpu.obs import get_telemetry
 from distriflow_tpu.server import InferenceServer
 from distriflow_tpu.server.inference_server import _PagePool
@@ -223,6 +227,159 @@ def test_flash_decode_paged_matches_dense_reference():
         ref = np.einsum("bhs,bhsd->bhd", p, vf)
         np.testing.assert_allclose(
             np.asarray(out, np.float32)[row], ref[0], rtol=0, atol=3e-2)
+
+
+# -- one work list a decode step, shared by the layers ---------------------
+#
+# In a paged single-token step the model builds the decode kernel's work
+# list once, from layer 0's table and index, and every layer's kernel call
+# runs on it (models/transformer.py::_step_work_list). What a layer would
+# have built is the same list, so the decode chunk is the per-layer form
+# bit for bit; that rests on every layer holding the same table and index.
+
+CHUNK = 4
+
+
+def _flash_cfg(n_layers):
+    return dataclasses.replace(CFG, n_layers=n_layers, use_flash_decode=True)
+
+
+@pytest.fixture(scope="module")
+def flash_params():
+    return {n: transformer_lm(_flash_cfg(n), example_seq=16).init(
+        jax.random.PRNGKey(n)) for n in (2, 4)}
+
+
+def _engine_cache(cfg, params, max_slots=4, n_pages=12):
+    """A paged cache as the engine leaves it: rows of 3, 16 (a whole page)
+    and 21 tokens at scattered pages, the 16-token row's table ending in a
+    sentinel; slot 2 never admitted (all sentinels, an index the decode
+    steps move on). Returns the cache and each row's first token."""
+    prefill, _ = _build_prefill(cfg)
+    insert, _ = _build_paged_fns(cfg, PS)
+    pp = pages_per_slot(cfg.max_seq, PS)
+    cache = paged_cache(cfg, params, max_slots, PS, n_pages)
+    table = np.full((max_slots, pp + 1), n_pages, np.int32)
+    placed = {0: ([5, 0, 7], 3), 1: ([2, 9, n_pages], PS),
+              3: ([4, 1, 3], PS + 5)}
+    rng = np.random.RandomState(6)
+    first = np.zeros(max_slots, np.int32)
+    for slot, (pages, n) in placed.items():
+        prompt = jnp.asarray(rng.randint(0, 64, (1, n)), jnp.int32)
+        logits, row = prefill(dict(params), prompt)
+        table[slot, :pp] = pages
+        cache = insert(cache, row, jnp.array([slot], jnp.int32),
+                       jnp.int32(n), jnp.int32(0), table)
+        first[slot] = int(jnp.argmax(logits, axis=-1)[0])
+    return cache, jnp.asarray(first)
+
+
+def _decode_args(first):
+    b = first.shape[0]
+    z, zi = jnp.zeros((b,), jnp.float32), jnp.zeros((b,), jnp.int32)
+    done = jnp.zeros((b,), bool).at[2].set(True)
+    return first, done, z, zi, z + 1.0, zi, jnp.full((b,), -1, jnp.int32)
+
+
+def _two_chunks(cfg, params, cache, first):
+    """Two decode chunks through the served program's body (undonated, so
+    the caller's cache serves again). Returns the tokens and the cache."""
+    _, _, decode = _build_slot_fns(cfg, CHUNK, False)
+    tok, done, *rest = _decode_args(first)
+    out = []
+    for _ in range(2):
+        cache, tok, done, toks = decode.body(dict(params), cache, tok, done,
+                                             *rest)
+        out.append(np.asarray(toks))
+    return np.concatenate(out, axis=1), cache
+
+
+def _layers(cache, name):
+    return [np.array(cache[key]["attn"][name])
+            for key in sorted(cache) if key.startswith("layers_")]
+
+
+@pytest.mark.parametrize("n_layers", [2, 4])
+def test_decode_chunk_shared_work_list_matches_per_layer(
+        flash_params, monkeypatch, n_layers):
+    cfg = _flash_cfg(n_layers)
+    params = flash_params[n_layers]
+    cache, first = _engine_cache(cfg, params)
+    toks, got = _two_chunks(cfg, params, cache, first)
+    # the per-layer form: no list a step, each kernel call builds its own
+    monkeypatch.setattr(transformer, "_step_work_list", lambda *a: None)
+    want_toks, want = _two_chunks(cfg, params, cache, first)
+    np.testing.assert_array_equal(toks, want_toks)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (toks[2] == 0).all() and len(set(toks[[0, 1, 3]].ravel())) > 1
+
+
+def _chunk_structs(cfg, max_slots=4, n_pages=12):
+    params = jax.eval_shape(transformer_lm(cfg, example_seq=16).init,
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(
+        lambda p: paged_cache(cfg, p, max_slots, PS, n_pages), params)
+    vec = lambda dt: jax.ShapeDtypeStruct((max_slots,), dt)  # noqa: E731
+    return (params, cache, vec(jnp.int32), vec(jnp.bool_), vec(jnp.float32),
+            vec(jnp.int32), vec(jnp.float32), vec(jnp.int32), vec(jnp.int32))
+
+
+@pytest.mark.parametrize("n_layers", [2, 4])
+def test_decode_chunk_tally_one_list_build_and_a_reuse_a_layer(n_layers):
+    cfg = _flash_cfg(n_layers)
+    _, _, decode = _build_slot_fns(cfg, CHUNK, False)
+    counts = pallas_cost_of(decode.body, *_chunk_structs(cfg))["counts"]
+    assert counts == {"paged_work_lists_built": 1,
+                      "paged_work_lists_reused": n_layers}
+
+
+def _list_locations(cfg):
+    """Source locations under the work list's scope in the decode chunk's
+    lowered text: the list's ops, each place once."""
+    _, _, decode = _build_slot_fns(cfg, CHUNK, False)
+    jax.clear_caches()  # the trace, not a cached one of another form
+    text = jax.jit(decode.body).lower(*_chunk_structs(cfg)).as_text(
+        debug_info=True)
+    return sum(1 for line in text.splitlines()
+               if line.startswith("#loc") and "paged_work_list" in line)
+
+
+def test_decode_chunk_list_ops_do_not_grow_with_depth(monkeypatch):
+    try:
+        shared = [_list_locations(_flash_cfg(n)) for n in (2, 4)]
+        monkeypatch.setattr(transformer, "_step_work_list", lambda *a: None)
+        per_layer = [_list_locations(_flash_cfg(n)) for n in (2, 4)]
+    finally:
+        jax.clear_caches()
+    assert shared[0] == shared[1] > 0
+    assert per_layer[1] > per_layer[0] > shared[0]
+
+
+def test_every_layer_holds_the_same_table_and_index(flash_params):
+    """What the shared list rests on: the paged ``insert`` and
+    ``set_page_tables`` write each layer's ``page_table`` from one array,
+    and ``insert`` and the decode chunk move each layer's
+    ``cache_index`` alike."""
+    cfg = _flash_cfg(4)
+    cache, first = _engine_cache(cfg, flash_params[4])
+
+    def same(cache, name):
+        each = _layers(cache, name)
+        assert len(each) == 4
+        for leaf in each[1:]:
+            np.testing.assert_array_equal(leaf, each[0])
+        return each[0]
+
+    assert same(cache, "cache_index").tolist() == [3, PS, 0, PS + 5]
+    table = same(cache, "page_table")
+    table[2, 0] = 11  # admit slot 2 onto a fresh page
+    cache = set_page_tables(cache, table)
+    np.testing.assert_array_equal(same(cache, "page_table"), table)
+    _, cache = _two_chunks(cfg, flash_params[4], cache, first)
+    assert same(cache, "cache_index").tolist() == [
+        3 + 2 * CHUNK, PS + 2 * CHUNK, 2 * CHUNK, PS + 5 + 2 * CHUNK]
+    np.testing.assert_array_equal(same(cache, "page_table"), table)
 
 
 # -- server half -----------------------------------------------------------
